@@ -109,7 +109,9 @@ print("@@RESULT@@" + json.dumps(slim))
 
 
 def run_variant(arch, shape, kwargs):
-    env = dict(os.environ, PYTHONPATH="src")
+    # a CPU dry-run: the child never reaches for the chip, which belongs
+    # to one process at a time
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     spec = json.dumps({"arch": arch, "shape": shape, "kwargs": kwargs})
     proc = subprocess.run([sys.executable, "-c", _RUNNER, spec], env=env,

@@ -39,6 +39,8 @@ def main() -> None:
     if "--smoke" in args:
         args.remove("--smoke")
         os.environ["REPRO_BENCH_SMOKE"] = "1"
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_kernels, bench_planner, bench_roofline,
                    bench_runtime, exp_crossover, exp_opt_time, exp_wilos)
     mods = {"exp_crossover": exp_crossover, "exp_wilos": exp_wilos,

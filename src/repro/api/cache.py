@@ -351,6 +351,9 @@ class ArtifactCache:
             self._entries.popitem(last=False)
             self.evictions += 1
 
+    def values(self) -> list:
+        return list(self._entries.values())
+
     def invalidate(self, pred) -> int:
         """Drop every entry for which ``pred(key, value)`` is true."""
         stale = [k for k, v in self._entries.items() if pred(k, v)]

@@ -5,12 +5,14 @@ A :class:`~repro.compiled.lower.CompiledLoop` executes through
 fast interpreter uses, which owns ALL simulated-time charging — but with
 :class:`~repro.core.vectorize.LoopHooks` that move the data differently:
 
-  * **navigation / cache-lookup probes** run against an epoch-cached
-    :class:`_ProbeIndex` (host key columns, argsort order, materialized
-    column arrays, and — under Pallas — a direct-address table), probed by
-    ``kernels.join_probe`` / ``kernels.ops`` on the ``"kernels"`` backend
-    or ``kernels.ref.join_probe_np`` on the ``"numpy"`` backend. The index
-    is keyed by the SAME (stats version, data version, instance) epoch the
+  * **navigation / cache-lookup probes** run against the build side's
+    sorted keys (:class:`_BuildKeys`), probed by the ``join_probe`` kernel
+    as ``kernels.ops`` dispatches it for the platform on the ``"kernels"``
+    backend, or by a host search on the ``"numpy"`` backend and for keys
+    the kernel cannot take. Every probe is counted under the
+    implementation that ran (``CompiledLoop.kernel_calls``). The
+    navigation index (:class:`_ProbeIndex`) is keyed by the SAME (stats
+    version, data version, instance) epoch the
     serving :class:`~repro.runtime.sitecache.SiteCache` uses, so an
     ``analyze()`` or a write landing mid-stream rebuilds it instead of
     serving stale gathers — compiled results stay bit-identical to
@@ -30,20 +32,23 @@ sources at run time), defers to the exact row-at-a-time semantics.
 from __future__ import annotations
 
 from collections import OrderedDict
-from typing import Dict, Optional
+from typing import Callable
 
+import jax.numpy as jnp
 import numpy as np
 
 from ..core.regions import Interpreter, IVar, LoopRegion
 from ..core.vectorize import (LoopHooks, _broadcast, _eval_vec,
                               _vec_accumulate, exec_loop_plan)
+from ..kernels import ops
 from ..kernels import ref as kref
+from ..kernels.join_probe import build_direct_table, join_probe
 from ..relational.table import Table
 
 __all__ = ["SplicingInterpreter", "make_hooks"]
 
 # fp32 holds integers exactly up to 2**24: the kernel fold (which
-# accumulates in float32 on the MXU path) is only taken below this bound
+# accumulates in float32) is only taken below this bound
 _EXACT_FP32 = float(1 << 24)
 
 # bounded memos: a serving process sees unbounded distinct query-result
@@ -52,59 +57,60 @@ _ROW_SOURCE_CAP = 32
 _PROBE_INDEX_CAP = 64
 
 
-def _pallas_probe_ok() -> bool:
-    from ..kernels import ops
-    return ops.pallas_state()[0]
+class _TableMemo:
+    """LRU memo of ``build(table, *args)``, keyed by the table's identity
+    (and ``args``) WITH a strong reference to the keyed table (``id``
+    alone could be recycled). In the serving path the site cache returns
+    the same Table object for an unchanged site, so repeated batches hit
+    this memo instead of re-deriving from the table."""
 
-
-class _RowSourceCache:
-    """Memoized columnar materialization of loop-source tables.
-
-    Keyed by object identity WITH a strong reference to the keyed table
-    (``id`` alone could be recycled). In the serving path the site cache
-    returns the same Table object for an unchanged site, so repeated
-    batches hit this memo instead of re-converting columns."""
-
-    def __init__(self, cap: int = _ROW_SOURCE_CAP):
+    def __init__(self, build: Callable, cap: int):
+        self.build = build
         self.cap = cap
-        self._memo: "OrderedDict[int, tuple]" = OrderedDict()
+        self._memo: "OrderedDict[tuple, tuple]" = OrderedDict()
 
-    def __call__(self, src: Table) -> Dict[str, np.ndarray]:
-        k = id(src)
+    def __call__(self, t: Table, *args):
+        k = (id(t),) + args
         hit = self._memo.get(k)
-        if hit is not None and hit[0] is src:
+        if hit is not None and hit[0] is t:
             self._memo.move_to_end(k)
             return hit[1]
-        cols = {c: np.asarray(src.column(c)) for c in src.schema.names}
-        self._memo[k] = (src, cols)
+        value = self.build(t, *args)
+        self._memo[k] = (t, value)
         while len(self._memo) > self.cap:
             self._memo.popitem(last=False)
-        return cols
+        return value
+
+
+def _columns(t: Table):
+    return {c: np.asarray(t.column(c)) for c in t.schema.names}
+
+
+class _BuildKeys:
+    """A build side's key column in sorted order — ``order`` is its stable
+    sort and ``sorted`` the keys in that order — plus, once the kernel
+    probes it, its direct-address table on the device."""
+
+    __slots__ = ("order", "sorted", "space", "direct")
+
+    def __init__(self, t: Table, col: str):
+        keys = np.asarray(t.column(col))
+        self.order = np.argsort(keys, kind="stable")
+        self.sorted = keys[self.order]
+        self.space = ops.direct_key_space(self.sorted)
+        self.direct = None
 
 
 class _ProbeIndex:
     """Per-(table, key column) probe state, rebuilt when the epoch moves."""
 
-    __slots__ = ("epoch", "table", "tkeys", "order", "sorted_keys", "cols",
-                 "direct")
+    __slots__ = ("epoch", "table", "keys", "cols")
 
     def __init__(self, epoch, t: Table, key_col: str):
         self.epoch = epoch
         self.table = t
-        self.tkeys = np.asarray(t.column(key_col))
-        self.order = np.argsort(self.tkeys, kind="stable")
-        self.sorted_keys = self.tkeys[self.order]
-        self.cols = {c: np.asarray(t.column(c)) for c in t.schema.names}
-        self.direct = None   # lazily-built Pallas direct-address table
-
-    def key_space(self) -> Optional[int]:
-        if self.tkeys.size == 0 \
-                or not np.issubdtype(self.tkeys.dtype, np.integer):
-            return None
-        lo, hi = int(self.tkeys.min()), int(self.tkeys.max())
-        if lo < 0 or hi + 1 > (1 << 22):
-            return None
-        return hi + 1
+        self.keys = _BuildKeys(t, key_col)
+        self.cols = _columns(t)
 
 
 class _ProbeIndexCache:
@@ -129,38 +135,35 @@ class _ProbeIndexCache:
         return idx
 
 
-def _probe(cl, idx: _ProbeIndex, keys: np.ndarray) -> np.ndarray:
-    """Row index in ``idx.table`` for each key, -1 on miss.
+def _probe(cl, bk: _BuildKeys, keys: np.ndarray) -> np.ndarray:
+    """Build-side row (an entry of ``bk.order``) for each key, -1 on miss.
 
-    ``"kernels"`` backend with Pallas dispatch on and an addressable key
-    space: the ``join_probe`` kernel against an epoch-cached direct-address
-    table (built once per epoch, not per call like ``ops.equi_probe``).
-    Everywhere else: searchsorted against the index's cached stable sort —
-    value-identical to ``kernels.ref.join_probe_np`` on the same inputs,
-    without re-sorting the build side on every probe."""
-    if cl.backend == "kernels" and _pallas_probe_ok():
-        ks = idx.key_space()
-        if ks is not None:
-            from ..kernels import ops
-            from ..kernels.join_probe import build_direct_table, join_probe
-            import jax.numpy as jnp
-            if idx.direct is None:
-                idx.direct = build_direct_table(
-                    jnp.asarray(idx.tkeys, jnp.int32), ks)
-            cl.kernel_probes += 1
-            return np.asarray(join_probe(jnp.asarray(keys, jnp.int32),
-                                         idx.direct,
-                                         interpret=ops.pallas_state()[1]))
-    n = keys.shape[0]
-    if n == 0:
-        return np.zeros((0,), np.int32)
-    if idx.tkeys.shape[0] == 0:
-        return np.full((n,), -1, np.int32)
-    pos = np.clip(np.searchsorted(idx.sorted_keys, keys), 0,
-                  len(idx.order) - 1)
-    gidx = idx.order[pos]
-    found = idx.tkeys[gidx] == keys
-    return np.where(found, gidx, -1).astype(np.int32)
+    The ``"kernels"`` backend probes with ``join_probe`` where
+    ``kernels.ops`` dispatches it (compiled on the TPU), against a
+    direct-address table built once per build side. Build keys the kernel
+    cannot take (``ops.direct_key_space``), probe keys that are not
+    integers, other platforms and the ``"numpy"`` backend search the sorted
+    keys on the host instead — the same values. Either way the call is
+    counted under the implementation that ran."""
+    how = ops.REF
+    if cl.backend == "kernels" and np.issubdtype(keys.dtype, np.integer):
+        how = ops.probe_impl(bk.space)
+    cl.kernel_calls["join_probe", how] += 1
+    if bk.sorted.shape[0] == 0:
+        return np.full(keys.shape, -1, np.int32)
+    if how != ops.REF:
+        if bk.direct is None:
+            bk.direct = build_direct_table(jnp.asarray(bk.sorted, jnp.int32),
+                                           bk.space)
+        # out-of-range keys miss; clamp them before narrowing to int32
+        keys = np.where((keys >= 0) & (keys < bk.space), keys, -1)
+        pos = np.asarray(join_probe(jnp.asarray(keys, jnp.int32), bk.direct,
+                                    interpret=how == ops.INTERPRET))
+    else:
+        pos = np.clip(np.searchsorted(bk.sorted, keys), 0,
+                      bk.sorted.shape[0] - 1)
+        pos = np.where(bk.sorted[pos] == keys, pos, -1)
+    return np.where(pos >= 0, bk.order[pos], -1).astype(np.int32)
 
 
 def make_hooks(cl) -> LoopHooks:
@@ -170,14 +173,18 @@ def make_hooks(cl) -> LoopHooks:
     same values, same ORM-cache mutations, same failure behavior — only
     the gather/fold machinery differs (epoch-cached indices + kernels)."""
     probe_cache = _ProbeIndexCache(cl)
-    row_source = _RowSourceCache()
+    row_source = _TableMemo(_columns, _ROW_SOURCE_CAP)
+    # the build keys of a prefetch cache, by its table: each invocation
+    # re-prefetches, but the same Table (the same sort as the cache's own),
+    # so the direct-address table is built once and not per invocation
+    prefetch_keys = _TableMemo(_BuildKeys, _PROBE_INDEX_CAP)
 
     # ------------------------------------------------------------------ nav
     def nav(env, ce, target, e, n):
         base = ce.rows[e.base.name]
         keys = np.asarray(base[e.fk_field])
         idx = probe_cache.get(env, e.target, e.target_key)
-        gidx = _probe(cl, idx, keys)
+        gidx = _probe(cl, idx.keys, keys)
         if (gidx < 0).any():
             raise KeyError(f"navigation {e!r}: missing keys (FK violation)")
         ce.rows[target] = {c: idx.cols[c][gidx] for c in idx.table.schema.names}
@@ -201,8 +208,8 @@ def make_hooks(cl) -> LoopHooks:
                     m.startup_s + m.index_lookup_s,
                     m.startup_s + m.index_lookup_s + 1 / m.emit_rows_per_s)
         if env.orm_cache_enabled and n_misses:
-            pos = np.searchsorted(idx.sorted_keys, np.asarray(new_keys))
-            rows_idx = idx.order[pos]
+            pos = np.searchsorted(idx.keys.sorted, np.asarray(new_keys))
+            rows_idx = idx.keys.order[pos]
             for k, i in zip(new_keys, rows_idx.tolist()):
                 env._orm_cache[(e.target, k)] = t.row(int(i))
 
@@ -212,19 +219,10 @@ def make_hooks(cl) -> LoopHooks:
         if entry is None:
             raise KeyError(f"no prefetch cache for ({e.table}, {e.col})")
         keys = _broadcast(_eval_vec(e.keyexpr, ce), n)
-        ckeys, corder = entry["keys"], entry["order"]
-        if cl.backend == "kernels":
-            from ..kernels import ops
-            import jax.numpy as jnp
-            pos = np.asarray(ops.equi_probe(jnp.asarray(keys),
-                                            jnp.asarray(ckeys)))
-            cl.kernel_probes += 1
-        else:
-            pos = kref.join_probe_np(keys, ckeys)
-        if (pos < 0).any():
-            raise KeyError(f"cache lookup {e!r}: missing keys")
-        gidx = corder[pos]
         t = entry["table"]
+        gidx = _probe(cl, prefetch_keys(t, e.col), np.asarray(keys))
+        if (gidx < 0).any():
+            raise KeyError(f"cache lookup {e!r}: missing keys")
         cols = row_source(t)
         ce.rows[target] = {c: cols[c][gidx] for c in t.schema.names}
 
@@ -249,29 +247,27 @@ def make_hooks(cl) -> LoopHooks:
             if np.all(delta == np.floor(delta)) \
                     and float(np.abs(delta).sum()) < _EXACT_FP32:
                 total = _fold_sum(cl, delta)
-                if total is not None:
-                    # the interpreted tier exports col[-1].item() — a float
-                    state[acc] = float(state.get(acc, 0.0)) + total
-                    cl.kernel_folds += 1
-                    return
+                # the interpreted tier exports col[-1].item() — a float
+                state[acc] = float(state.get(acc, 0.0)) + total
+                return
         _vec_accumulate(ce, stmt, e, mask, state)
 
     return LoopHooks(nav=nav, cache_lookup=cache_lookup,
                      accumulate=accumulate, row_source=row_source)
 
 
-def _fold_sum(cl, delta: np.ndarray) -> Optional[float]:
-    """Total of ``delta`` via the segment-reduce kernel (one segment)."""
+def _fold_sum(cl, delta: np.ndarray) -> float:
+    """Total of ``delta`` via the segment-reduce kernel (one segment) as
+    ``kernels.ops`` dispatches it, or its numpy twin on the ``"numpy"``
+    backend; counted under the implementation that ran."""
+    segs = np.zeros(delta.shape[0], np.int32)
     if cl.backend == "kernels":
-        from ..kernels import ops
-        import jax.numpy as jnp
+        cl.kernel_calls["segment_reduce", ops.impl()] += 1
         out = ops.segment_reduce(jnp.asarray(delta, jnp.float32),
-                                 jnp.zeros(delta.shape[0], jnp.int32), 1,
-                                 op="sum")
+                                 jnp.asarray(segs), 1, op="sum")
         return float(np.asarray(out)[0])
-    out = kref.segment_reduce_np(delta, np.zeros(delta.shape[0], np.int64), 1,
-                                 op="sum")
-    return float(out[0])
+    cl.kernel_calls["segment_reduce", ops.REF] += 1
+    return float(kref.segment_reduce_np(delta, segs, 1, op="sum")[0])
 
 
 class SplicingInterpreter(Interpreter):

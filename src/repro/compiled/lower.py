@@ -6,8 +6,9 @@ asks :func:`~repro.core.regions.compilability` for the per-region verdict,
 and binds every ``"columnar"`` loop to a :class:`CompiledLoop` — the loop's
 precomputed :class:`~repro.core.vectorize.LoopPlan` plus kernel-backed
 :class:`~repro.core.vectorize.LoopHooks` (epoch-cached probe indices, the
-``join_probe``/``segment_reduce`` kernels through ``kernels.ops``, or the
-``kernels.ref`` numpy reference path when jax is not importable). Regions
+``join_probe``/``segment_reduce`` kernels as ``kernels.ops`` dispatches them
+for the platform, or the ``kernels.ref`` numpy twins when the ``"numpy"``
+backend is requested by name). Regions
 the analysis rejects — ``while`` guards, early exits, nested loops, update
 bodies — carry no binding and stay on the row-at-a-time interpreter; the
 :class:`~repro.compiled.exec.SplicingInterpreter` splices the compiled
@@ -32,6 +33,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import time
+from collections import Counter
 from typing import Dict, Optional, Tuple
 
 from ..core.fir import fold_accumulators
@@ -44,25 +46,21 @@ __all__ = ["CompiledLoop", "LoweredProgram", "lower_program",
 
 
 def available_backends() -> Tuple[str, ...]:
-    """Backends this process can lower to, preferred first."""
-    from .. import kernels
-    return ("kernels", "numpy") if kernels.HAS_JAX else ("numpy",)
+    """Backends a lowering can take, the default first."""
+    return ("kernels", "numpy")
 
 
 def resolve_backend(requested: Optional[str] = None) -> str:
-    """Pick the execution backend: ``"kernels"`` (jnp dispatch through
-    ``kernels.ops``, Pallas when ``ops.use_pallas`` is on) when jax is
-    importable, the ``kernels.ref`` numpy path otherwise. The
+    """Pick the execution backend: ``"kernels"`` (``kernels.ops``, which
+    runs the compiled Pallas kernels on the TPU and the jnp references
+    elsewhere) by default, or the ``kernels.ref`` numpy twins. The
     ``REPRO_COMPILED_BACKEND`` environment variable overrides the default;
     an explicit ``requested`` overrides both."""
-    avail = available_backends()
-    choice = requested or os.environ.get("REPRO_COMPILED_BACKEND") or avail[0]
-    if choice not in ("kernels", "numpy"):
+    choice = requested or os.environ.get("REPRO_COMPILED_BACKEND") \
+        or "kernels"
+    if choice not in available_backends():
         raise ValueError(f"unknown compiled backend {choice!r}; "
                          f"expected 'kernels' or 'numpy'")
-    if choice not in avail:
-        raise RuntimeError(f"backend {choice!r} unavailable "
-                           f"(jax not importable); available: {avail}")
     return choice
 
 
@@ -126,11 +124,12 @@ class CompiledLoop:
     backend: str
     fold_ops: Dict[str, str]          # F-IR cross-check result per accumulator
     kernel_fold_accs: frozenset       # accs eligible for a kernel fold
-    # execution telemetry (filled by the hooks in compiled.exec)
+    # execution telemetry (filled by the hooks in compiled.exec);
+    # kernel_calls counts (kernel, implementation that ran) pairs, the
+    # implementation being one of kernels.ops PALLAS / INTERPRET / REF
     executions: int = 0
-    kernel_probes: int = 0
-    kernel_folds: int = 0
     index_rebuilds: int = 0
+    kernel_calls: Counter = dataclasses.field(default_factory=Counter)
 
 
 class LoweredProgram:
@@ -160,6 +159,11 @@ class LoweredProgram:
     @property
     def n_columnar(self) -> int:
         return len(self._loops)
+
+    def kernel_calls(self) -> Counter:
+        """(kernel, implementation) call counts summed over the loops."""
+        return sum((cl.kernel_calls for cl in self._loops.values()),
+                   Counter())
 
     def run(self, env, params=None):
         """Execute on ``env`` through the splicing interpreter."""

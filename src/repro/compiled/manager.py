@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from collections import Counter
 from typing import Dict, FrozenSet, Optional, Tuple
 
 from ..api.cache import (ArtifactCache, program_fingerprint, program_sites,
@@ -152,6 +153,12 @@ class CompileManager:
         return n
 
     # ------------------------------------------------------------- telemetry
+    def kernel_calls(self) -> Counter:
+        """(kernel, implementation) call counts of the cached lowerings."""
+        return sum((art.lowered.kernel_calls()
+                    for art in self.artifacts.values()
+                    if art.lowered is not None), Counter())
+
     def telemetry(self) -> Dict[str, object]:
         t = {"backend": self.backend,
              "threshold": self.threshold,

@@ -2,28 +2,21 @@
 
   flash_attention — blocked online-softmax attention (causal/SWA/chunked/GQA)
   rwkv6_scan      — chunked WKV linear-attention scan (data-dependent decay)
-  segment_reduce  — relational γ group-by aggregation via one-hot MXU matmul
+  segment_reduce  — relational γ group-by aggregation (masked VPU fold)
   join_probe      — direct-address equi-join probe (application-side join)
 
-Each kernel has a pure-jnp oracle in ``ref.py``; ``ops.py`` is the jit'd
-dispatch layer. Kernels are validated in interpret mode on CPU
-(tests/test_kernels.py); on real TPUs pass interpret=False.
+Each kernel has a pure-jnp oracle in ``ref.py``. ``ops.py`` dispatches the
+relational kernels by platform: compiled Pallas on the TPU, the jnp
+reference elsewhere. Tests run the kernels in interpret mode on the CPU by
+passing ``interpret=True`` (tests/test_kernels.py), and compile them for a
+described TPU v5e (tests/test_tpu_compile.py).
 """
 
-from . import ref
-
-try:  # the Pallas kernels and their dispatch layer need jax
-    from . import ops
-    from .flash_attention import flash_attention
-    from .join_probe import build_direct_table, join_probe
-    from .rwkv6_scan import rwkv6_scan
-    from .segment_reduce import segment_reduce
-    HAS_JAX = True
-except ImportError:  # jax-free install: ref.py numpy fallbacks remain usable
-    ops = None
-    flash_attention = rwkv6_scan = segment_reduce = None
-    join_probe = build_direct_table = None
-    HAS_JAX = False
+from . import ops, ref
+from .flash_attention import flash_attention
+from .join_probe import build_direct_table, join_probe
+from .rwkv6_scan import rwkv6_scan
+from .segment_reduce import segment_reduce
 
 __all__ = ["ops", "ref", "flash_attention", "rwkv6_scan", "segment_reduce",
-           "join_probe", "build_direct_table", "HAS_JAX"]
+           "join_probe", "build_direct_table"]

@@ -1,64 +1,103 @@
-"""Jit'd public wrappers: kernel on TPU, reference elsewhere.
+"""Kernel dispatch: the compiled Pallas kernels on the TPU, the jnp
+references elsewhere.
 
-``use_pallas(True)`` flips dispatch to the Pallas kernels (interpret mode on
-CPU — used by the kernel tests; on a real TPU pod the launcher enables it
-with interpret=False). Default is the pure-jnp reference path so CPU smoke
-tests and the dry-run lower plain XLA HLO.
+The platform decides (:func:`impl_for`): on ``tpu`` the relational kernels
+run compiled (``interpret=False``); on any other platform the jnp
+references in ``ref.py`` run. Pallas interpret mode runs only when a test
+asks for it with ``use_pallas(True, interpret=True)``.
+
+A shape a kernel cannot take goes to the reference by a stated rule
+(:func:`direct_key_space` for the probe) and is reported as ``"ref"``, so
+callers that count kernel calls count what actually ran.
 """
 
 from __future__ import annotations
 
 from typing import Optional
 
+import jax
+import numpy as np
 
 from . import ref
-from .flash_attention import flash_attention as _flash_pallas
 from .join_probe import build_direct_table, join_probe as _probe_pallas
-from .rwkv6_scan import rwkv6_scan as _rwkv_pallas
 from .segment_reduce import segment_reduce as _segred_pallas
 
-_STATE = {"use_pallas": False, "interpret": True}
+PALLAS, INTERPRET, REF = "pallas", "interpret", "ref"
+
+# largest direct-address table the probe kernel is given: 16 MiB of slots,
+# 24 MiB of bf16 byte planes in HBM, tiled through VMEM by the kernel
+MAX_KEY_SPACE = 1 << 22
+
+# test override: None = the platform decides
+_FORCED = {"use_pallas": None, "interpret": False}
 
 
-def use_pallas(on: bool = True, interpret: bool = True) -> None:
-    _STATE["use_pallas"] = on
-    _STATE["interpret"] = interpret
+def use_pallas(on: Optional[bool], interpret: bool = False) -> None:
+    """Force the Pallas kernels on (``True``) or off (``False``) — tests use
+    this to run the kernels in interpret mode on the CPU. ``None`` hands
+    the choice back to the platform."""
+    _FORCED["use_pallas"] = on
+    _FORCED["interpret"] = interpret
 
 
 def pallas_state() -> tuple:
-    """Current dispatch state as ``(use_pallas, interpret)`` — read by the
-    compiled execution tier to pick its probe path."""
-    return (_STATE["use_pallas"], _STATE["interpret"])
+    """The override as ``(use_pallas, interpret)``; restore it with
+    ``use_pallas(*state)``."""
+    return (_FORCED["use_pallas"], _FORCED["interpret"])
 
 
-def attention(q, k, v, causal=True, window=None, chunk=None, scale=None,
-              block_q: int = 128, block_k: int = 128):
-    """q (B,H,Tq,hd), k/v (B,KV,Tk,hd)."""
-    if _STATE["use_pallas"]:
-        return _flash_pallas(q, k, v, causal=causal, window=window,
-                             chunk=chunk, scale=scale, block_q=block_q,
-                             block_k=block_k, interpret=_STATE["interpret"])
-    return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
-                                   chunk=chunk, scale=scale)
+def impl_for(platform: str) -> str:
+    """The dispatch rule: compiled Pallas on ``tpu``, the reference
+    elsewhere."""
+    return PALLAS if platform == "tpu" else REF
 
 
-def rwkv_scan(r, k, v, w_log, u, chunk: int = 64):
-    if _STATE["use_pallas"]:
-        return _rwkv_pallas(r, k, v, w_log, u, chunk=chunk,
-                            interpret=_STATE["interpret"])
-    return ref.rwkv6_scan_ref(r, k, v, w_log, u)
+def impl() -> str:
+    """The implementation the relational kernels take in this process."""
+    on = _FORCED["use_pallas"]
+    if on is None:
+        return impl_for(jax.default_backend())
+    if not on:
+        return REF
+    return INTERPRET if _FORCED["interpret"] else PALLAS
+
+
+def direct_key_space(sorted_keys: np.ndarray) -> Optional[int]:
+    """Key space of the direct-address table for these build keys (given
+    sorted), or None when the probe kernel cannot take them: keys that are
+    not integers, negative, not unique, or span more than
+    :data:`MAX_KEY_SPACE` slots."""
+    if sorted_keys.size == 0 \
+            or not np.issubdtype(sorted_keys.dtype, np.integer):
+        return None
+    lo, hi = int(sorted_keys[0]), int(sorted_keys[-1])
+    if lo < 0 or hi >= MAX_KEY_SPACE \
+            or (sorted_keys.size > 1 and not np.all(np.diff(sorted_keys))):
+        return None
+    return hi + 1
 
 
 def segment_reduce(values, segment_ids, num_segments: int, op: str = "sum"):
-    if _STATE["use_pallas"]:
+    how = impl()
+    if how != REF:
         return _segred_pallas(values, segment_ids, num_segments, op=op,
-                              interpret=_STATE["interpret"])
+                              interpret=how == INTERPRET)
     return ref.segment_reduce_ref(values, segment_ids, num_segments, op=op)
+
+
+def probe_impl(key_space: Optional[int]) -> str:
+    """What :func:`equi_probe` runs for a build side of ``key_space``
+    slots: the kernel's implementation when the key space is known and at
+    most :data:`MAX_KEY_SPACE`, the reference otherwise."""
+    if key_space is None or not 0 < key_space <= MAX_KEY_SPACE:
+        return REF
+    return impl()
 
 
 def equi_probe(probe_keys, table_keys, key_space: Optional[int] = None):
     """Index of each probe key's match in table_keys (-1 if absent)."""
-    if _STATE["use_pallas"] and key_space is not None and key_space <= (1 << 22):
+    how = probe_impl(key_space)
+    if how != REF:
         table = build_direct_table(table_keys, key_space)
-        return _probe_pallas(probe_keys, table, interpret=_STATE["interpret"])
+        return _probe_pallas(probe_keys, table, interpret=how == INTERPRET)
     return ref.join_probe_ref(probe_keys, table_keys)

@@ -1,8 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose ground truth).
 
-Also hosts the numpy fallbacks (``*_np``) used by the compiled execution
-tier when ``jax`` is not importable — those must stay importable without
-jax, hence the guarded import.
+Also hosts the numpy twins (``*_np``) that the compiled execution tier's
+``"numpy"`` backend runs when it is requested by name.
 """
 
 from __future__ import annotations
@@ -10,14 +9,9 @@ from __future__ import annotations
 import math
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
-
-try:  # pragma: no cover - exercised implicitly by import
-    import jax
-    import jax.numpy as jnp
-except Exception:  # jax optional: numpy fallbacks below still work
-    jax = None
-    jnp = None
 
 
 def flash_attention_ref(q, k, v, causal: bool = True,
@@ -98,7 +92,8 @@ def join_probe_ref(probe_keys, table_keys):
 
 
 def join_probe_np(probe_keys, table_keys):
-    """numpy twin of :func:`join_probe_ref` (jax-free compiled backend)."""
+    """numpy twin of :func:`join_probe_ref` (the ``"numpy"`` compiled
+    backend)."""
     probe_keys = np.asarray(probe_keys)
     table_keys = np.asarray(table_keys)
     n = probe_keys.shape[0]

@@ -76,7 +76,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_out_ref, state_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6_scan(r, k, v, w_log, u, chunk: int = 64, interpret: bool = True):
+def rwkv6_scan(r, k, v, w_log, u, chunk: int = 64, interpret: bool = False):
     """r/k/w_log: (B, H, T, K); v: (B, H, T, V); u: (H, K).
     Returns (y (B,H,T,V), final state (B,H,K,V) fp32).
 

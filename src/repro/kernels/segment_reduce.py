@@ -1,14 +1,21 @@
 """Segment reduction (relational γ group-by aggregation) for TPU via Pallas.
 
 Cobra's hottest relational operator after the join. The TPU adaptation of
-hash-based grouping (which needs pointer chasing — no TPU analogue): build a
-one-hot (Bn, G) membership tile from the segment-id block with an iota
-compare and reduce with a single (1, Bn) × (Bn, G) MXU matmul per block,
-accumulating into the (G,) output across the sequential grid. For min/max,
-the same membership tile drives a masked reduce (VPU).
+hash-based grouping (which needs pointer chasing — no TPU analogue): rows
+arrive lane-dense as ``(rows, 128)`` blocks, and for each row of 128 values
+the kernel compares their segment ids against a ``(block_g, 128)`` iota of
+group ids and folds the masked values into a ``(block_g, 128)`` accumulator
+on the VPU — groups on sublanes, row positions on lanes. The accumulator is
+the kernel's output; one reduction over the lanes outside the kernel gives
+each group's total. Larger G is tiled on the first grid axis.
 
-VMEM per step: Bn·G fp32 one-hot tile — with Bn = 256 and G ≤ 4096 that is
-4 MB; larger G is tiled on the second grid axis.
+The fold stays on the VPU in float32: integer-valued sums are exact while
+every partial sum stays below ``2**24``, whatever the order, which is the
+exactness the compiled tier's fold gate relies on (an MXU matmul at default
+precision would round the values to bf16 first).
+
+VMEM per step: two ``(block_n / 128, 128)`` input blocks and the
+``(block_g, 128)`` float32 accumulator — 512 KiB at ``block_g = 1024``.
 
 Validated in interpret mode against ``ref.segment_reduce_ref``.
 """
@@ -23,44 +30,50 @@ from jax.experimental import pallas as pl
 
 __all__ = ["segment_reduce"]
 
+_LANES = 128
+_IDENTITY = {"sum": 0.0, "min": jnp.inf, "max": -jnp.inf}
 
-def _kernel(v_ref, s_ref, o_ref, *, op, block_n, block_g, n_blocks):
-    ni = pl.program_id(1)
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _kernel(v_ref, s_ref, o_ref, *, op, rows, block_g):
     gi = pl.program_id(0)
+    ni = pl.program_id(1)
 
     @pl.when(ni == 0)
     def _init():
-        if op in ("sum", "count"):
-            o_ref[...] = jnp.zeros_like(o_ref)
-        elif op == "min":
-            o_ref[...] = jnp.full_like(o_ref, jnp.inf)
-        else:
-            o_ref[...] = jnp.full_like(o_ref, -jnp.inf)
+        o_ref[...] = jnp.full_like(o_ref, _IDENTITY[op])
 
-    vals = v_ref[...].astype(jnp.float32)              # (Bn,)
-    segs = s_ref[...]                                  # (Bn,)
-    g0 = gi * block_g
-    onehot = (segs[:, None] == (g0 + jax.lax.broadcasted_iota(
-        jnp.int32, (block_n, block_g), 1))).astype(jnp.float32)
-    if op == "sum":
-        o_ref[...] += (vals[None, :] @ onehot)[0]      # MXU (1,Bn)x(Bn,G)
-    elif op == "count":
-        o_ref[...] += jnp.sum(onehot, axis=0)
-    elif op == "min":
-        masked = jnp.where(onehot > 0, vals[:, None], jnp.inf)
-        o_ref[...] = jnp.minimum(o_ref[...], jnp.min(masked, axis=0))
-    else:  # max
-        masked = jnp.where(onehot > 0, vals[:, None], -jnp.inf)
-        o_ref[...] = jnp.maximum(o_ref[...], jnp.max(masked, axis=0))
+    gids = gi * block_g + jax.lax.broadcasted_iota(
+        jnp.int32, (block_g, _LANES), 0)
+
+    def fold_row(j, carry):
+        vals = v_ref[pl.ds(j, 1), :]                           # (1, 128)
+        hit = gids == s_ref[pl.ds(j, 1), :]                    # (Bg, 128)
+        if op == "sum":
+            o_ref[...] += jnp.where(hit, vals, 0.0)
+        elif op == "min":
+            o_ref[...] = jnp.minimum(o_ref[...], jnp.where(hit, vals, jnp.inf))
+        else:
+            o_ref[...] = jnp.maximum(o_ref[...],
+                                     jnp.where(hit, vals, -jnp.inf))
+        return carry
+
+    jax.lax.fori_loop(0, rows, fold_row, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "op", "block_n",
                                              "block_g", "interpret"))
 def segment_reduce(values, segment_ids, num_segments: int, op: str = "sum",
-                   block_n: int = 256, block_g: int = 512,
-                   interpret: bool = True):
+                   block_n: int = 65536, block_g: int = 1024,
+                   interpret: bool = False):
     """values (N,) float; segment_ids (N,) int32 in [0, num_segments).
-    Returns (num_segments,) float32 aggregation."""
+    Returns (num_segments,) float32 aggregation. ``block_n`` rows and
+    ``block_g`` groups go through each grid step."""
+    if op not in ("sum", "count", "min", "max"):
+        raise ValueError(op)
     N = values.shape[0]
     if num_segments == 0:
         return jnp.zeros((0,), jnp.float32)
@@ -68,31 +81,35 @@ def segment_reduce(values, segment_ids, num_segments: int, op: str = "sum",
         # every group is empty: sum/count identity is 0, and the min/max
         # convention below maps empty groups to 0 as well
         return jnp.zeros((num_segments,), jnp.float32)
-    bn = min(block_n, N)
-    pad = (-N) % bn
-    if pad:
-        values = jnp.pad(values, (0, pad))
-        # padded rows point at an out-of-range segment → never matched
-        segment_ids = jnp.pad(segment_ids, (0, pad),
-                              constant_values=num_segments + block_g)
-    Np = N + pad
-    bg = min(block_g, num_segments)
-    gpad = (-num_segments) % bg
-    G = num_segments + gpad
+    if op == "count":
+        values, op = jnp.ones((N,), jnp.float32), "sum"
+    rows = pl.cdiv(N, _LANES)
+    block_rows = max(8, _round_up(pl.cdiv(block_n, _LANES), 8))
+    if rows <= block_rows:
+        block_rows = rows                   # one block spans the whole array
+    rows_p = _round_up(rows, block_rows)
+    pad = rows_p * _LANES - N
+    values = jnp.pad(values.astype(jnp.float32), (0, pad)) \
+        .reshape(rows_p, _LANES)
+    # padded rows carry segment -1, which no group id matches
+    segment_ids = jnp.pad(segment_ids.astype(jnp.int32), (0, pad),
+                          constant_values=-1).reshape(rows_p, _LANES)
+    bg = _round_up(min(block_g, num_segments), 8)
+    G = _round_up(num_segments, bg)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, op=op, block_n=bn, block_g=bg,
-                          n_blocks=Np // bn),
-        grid=(G // bg, Np // bn),
+    acc = pl.pallas_call(
+        functools.partial(_kernel, op=op, rows=block_rows, block_g=bg),
+        grid=(G // bg, rows_p // block_rows),
         in_specs=[
-            pl.BlockSpec((bn,), lambda gi, ni: (ni,)),
-            pl.BlockSpec((bn,), lambda gi, ni: (ni,)),
+            pl.BlockSpec((block_rows, _LANES), lambda gi, ni: (ni, 0)),
+            pl.BlockSpec((block_rows, _LANES), lambda gi, ni: (ni, 0)),
         ],
-        out_specs=pl.BlockSpec((bg,), lambda gi, ni: (gi,)),
-        out_shape=jax.ShapeDtypeStruct((G,), jnp.float32),
+        out_specs=pl.BlockSpec((bg, _LANES), lambda gi, ni: (gi, 0)),
+        out_shape=jax.ShapeDtypeStruct((G, _LANES), jnp.float32),
         interpret=interpret,
-    )(values, segment_ids.astype(jnp.int32))
-    out = out[:num_segments]
+    )(values, segment_ids)
+    reduce = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}[op]
+    out = reduce(acc, axis=1)[:num_segments]
     if op in ("min", "max"):
         out = jnp.where(jnp.isfinite(out), out, 0.0)  # empty groups → 0
     return out

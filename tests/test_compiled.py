@@ -19,12 +19,14 @@ Issue acceptance:
 
 import types
 
+import numpy as np
 import pytest
 
 from repro.api import CobraSession, OptimizerConfig
 from repro.compiled import (CompileManager, available_backends, lower_program,
                             resolve_backend)
 from repro.core import CostCatalog
+from repro.kernels import ops
 from repro.programs import (make_m0, make_orders_customer_db, make_p0,
                             make_p1, make_p2, make_sales_db, make_scan,
                             make_wilos_a, make_wilos_b, make_wilos_c,
@@ -178,6 +180,73 @@ class TestLowering:
         exe = sess.compile(make_p0())
         with pytest.raises(ValueError):
             exe.run_batch([{}], tier="gpu")
+
+
+# --------------------------------------------------------------------------
+# Kernel calls: counted under the implementation that actually ran
+# --------------------------------------------------------------------------
+
+class TestKernelCalls:
+    # (program, rules, kernel the compiled loop reaches): P2's prefetch
+    # lookups and the unrewritten P0's ORM navigation both probe; the
+    # unrewritten W_B's integer count folds (rules=() keeps a program as
+    # written)
+    CASES = {
+        "lookup": (make_p2, None, "join_probe"),
+        "navigation": (make_p0, (), "join_probe"),
+        "fold": (make_wilos_b, (), "segment_reduce"),
+    }
+
+    def run(self, case, monkeypatch=None, backend=None):
+        make, rules, kernel = self.CASES[case]
+        db = make_wilos_db(200) if case == "fold" \
+            else make_orders_customer_db(300, 30)
+        sess = session(db)
+        config = None if rules is None else OptimizerConfig(rules=rules)
+        exe = sess.compile(make(), config=config)
+        if backend is not None:
+            monkeypatch.setenv("REPRO_COMPILED_BACKEND", backend)
+        interp = exe.run_batch([{}] * 2, tier="interpreter")
+        comp = exe.run_batch([{}] * 2, tier="compiled")
+        assert_batches_identical(interp, comp)
+        return kernel, exe.lower().kernel_calls()
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_cpu_counts_reference_calls(self, case):
+        kernel, calls = self.run(case)
+        assert calls == {(kernel, ops.REF): 2}
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_numpy_backend_counts_reference_calls(self, case, monkeypatch):
+        kernel, calls = self.run(case, monkeypatch, backend="numpy")
+        assert calls == {(kernel, ops.REF): 2}
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_forced_kernels_count_interpret_calls(self, case):
+        state = ops.pallas_state()
+        try:
+            ops.use_pallas(True, interpret=True)
+            kernel, calls = self.run(case)
+        finally:
+            ops.use_pallas(*state)
+        assert calls == {(kernel, ops.INTERPRET): 2}
+
+    def test_keys_outside_the_rule_count_as_reference(self):
+        # a duplicate build key: no direct-address table can hold it, so
+        # even with the kernel forced on the probe takes the reference
+        db = make_orders_customer_db(300, 30)
+        cust = db.table("customer")
+        db.replace_table(cust.take(np.r_[np.arange(cust.nrows), 0]))
+        exe = session(db).compile(make_p2())
+        state = ops.pallas_state()
+        try:
+            ops.use_pallas(True, interpret=True)
+            interp = exe.run_batch([{}], tier="interpreter")
+            comp = exe.run_batch([{}], tier="compiled")
+        finally:
+            ops.use_pallas(*state)
+        assert_batches_identical(interp, comp)
+        assert exe.lower().kernel_calls() == {("join_probe", ops.REF): 1}
 
 
 # --------------------------------------------------------------------------

@@ -75,7 +75,10 @@ print(json.dumps(out))
 
 @pytest.fixture(scope="module")
 def results():
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # a CPU dry-run: the child never reaches for the chip, which belongs
+    # to one process at a time
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=900)

@@ -1,5 +1,5 @@
 """Golden parity: Pallas kernels (interpret mode) vs the numpy reference
-path (``kernels/ref.py``) the jax-free compiled backend executes.
+path (``kernels/ref.py``) the "numpy" compiled backend executes.
 
 The compiled execution tier promises bit-identical results whichever
 backend serves a columnar loop, so the kernels themselves must agree with
