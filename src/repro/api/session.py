@@ -287,6 +287,10 @@ class CobraSession:
         # write below (the descriptors route attribute writes through it)
         self.metrics = MetricsRegistry()
         self.tracer = tracer if tracer is not None else NOOP_TRACER
+        if tracer is not None and not db.tracer.enabled:
+            # the server's spans join the session's tree unless the server
+            # was given a tracer of its own
+            db.tracer = tracer
         self.catalog = catalog if catalog is not None else CostCatalog(SLOW_REMOTE)
         self.config = config if config is not None else OptimizerConfig()
         # default ExecutionContext compiles are costed for (one-shot unless

@@ -57,7 +57,6 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..obs.trace import NOOP_TRACER
 from ..relational.algebra import (Aggregate, AggSpec, BoolOp, Cmp, Col, Join,
                                   Limit, Lit, OrderBy, Param, Project, Query,
                                   Scan, Select, scan_tables)
@@ -96,8 +95,8 @@ class ShardedDatabase(DatabaseServer):
         # base init computes GLOBAL stats over the unsharded tables and
         # calls the (guarded) analyze(); cluster structures come after
         self._cluster_ready = False
-        super().__init__(tables, model, stats_config=stats_config)
-        self.tracer = tracer if tracer is not None else NOOP_TRACER
+        super().__init__(tables, model, stats_config=stats_config,
+                         tracer=tracer)
         self.partitioner = Partitioner(n_shards, keys)
         self.n_shards = n_shards
         self.merge_rows_per_s = merge_rows_per_s or model.agg_rows_per_s

@@ -43,6 +43,8 @@ from ..core.vectorize import (LoopHooks, _broadcast, _eval_vec,
 from ..kernels import ops
 from ..kernels import ref as kref
 from ..kernels.join_probe import build_direct_table, join_probe
+from ..obs.trace import NOOP_TRACER
+from ..obs.transfer import to_device, to_host
 from ..relational.table import Table
 
 __all__ = ["SplicingInterpreter", "make_hooks"]
@@ -83,7 +85,8 @@ class _TableMemo:
 
 
 def _columns(t: Table):
-    return {c: np.asarray(t.column(c)) for c in t.schema.names}
+    return {c: to_host(t.column(c), "compiled.columns")
+            for c in t.schema.names}
 
 
 class _BuildKeys:
@@ -94,7 +97,7 @@ class _BuildKeys:
     __slots__ = ("order", "sorted", "space", "direct")
 
     def __init__(self, t: Table, col: str):
-        keys = np.asarray(t.column(col))
+        keys = to_host(t.column(col), "compiled.build_keys")
         self.order = np.argsort(keys, kind="stable")
         self.sorted = keys[self.order]
         self.space = ops.direct_key_space(self.sorted)
@@ -135,6 +138,13 @@ class _ProbeIndexCache:
         return idx
 
 
+def _probe_impl(cl, bk: _BuildKeys, keys: np.ndarray) -> str:
+    """The implementation :func:`_probe` runs for these keys."""
+    if cl.backend == "kernels" and np.issubdtype(keys.dtype, np.integer):
+        return ops.probe_impl(bk.space)
+    return ops.REF
+
+
 def _probe(cl, bk: _BuildKeys, keys: np.ndarray) -> np.ndarray:
     """Build-side row (an entry of ``bk.order``) for each key, -1 on miss.
 
@@ -145,20 +155,19 @@ def _probe(cl, bk: _BuildKeys, keys: np.ndarray) -> np.ndarray:
     integers, other platforms and the ``"numpy"`` backend search the sorted
     keys on the host instead — the same values. Either way the call is
     counted under the implementation that ran."""
-    how = ops.REF
-    if cl.backend == "kernels" and np.issubdtype(keys.dtype, np.integer):
-        how = ops.probe_impl(bk.space)
+    how = _probe_impl(cl, bk, keys)
     cl.kernel_calls["join_probe", how] += 1
     if bk.sorted.shape[0] == 0:
         return np.full(keys.shape, -1, np.int32)
     if how != ops.REF:
         if bk.direct is None:
-            bk.direct = build_direct_table(jnp.asarray(bk.sorted, jnp.int32),
-                                           bk.space)
+            bk.direct = build_direct_table(
+                to_device(bk.sorted, jnp.int32, "compiled.probe"), bk.space)
         # out-of-range keys miss; clamp them before narrowing to int32
         keys = np.where((keys >= 0) & (keys < bk.space), keys, -1)
-        pos = np.asarray(join_probe(jnp.asarray(keys, jnp.int32), bk.direct,
-                                    interpret=how == ops.INTERPRET))
+        pos = to_host(join_probe(to_device(keys, jnp.int32, "compiled.probe"),
+                                 bk.direct, interpret=how == ops.INTERPRET),
+                      "compiled.probe")
     else:
         pos = np.clip(np.searchsorted(bk.sorted, keys), 0,
                       bk.sorted.shape[0] - 1)
@@ -179,12 +188,18 @@ def make_hooks(cl) -> LoopHooks:
     # so the direct-address table is built once and not per invocation
     prefetch_keys = _TableMemo(_BuildKeys, _PROBE_INDEX_CAP)
 
+    def probe(env, bk, keys):
+        tracer = getattr(env, "tracer", NOOP_TRACER)
+        with tracer.span("compiled.probe", n=keys.shape[0],
+                         impl=_probe_impl(cl, bk, keys)):
+            return _probe(cl, bk, keys)
+
     # ------------------------------------------------------------------ nav
     def nav(env, ce, target, e, n):
         base = ce.rows[e.base.name]
         keys = np.asarray(base[e.fk_field])
         idx = probe_cache.get(env, e.target, e.target_key)
-        gidx = _probe(cl, idx.keys, keys)
+        gidx = probe(env, idx.keys, keys)
         if (gidx < 0).any():
             raise KeyError(f"navigation {e!r}: missing keys (FK violation)")
         ce.rows[target] = {c: idx.cols[c][gidx] for c in idx.table.schema.names}
@@ -220,7 +235,7 @@ def make_hooks(cl) -> LoopHooks:
             raise KeyError(f"no prefetch cache for ({e.table}, {e.col})")
         keys = _broadcast(_eval_vec(e.keyexpr, ce), n)
         t = entry["table"]
-        gidx = _probe(cl, prefetch_keys(t, e.col), np.asarray(keys))
+        gidx = probe(env, prefetch_keys(t, e.col), np.asarray(keys))
         if (gidx < 0).any():
             raise KeyError(f"cache lookup {e!r}: missing keys")
         cols = row_source(t)
@@ -263,9 +278,10 @@ def _fold_sum(cl, delta: np.ndarray) -> float:
     segs = np.zeros(delta.shape[0], np.int32)
     if cl.backend == "kernels":
         cl.kernel_calls["segment_reduce", ops.impl()] += 1
-        out = ops.segment_reduce(jnp.asarray(delta, jnp.float32),
-                                 jnp.asarray(segs), 1, op="sum")
-        return float(np.asarray(out)[0])
+        out = ops.segment_reduce(
+            to_device(delta, jnp.float32, "compiled.fold_sum"),
+            to_device(segs, None, "compiled.fold_sum"), 1, op="sum")
+        return float(to_host(out, "compiled.fold_sum")[0])
     cl.kernel_calls["segment_reduce", ops.REF] += 1
     return float(kref.segment_reduce_np(delta, segs, 1, op="sum")[0])
 
@@ -290,16 +306,15 @@ class SplicingInterpreter(Interpreter):
             if cl is not None:
                 src = self.eval(r.source, state)
                 if isinstance(src, Table) and src.nrows > 0:
-                    exec_loop_plan(self.env, r, src, state, cl.plan,
-                                   hooks=cl.hooks)
+                    env = self.env
+                    tracer = getattr(env, "tracer", NOOP_TRACER)
+                    with tracer.span("compiled.loop",
+                                     sim_clock=lambda: env.clock,
+                                     loop_var=r.var, rows=src.nrows):
+                        exec_loop_plan(env, r, src, state, cl.plan,
+                                       hooks=cl.hooks)
                     cl.executions += 1
                     self.lowered.columnar_execs += 1
-                    tracer = getattr(self.env, "tracer", None)
-                    if tracer is not None and tracer.enabled:
-                        tracer.event(
-                            "kernel-invoke", sim=self.env.clock,
-                            loop_var=r.var, rows=src.nrows,
-                            backend=self.lowered.backend)
                     return
                 # run-time fallback (empty or non-table source): the exact
                 # path also records collection-loop iteration observations

@@ -28,6 +28,8 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import NOOP_TRACER
+from ..obs.transfer import to_host
 from ..relational.table import Table
 from .regions import (Assign, BasicBlock, BreakStmt, CollectionAdd, CondRegion,
                       ContinueStmt, IBin, ICacheLookup, ICall, IConst, IField,
@@ -253,7 +255,8 @@ class LoopHooks:
 
 
 def _default_row_source(src: Table) -> Dict[str, np.ndarray]:
-    return {c: np.asarray(src.column(c)) for c in src.schema.names}
+    return {c: to_host(src.column(c), "vectorize.row_source")
+            for c in src.schema.names}
 
 
 def try_exec_loop_fast(interp, r: LoopRegion, src, state: Dict[str, object]) -> bool:
@@ -279,6 +282,7 @@ def exec_loop_plan(env, r: LoopRegion, src: Table, state: Dict[str, object],
     cache_lookup = hooks.cache_lookup or _vec_cache_lookup
     accumulate = hooks.accumulate or _vec_accumulate
     row_source = hooks.row_source or _default_row_source
+    tracer = getattr(env, "tracer", NOOP_TRACER)
     n = src.nrows
     ce = _ColEnv(n, state)
     ce.rows[r.var] = row_source(src)
@@ -317,8 +321,8 @@ def exec_loop_plan(env, r: LoopRegion, src: Table, state: Dict[str, object],
         if isinstance(stmt, CollectionAdd):
             vals = _broadcast(_eval_vec(stmt.expr, ce), n)
             sel = vals[mask] if guard is not None else vals
-            state.setdefault(stmt.target, [])
-            state[stmt.target].extend(sel.tolist())
+            with tracer.span("loop.export", n=len(sel)):
+                state.setdefault(stmt.target, []).extend(sel.tolist())
             env.charge_statement(nexec)
             continue
         if isinstance(stmt, MapPut):
@@ -326,9 +330,10 @@ def exec_loop_plan(env, r: LoopRegion, src: Table, state: Dict[str, object],
             vals = _broadcast(_eval_vec(stmt.valexpr, ce), n)
             if guard is not None:
                 keys, vals = keys[mask], vals[mask]
-            d = state.setdefault(stmt.target, {})
-            for k, v in zip(keys.tolist(), vals.tolist()):
-                d[k] = v
+            with tracer.span("loop.export", n=len(keys)):
+                d = state.setdefault(stmt.target, {})
+                for k, v in zip(keys.tolist(), vals.tolist()):
+                    d[k] = v
             env.charge_statement(nexec)
             continue
         if isinstance(stmt, UpdateRow):
@@ -351,7 +356,7 @@ def _vec_nav(env, ce: _ColEnv, target: str, e: INav, n: int) -> None:
     base = ce.rows[e.base.name]
     keys = np.asarray(base[e.fk_field])
     t = env.db.table(e.target)
-    tkeys = np.asarray(t.column(e.target_key))
+    tkeys = to_host(t.column(e.target_key), "vectorize.nav")
     order = np.argsort(tkeys, kind="stable")
     pos = np.searchsorted(tkeys[order], keys)
     pos = np.clip(pos, 0, len(order) - 1)
@@ -359,7 +364,8 @@ def _vec_nav(env, ce: _ColEnv, target: str, e: INav, n: int) -> None:
     found = tkeys[gidx] == keys
     if not found.all():
         raise KeyError(f"navigation {e!r}: missing keys (FK violation)")
-    ce.rows[target] = {c: np.asarray(t.column(c))[gidx] for c in t.schema.names}
+    ce.rows[target] = {c: to_host(t.column(c), "vectorize.nav")[gidx]
+                       for c in t.schema.names}
     # ORM cache accounting: first occurrence of an uncached key = point query;
     # every other occurrence = cache hit (1 statement).
     uniq, first_idx = np.unique(keys, return_index=True)
@@ -401,7 +407,8 @@ def _vec_cache_lookup(env, ce: _ColEnv, target: str, e: ICacheLookup, n: int) ->
         raise KeyError(f"cache lookup {e!r}: missing keys")
     gidx = corder[pos]
     t = entry["table"]
-    ce.rows[target] = {c: np.asarray(t.column(c))[gidx] for c in t.schema.names}
+    ce.rows[target] = {c: to_host(t.column(c), "vectorize.cache_lookup")[gidx]
+                       for c in t.schema.names}
 
 
 def _vec_accumulate(ce: _ColEnv, stmt: Assign, e: IBin, mask, state) -> None:
@@ -435,8 +442,8 @@ def _vec_update(env, ce: _ColEnv, stmt: UpdateRow, mask, n: int) -> None:
         env._charge_query(1, 16, m.startup_s + m.index_lookup_s,
                           m.startup_s + m.index_lookup_s)
     t = env.db.table(stmt.table)
-    arr = np.asarray(t.column(stmt.key_col))
-    col = np.asarray(t.column(stmt.set_col)).copy()
+    arr = to_host(t.column(stmt.key_col), "vectorize.update")
+    col = to_host(t.column(stmt.set_col), "vectorize.update").copy()
     order = np.argsort(arr, kind="stable")
     pos = np.searchsorted(arr[order], keys)
     pos = np.clip(pos, 0, len(order) - 1)

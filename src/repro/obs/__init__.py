@@ -1,14 +1,19 @@
 """Serving observability: trace spans, metrics registry, plan diagnostics.
 
-Four pieces, threaded through every tier of the framework:
+Five pieces, threaded through every tier of the framework:
 
   * :mod:`repro.obs.trace` — nested wall+simulated-clock spans (compile →
-    saturation rounds; serve → batch → site fetch → kernel invoke → swap
+    saturation rounds; serving.serve → batch → server.run,
+    client.cache_by_column, client.lookup, compiled.loop → compiled.probe,
+    loop.export; serving.feedback → server.analyze, recompiles, swap
     verdicts), JSONL export, text flamegraph rendering; a no-op tracer by
     default so the hot path pays only a branch;
   * :mod:`repro.obs.metrics` — labeled counters/gauges/histograms with
     ``snapshot()``/``diff()``; the legacy telemetry dicts are
     backwards-compatible views over per-component registries;
+  * :mod:`repro.obs.transfer` — the counted host↔device transfers of the
+    data path (``to_host``/``to_device``): host reads and bytes each way,
+    per site, in one process-wide registry;
   * :mod:`repro.obs.explain` / :mod:`repro.obs.signals` — ``explain()``
     renders the winning region tree annotated with estimated cost, rule
     provenance, estimated-vs-observed counts and q-error; ``scan_plan()``

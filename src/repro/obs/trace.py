@@ -1,12 +1,19 @@
 """Trace spans: nested wall-clock + simulated-clock timing, near-free off.
 
-A :class:`Tracer` records a tree of :class:`Span`\\ s — compile → rule
-saturation rounds → costing; serve → batch → site fetch / cache hit →
-compiled-kernel invoke → swap verdicts. Each span carries wall time
-(``perf_counter``) and, when the caller passes a ``sim_clock`` callable
-(e.g. ``lambda: env.clock``), the simulated clock interval too. Export as
-JSONL (:meth:`Tracer.export_jsonl`) or render a text flamegraph-style tree
-(:meth:`Tracer.render`).
+A :class:`Tracer` records a tree of :class:`Span`\\ s::
+
+    compile → build-memo, saturate → saturate-round, search, codegen
+    serving.serve → lowering
+                  → batch → server.run
+                          → client.cache_by_column, client.lookup
+                          → compiled.loop → compiled.probe, loop.export
+                  → serving.feedback → server.analyze, compile,
+                                       swap-verdict
+
+Each span carries wall time (``perf_counter``) and, when the caller passes
+a ``sim_clock`` callable (e.g. ``lambda: env.clock``), the simulated clock
+interval too. Export as JSONL (:meth:`Tracer.export_jsonl`) or render a
+text flamegraph-style tree (:meth:`Tracer.render`).
 
 The default everywhere is the module singleton :data:`NOOP_TRACER`: its
 ``span()`` returns a shared no-op handle, so an instrumented hot path pays

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.transfer import to_device, to_host
 from .table import Field, Schema, Table
 
 __all__ = [
@@ -346,7 +347,7 @@ class Select(Query):
         if t.nrows == 0:
             return t
         mask = self.pred.eval(t, params)
-        return t.filter_mask(np.asarray(mask))
+        return t.filter_mask(to_host(mask, "algebra.select"))
 
     def key(self):
         return ("select", self.pred.key(), self.child.key())
@@ -374,7 +375,8 @@ class Project(Query):
         out = t.select_columns([c for c in self.cols]) if self.cols else t.select_columns([])
         for name, expr in self.computed:
             vals = expr.eval(t, params)
-            out = out.with_column(Field(name, str(np.asarray(vals).dtype)), vals)
+            dt = str(to_host(vals, "algebra.project").dtype)
+            out = out.with_column(Field(name, dt), vals)
         return out
 
     def key(self):
@@ -406,8 +408,9 @@ class Join(Query):
     def execute(self, db, params=None):
         lt = self.left.execute(db, params)
         rt = self.right.execute(db, params)
-        li, ri = equi_join_indices(np.asarray(lt.column(self.left_key)),
-                                   np.asarray(rt.column(self.right_key)))
+        li, ri = equi_join_indices(
+            to_host(lt.column(self.left_key), "algebra.join"),
+            to_host(rt.column(self.right_key), "algebra.join"))
         lsel = lt.take(li)
         rsel = rt.take(ri)
         # disambiguate duplicate names by prefixing right side
@@ -472,13 +475,15 @@ class Aggregate(Query):
                     val, dt = 0, "float32"
                 else:
                     val = _AGG_FUNCS[a.func](arr)
-                    dt = "float32" if a.func == "avg" else str(np.asarray(val).dtype)
+                    dt = "float32" if a.func == "avg" else str(
+                        to_host(val, "algebra.aggregate").dtype)
             fields.append(Field(a.out, dt))
             cols[a.out] = np.asarray([val], dtype=np.dtype(dt) if np.dtype(dt).itemsize<8 else np.dtype(dt.replace("64","32")))
         return Table("agg", Schema(tuple(fields)), cols)
 
     def _grouped(self, t: Table) -> Table:
-        keys = [np.asarray(t.column(g)) for g in self.group_by]
+        keys = [to_host(t.column(g), "algebra.aggregate")
+                for g in self.group_by]
         if t.nrows == 0:
             uniq_idx = np.asarray([], dtype=np.int64)
             inv = np.asarray([], dtype=np.int64)
@@ -495,8 +500,8 @@ class Aggregate(Query):
                 if tf.name == g:
                     f = tf
             fields.append(f)
-            cols[g] = np.asarray(t.column(g))[uniq_idx]
-        seg = jnp.asarray(inv)
+            cols[g] = to_host(t.column(g), "algebra.aggregate")[uniq_idx]
+        seg = to_device(inv, None, "algebra.aggregate")
         for a in self.aggs:
             if a.func == "count":
                 vals = jax.ops.segment_sum(jnp.ones((t.nrows,), jnp.int32), seg, ngroups)
@@ -515,7 +520,8 @@ class Aggregate(Query):
                     vals = s / jnp.maximum(c, 1.0)
                 else:
                     raise ValueError(a.func)
-                dt = "float32" if a.func == "avg" else str(np.asarray(vals).dtype)
+                dt = "float32" if a.func == "avg" else str(
+                    to_host(vals, "algebra.aggregate").dtype)
             fields.append(Field(a.out, dt))
             cols[a.out] = vals
         return Table("agg", Schema(tuple(fields)), cols)

@@ -32,6 +32,8 @@ from typing import Dict, Mapping, Optional, Tuple
 
 import numpy as np
 
+from ..obs.trace import NOOP_TRACER
+from ..obs.transfer import to_host
 from .algebra import (Aggregate, Join, Limit, OrderBy, Project, Query, Scan,
                       Select)
 from .table import Table
@@ -125,10 +127,13 @@ _INSTANCE_TOKENS = itertools.count(1)
 
 class DatabaseServer:
     def __init__(self, tables: Dict[str, Table], model: ServerModel = ServerModel(),
-                 stats_config=None):
+                 stats_config=None, tracer=None):
         from ..stats.histogram import DEFAULT_STATS_CONFIG
         self.tables = dict(tables)
         self.model = model
+        # spans of query execution and ANALYZE (``server.run``,
+        # ``server.analyze``); a session adopts a server without a tracer
+        self.tracer = tracer if tracer is not None else NOOP_TRACER
         self.stats_config = stats_config if stats_config is not None \
             else DEFAULT_STATS_CONFIG
         # process-unique identity: result caches shared across sessions key
@@ -225,11 +230,13 @@ class DatabaseServer:
         which is what the feedback controller's q-error path requests
         when one site's estimate went bad."""
         names = tables or tuple(self.tables)
-        for name in names:
-            self._stats[name] = self._compute_stats(
-                self.tables[name], columns=columns,
-                prev=self._stats.get(name) if columns else None)
-            self._table_versions[name] = self._table_versions.get(name, 0) + 1
+        with self.tracer.span("server.analyze", tables=names):
+            for name in names:
+                self._stats[name] = self._compute_stats(
+                    self.tables[name], columns=columns,
+                    prev=self._stats.get(name) if columns else None)
+                self._table_versions[name] = \
+                    self._table_versions.get(name, 0) + 1
         self._stats_version += 1
         return self._stats_version
 
@@ -240,7 +247,7 @@ class DatabaseServer:
         distinct, minmax, hists = {}, {}, {}
         want = None if columns is None else set(columns)
         for f in t.schema.fields:
-            arr = np.asarray(t.column(f.name))
+            arr = to_host(t.column(f.name), "database.analyze")
             if arr.size:
                 distinct[f.name] = int(len(np.unique(arr)))
                 minmax[f.name] = (float(arr.min()), float(arr.max()))
@@ -267,8 +274,12 @@ class DatabaseServer:
     def run(self, query: Query, params: Optional[Mapping[str, object]] = None
             ) -> Tuple[Table, float, float]:
         """Execute and return (result, true C_Q^F, true C_Q^L)."""
-        result = query.execute(self, params)
-        first, last = self._true_times(query, params)
+        tracer = self.tracer
+        with tracer.span("server.run") as sp:
+            if tracer.enabled:
+                sp.attrs["sql"] = query.sql()
+            result = query.execute(self, params)
+            first, last = self._true_times(query, params)
         return result, first, last
 
     def _true_times(self, q: Query, params) -> Tuple[float, float]:
@@ -425,6 +436,11 @@ class ClientEnv:
         C_Q = C_NRT + C_Q^F + max(N_Q*S_row/BW, C_Q^L − C_Q^F)
     """
 
+    # the prefetch cache's build and lookups open ``client.cache_by_column``
+    # and ``client.lookup`` spans on it; a batching env carries the
+    # session's tracer
+    tracer = NOOP_TRACER
+
     def __init__(self, db: DatabaseServer, network: NetworkProfile,
                  c_z: float = 30e-9, orm_cache: bool = True):
         self.db = db
@@ -472,7 +488,7 @@ class ClientEnv:
             return self._orm_cache[ck]
         t = self.db.table(table)
         # index lookup: server time is one B-tree probe, one row out
-        arr = np.asarray(t.column(key_col))
+        arr = to_host(t.column(key_col), "database.point_lookup")
         idx = np.flatnonzero(arr == key_val)
         m = self.db.model
         self._charge_query(len(idx), t.row_bytes,
@@ -489,12 +505,13 @@ class ClientEnv:
     # --------------------------------------------------- prefetch cache (N1)
     def cache_by_column(self, t: Table, col: str) -> None:
         """``Utils.cacheByColumn`` from the paper (footnote 3)."""
-        index: Dict[object, list] = {}
-        arr = np.asarray(t.column(col))
         # building the local hash index costs C_Z per row
         self.charge_statement(t.nrows)
-        order = np.argsort(arr, kind="stable")
-        sorted_keys = arr[order]
+        with self.tracer.span("client.cache_by_column", table=t.name,
+                              rows=t.nrows):
+            arr = to_host(t.column(col), "database.cache_by_column")
+            order = np.argsort(arr, kind="stable")
+            sorted_keys = arr[order]
         # store as (table, sorted keys, order) for O(log n) lookups
         self._prefetch_cache[(t.name, col)] = {
             "table": t, "keys": sorted_keys, "order": order,
@@ -505,22 +522,32 @@ class ClientEnv:
         if entry is None:
             raise KeyError(f"no prefetch cache for ({table_name}, {col})")
         self.charge_statement()
-        keys = entry["keys"]
-        lo = np.searchsorted(keys, key_val, side="left")
-        if lo < len(keys) and keys[lo] == key_val:
-            return entry["table"].row(int(entry["order"][lo]))
-        return None
+        tracer = self.tracer
+        with tracer.span("client.lookup") as sp:
+            keys = entry["keys"]
+            lo = np.searchsorted(keys, key_val, side="left")
+            row = None
+            if lo < len(keys) and keys[lo] == key_val:
+                row = entry["table"].row(int(entry["order"][lo]))
+            if tracer.enabled:
+                sp.attrs["n_rows"] = int(row is not None)
+        return row
 
     def lookup_cache_all(self, table_name: str, col: str, key_val) -> list:
         entry = self._prefetch_cache.get((table_name, col))
         if entry is None:
             raise KeyError(f"no prefetch cache for ({table_name}, {col})")
         self.charge_statement()
-        keys = entry["keys"]
-        lo = np.searchsorted(keys, key_val, side="left")
-        hi = np.searchsorted(keys, key_val, side="right")
-        t = entry["table"]
-        return [t.row(int(entry["order"][i])) for i in range(lo, hi)]
+        tracer = self.tracer
+        with tracer.span("client.lookup") as sp:
+            keys = entry["keys"]
+            lo = np.searchsorted(keys, key_val, side="left")
+            hi = np.searchsorted(keys, key_val, side="right")
+            t = entry["table"]
+            rows = [t.row(int(entry["order"][i])) for i in range(lo, hi)]
+            if tracer.enabled:
+                sp.attrs["n_rows"] = len(rows)
+        return rows
 
     def has_cache(self, table_name: str, col: str) -> bool:
         return (table_name, col) in self._prefetch_cache

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.transfer import to_device, to_host
+
 __all__ = ["Field", "Schema", "Table"]
 
 
@@ -106,7 +108,8 @@ class Table:
         for f in schema.fields:
             if f.name not in columns:
                 raise KeyError(f"missing column {f.name!r} for table {name!r}")
-            arr = jnp.asarray(columns[f.name], dtype=_storage_dtype(f.dtype))
+            arr = to_device(columns[f.name], _storage_dtype(f.dtype),
+                            "table.from_columns")
             if arr.ndim != 1:
                 raise ValueError(f"column {f.name!r} must be 1-D, got shape {arr.shape}")
             if n is None:
@@ -163,19 +166,21 @@ class Table:
 
     # ------------------------------------------------------------- row access
     def row(self, i: int) -> Dict[str, object]:
-        return {n: self.columns[n][i].item() for n in self.schema.names}
+        return {n: to_host(self.columns[n][i], "table.row").item()
+                for n in self.schema.names}
 
     def to_rows(self) -> List[Dict[str, object]]:
-        host = {n: np.asarray(self.columns[n]) for n in self.schema.names}
+        host = {n: to_host(self.columns[n], "table.to_rows")
+                for n in self.schema.names}
         return [{n: host[n][i].item() for n in self.schema.names} for i in range(self.nrows)]
 
     # ------------------------------------------------------------- transforms
     def take(self, idx) -> "Table":
-        idx = jnp.asarray(idx)
+        idx = to_device(idx, None, "table.take")
         return Table(self.name, self.schema, {n: jnp.take(c, idx, axis=0) for n, c in self.columns.items()})
 
     def filter_mask(self, mask) -> "Table":
-        keep = np.flatnonzero(np.asarray(mask))
+        keep = np.flatnonzero(to_host(mask, "table.filter_mask"))
         return self.take(keep)
 
     def head(self, k: int) -> "Table":
@@ -192,7 +197,8 @@ class Table:
         return Table(self.name, Schema(fields), cols)
 
     def with_column(self, field: Field, values) -> "Table":
-        values = jnp.asarray(values, dtype=_storage_dtype(field.dtype))
+        values = to_device(values, _storage_dtype(field.dtype),
+                           "table.with_column")
         if self.schema.has(field.name):
             fields = tuple(field if f.name == field.name else f for f in self.schema.fields)
         else:
@@ -204,7 +210,8 @@ class Table:
     def sort_by(self, keys: Sequence[str], descending: bool = False) -> "Table":
         if self.nrows == 0:
             return self
-        arrs = [np.asarray(self.columns[k]) for k in reversed(list(keys))]
+        arrs = [to_host(self.columns[k], "table.sort_by")
+                for k in reversed(list(keys))]
         order = np.lexsort(arrs)
         if descending:
             order = order[::-1]

@@ -88,11 +88,6 @@ class BatchClientEnv(ClientEnv):
         self.site_cache = site_cache if site_cache is not None else SiteCache()
         self.write_set: Set[str] = set(write_set)
         self.tracer = tracer if tracer is not None else NOOP_TRACER
-        # id(query) -> [query, hits, shared_hits, fetches, fetched_rows];
-        # flushed as ONE aggregated span event per site per batch
-        # (flush_site_events) so per-invocation tracing cost stays at a
-        # dict update, not a span allocation
-        self._site_log: Dict[int, list] = {}
         self.site_hits = 0          # in-batch reuse
         self.shared_site_hits = 0   # cross-batch / cross-program reuse
         # (query, observed rows, observed wall-clock) per true execution —
@@ -102,26 +97,6 @@ class BatchClientEnv(ClientEnv):
         # (+ total lookups) at PARAMETERIZED sites, merged by run_batch
         self.binding_sets: Dict[str, set] = {}
         self.binding_totals: Dict[str, int] = {}
-
-    def _site_rec(self, q) -> list:
-        rec = self._site_log.get(id(q))
-        if rec is None:
-            rec = self._site_log[id(q)] = [q, 0, 0, 0, 0]
-        return rec
-
-    def flush_site_events(self) -> None:
-        """Emit one aggregated ``site-hit``/``site-fetch`` event per query
-        site touched this batch (called by ``run_batch`` inside its batch
-        span while the tracer is enabled)."""
-        for q, hits, shared, fetches, rows in self._site_log.values():
-            sql = q.sql()
-            if fetches:
-                self.tracer.event("site-fetch", sim=self.clock, sql=sql,
-                                  n=fetches, rows=rows)
-            if hits or shared:
-                self.tracer.event("site-hit", sim=self.clock, sql=sql,
-                                  n=hits + shared, shared=shared)
-        self._site_log.clear()
 
     # ----------------------------------------------------------------- exec
     def _fetch(self, q, params):
@@ -175,14 +150,8 @@ class BatchClientEnv(ClientEnv):
             else:
                 self.site_hits += 1
             self.charge_statement()
-            if self.tracer.enabled:
-                self._site_rec(q)[2 if cross else 1] += 1
             return result
         t = self._fetch(q, params)
-        if self.tracer.enabled:
-            rec = self._site_rec(q)
-            rec[3] += 1
-            rec[4] += t.nrows
         cache.put(key, t, tables)
         return t
 
@@ -396,8 +365,6 @@ def run_batch(session, program: Program,
                 observations.extend(env.observations)
                 envs.append(env)
             if tracer.enabled:
-                for e in envs:
-                    e.flush_site_events()
                 bsp.attrs["simulated_s"] = sum(r.simulated_s
                                                for r in results)
         session.executions += len(param_sets)
@@ -435,8 +402,6 @@ def run_batch(session, program: Program,
                 n_queries=env.n_queries - q0,
                 n_round_trips=env.n_round_trips - rt0))
             clock0, q0, rt0 = env.clock, env.n_queries, env.n_round_trips
-        if tracer.enabled:
-            env.flush_site_events()
     session.executions += len(param_sets)
     if executable is not None:
         executable.n_runs += len(param_sets)

@@ -55,6 +55,7 @@ from ..core.context import ExecutionContext
 from ..core.regions import Program
 from ..obs.metrics import MetricsRegistry, merge_snapshots, registry_counter
 from ..obs.trace import NOOP_TRACER
+from ..obs.transfer import TRANSFERS
 from .feedback import FeedbackController
 from .sitecache import SiteCache
 
@@ -182,7 +183,7 @@ class ServingRuntime:
             self.executable(name)  # fail fast on unknown programs
             by_program.setdefault(name, []).append(i)
 
-        with self.tracer.span("serve", n_requests=len(todo)):
+        with self.tracer.span("serving.serve", n_requests=len(todo)):
             for name, indices in by_program.items():
                 for lo in range(0, len(indices), self.batch_size):
                     chunk = indices[lo:lo + self.batch_size]
@@ -219,35 +220,38 @@ class ServingRuntime:
     def _after_batch(self, batch) -> None:
         if self.feedback is None:
             return
-        stats_moved = False
-        if batch.iteration_observations:
-            stats_moved = self.feedback.observe_iterations(
-                batch.iteration_observations)
-        if batch.binding_observations:
-            stats_moved |= self.feedback.observe_bindings(
-                batch.binding_observations)
-        drifted = self.feedback.observe(batch.observations) \
-            if batch.observations else []
-        if drifted:
-            self.feedback.refresh(drifted)
-            # the re-analyze moved the drifted tables' stats epoch, so
-            # their site-cache entries are already unreachable; drop them
-            # eagerly too
-            self.site_cache.invalidate_tables(drifted)
-            if self.compiler is not None:
-                # same epoch discipline for compiled artifacts: drop the
-                # lowerings (and promotion heat) of plans touching the
-                # drifted tables — their replacements start cold
-                self.compiler.invalidate_tables(drifted)
-            self._recompile_touching(drifted)
-        if stats_moved:
-            # a published iteration count or binding-diversity fraction
-            # moved: the serving context's fingerprint changed, so
-            # recompile under the new context. The fingerprint is
-            # restricted per program to its own sites — programs without
-            # the moved site (and any the drift branch just recompiled
-            # under this same context) hit the plan cache.
-            self._recompile_for_context()
+        with self.tracer.span("serving.feedback") as sp:
+            stats_moved = False
+            if batch.iteration_observations:
+                stats_moved = self.feedback.observe_iterations(
+                    batch.iteration_observations)
+            if batch.binding_observations:
+                stats_moved |= self.feedback.observe_bindings(
+                    batch.binding_observations)
+            drifted = self.feedback.observe(batch.observations) \
+                if batch.observations else []
+            if self.tracer.enabled:
+                sp.attrs.update(drifted=list(drifted), stats_moved=stats_moved)
+            if drifted:
+                self.feedback.refresh(drifted)
+                # the re-analyze moved the drifted tables' stats epoch, so
+                # their site-cache entries are already unreachable; drop them
+                # eagerly too
+                self.site_cache.invalidate_tables(drifted)
+                if self.compiler is not None:
+                    # same epoch discipline for compiled artifacts: drop the
+                    # lowerings (and promotion heat) of plans touching the
+                    # drifted tables — their replacements start cold
+                    self.compiler.invalidate_tables(drifted)
+                self._recompile_touching(drifted)
+            if stats_moved:
+                # a published iteration count or binding-diversity fraction
+                # moved: the serving context's fingerprint changed, so
+                # recompile under the new context. The fingerprint is
+                # restricted per program to its own sites — programs without
+                # the moved site (and any the drift branch just recompiled
+                # under this same context) hit the plan cache.
+                self._recompile_for_context()
 
     def _guarded_swap(self, name: str, new_exe) -> None:
         """Install ``new_exe`` as the serving plan for ``name`` — unless the
@@ -314,8 +318,9 @@ class ServingRuntime:
 
     def metrics_snapshot(self) -> Dict[str, object]:
         """One flat snapshot across every component registry (serving,
-        session, feedback) plus the site-cache / compiler stats dicts
-        ingested as gauges — diff two snapshots to see a serve cycle."""
+        session, feedback, the process's host↔device transfer counters)
+        plus the site-cache / compiler stats dicts ingested as gauges —
+        diff two snapshots to see a serve cycle."""
         self.metrics.ingest(self.site_cache.stats(), prefix="site_cache_")
         if self.compiler is not None:
             self.metrics.ingest(self.compiler.metrics.snapshot(),
@@ -324,6 +329,7 @@ class ServingRuntime:
                  "session": self.session.metrics.snapshot()}
         if self.feedback is not None:
             parts["feedback"] = self.feedback.metrics.snapshot()
+        parts["transfer"] = TRANSFERS.snapshot()
         return merge_snapshots(**parts)
 
     # ------------------------------------------------------------- telemetry

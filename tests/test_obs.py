@@ -14,6 +14,7 @@ Issue acceptance:
 
 import json
 
+import numpy as np
 import pytest
 
 from repro.api import CobraSession, ExecutionContext, OptimizerConfig
@@ -207,7 +208,7 @@ class TestTracer:
         rt.serve([("P0", {})] * 8)
         assert rt.recompiles >= 1
         assert tracer.well_nested()
-        assert tracer.spans("serve") and tracer.spans("batch")
+        assert tracer.spans("serving.serve") and tracer.spans("batch")
         verdicts = tracer.spans("swap-verdict")
         assert verdicts and verdicts[0].attrs["accepted"] is True
         # batch spans carry the simulated clock alongside the wall clock
@@ -216,10 +217,13 @@ class TestTracer:
 
     def test_tracing_never_changes_outputs_or_clock(self):
         """Bit-identity: the same stream served traced and untraced, through
-        a drift-driven swap, yields equal outputs and simulated clocks."""
-        def run(tracer):
+        a drift-driven swap, on the interpreted and the compiled tier,
+        yields equal outputs and simulated clocks; the traced run records
+        the server, feedback, export and compiled-tier spans."""
+        def run(tracer, hot):
             session, grown = drifted_session(tracer=tracer)
-            rt = ServingRuntime(session, batch_size=4, drift_threshold=3.0)
+            rt = ServingRuntime(session, batch_size=4, drift_threshold=3.0,
+                                compile_hot_plans=hot)
             rt.register(make_p0())
             out = list(rt.serve([("P0", {})] * 4))
             session.db.replace_table(grown.table("orders"))
@@ -227,19 +231,139 @@ class TestTracer:
             out += list(rt.serve([("P0", {})] * 8))
             return out, rt.simulated_s
 
-        traced_out, traced_sim = run(Tracer())
-        plain_out, plain_sim = run(None)
-        assert traced_sim == plain_sim               # exact, not approx
-        assert [r.outputs for r in traced_out] == \
-            [r.outputs for r in plain_out]
-        assert [r.simulated_s for r in traced_out] == \
-            [r.simulated_s for r in plain_out]
+        served = {"serving.serve", "batch", "serving.feedback",
+                  "server.run", "server.analyze", "loop.export"}
+        for hot, spans in ((0, served),
+                           (1, served | {"compiled.loop", "compiled.probe"})):
+            tracer = Tracer()
+            traced_out, traced_sim = run(tracer, hot)
+            plain_out, plain_sim = run(None, hot)
+            assert traced_sim == plain_sim               # exact, not approx
+            assert [r.outputs for r in traced_out] == \
+                [r.outputs for r in plain_out]
+            assert [r.simulated_s for r in traced_out] == \
+                [r.simulated_s for r in plain_out]
+            assert tracer.well_nested()
+            assert spans <= {s.name for s in tracer.spans()}
+
+    def test_feedback_span_holds_the_drift_recompile(self):
+        """The recompile a drift triggers nests under the feedback span
+        that found the drift, with the re-ANALYZE it ran."""
+        tracer = Tracer()
+        session, grown = drifted_session(tracer=tracer)
+        rt = ServingRuntime(session, batch_size=4, drift_threshold=3.0)
+        rt.register(make_p0())
+        rt.serve([("P0", {})] * 4)
+        session.db.replace_table(grown.table("orders"))
+        session.db.replace_table(grown.table("customer"))
+        rt.serve([("P0", {})] * 8)
+        drifted = [s for s in tracer.spans("serving.feedback")
+                   if s.attrs["drifted"]]
+        assert drifted
+        names = {c.name for s in drifted for c in s.children}
+        assert {"server.analyze", "compile"} <= names
+
+    def test_prefetch_lookups_open_client_spans(self):
+        """W_E served by its prefetch plan on the exact interpreter: one
+        ``client.lookup`` span per worklist key, inside its batch, with the
+        rows it read."""
+        tracer = Tracer()
+        session = CobraSession(make_wilos_db(2000),
+                               CostCatalog(FAST_LOCAL),
+                               config=OptimizerConfig.preset("wilos"),
+                               tracer=tracer)
+        rt = ServingRuntime(session, batch_size=16)
+        rt.register(make_wilos_e())
+        rt.serve([("W_E", {"worklist": list(range(20))})])
+        (batch,) = tracer.spans("batch")
+        lookups = tracer.spans("client.lookup")
+        assert len(lookups) == 20 and tracer.well_nested()
+        (build,) = tracer.spans("client.cache_by_column")
+        assert build.attrs == {"table": "tasks", "rows": 2000}
+        assert all(batch.wall_start <= s.wall_start <= s.wall_end
+                   <= batch.wall_end for s in lookups)
+        assert sum(s.attrs["n_rows"] for s in lookups) > 0
 
     def test_noop_tracer_records_nothing(self):
         session = paper_session(make_orders_customer_db(100, 50))
         assert isinstance(session.tracer, NoopTracer)
         session.compile(make_p0()).run()
         assert session.tracer.spans() == []
+
+
+# --------------------------------------------------------------------------
+# Host↔device transfer counters
+# --------------------------------------------------------------------------
+
+def _transfer_delta(before):
+    from repro.obs.transfer import TRANSFERS
+    return TRANSFERS.diff(before)
+
+
+class TestTransferCounters:
+    def test_row_read_counts_one_host_read_per_column(self):
+        from repro.obs.transfer import TRANSFERS
+        from repro.relational.table import Field, Schema, Table
+        t = Table.from_columns(
+            "t", Schema.of(Field("a", "int32"), Field("b", "float32"),
+                           Field("c", "int32")),
+            a=np.arange(10), b=np.ones(10), c=np.zeros(10))
+        before = TRANSFERS.snapshot()
+        assert t.row(3) == {"a": 3, "b": 1.0, "c": 0}
+        assert _transfer_delta(before) == {
+            "host_reads{site=table.row}": 3,
+            "d2h_bytes{site=table.row}": 12}
+
+    def test_interpret_probe_moves_4n_bytes_each_way(self):
+        from collections import Counter
+        from types import SimpleNamespace
+        from repro.compiled.exec import _BuildKeys, _probe
+        from repro.kernels import ops
+        from repro.obs.transfer import TRANSFERS
+        db = make_orders_customer_db(1000, 100)
+        bk = _BuildKeys(db.table("customer"), "c_customer_sk")
+        keys = np.asarray(db.table("orders").column("o_customer_sk"))
+        cl = SimpleNamespace(backend="kernels", kernel_calls=Counter())
+        state = ops.pallas_state()
+        try:
+            ops.use_pallas(True, interpret=True)
+            first = _probe(cl, bk, keys)        # builds the direct table
+            before = TRANSFERS.snapshot()
+            again = _probe(cl, bk, keys)
+        finally:
+            ops.use_pallas(*state)
+        assert cl.kernel_calls == {("join_probe", ops.INTERPRET): 2}
+        assert (first == again).all()
+        n = keys.shape[0]
+        assert _transfer_delta(before) == {
+            "h2d_bytes{site=compiled.probe}": 4 * n,
+            "host_reads{site=compiled.probe}": 1,
+            "d2h_bytes{site=compiled.probe}": 4 * n}
+
+    def test_host_arrays_count_nothing(self):
+        import jax.numpy as jnp
+        from repro.obs.transfer import TRANSFERS, to_device, to_host
+        host = np.arange(8, dtype=np.int32)
+        dev = jnp.arange(8, dtype=jnp.int32)
+        before = TRANSFERS.snapshot()
+        assert to_host(host, "test") is host
+        to_device(dev, jnp.float32, "test")
+        assert _transfer_delta(before) == {}
+        to_device(host, None, "test")
+        to_host(dev, "test")
+        assert _transfer_delta(before) == {
+            "h2d_bytes{site=test}": 32, "host_reads{site=test}": 1,
+            "d2h_bytes{site=test}": 32}
+
+    def test_runtime_snapshot_surfaces_the_counters(self):
+        session = paper_session(make_orders_customer_db(100, 50))
+        rt = ServingRuntime(session, batch_size=4)
+        rt.register(make_p0())
+        before = rt.metrics_snapshot()
+        rt.serve([("P0", {})] * 4)
+        after = rt.metrics_snapshot()
+        reads = [k for k in after if k.startswith("transfer_host_reads")]
+        assert sum(after[k] - before.get(k, 0) for k in reads) > 0
 
 
 # --------------------------------------------------------------------------
