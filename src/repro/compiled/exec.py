@@ -32,7 +32,6 @@ sources at run time), defers to the exact row-at-a-time semantics.
 from __future__ import annotations
 
 from collections import OrderedDict
-from typing import Callable
 
 import jax.numpy as jnp
 import numpy as np
@@ -45,6 +44,7 @@ from ..kernels import ref as kref
 from ..kernels.join_probe import build_direct_table, join_probe
 from ..obs.trace import NOOP_TRACER
 from ..obs.transfer import to_device, to_host
+from ..relational.memo import TableMemo
 from ..relational.table import Table
 
 __all__ = ["SplicingInterpreter", "make_hooks"]
@@ -57,31 +57,6 @@ _EXACT_FP32 = float(1 << 24)
 # tables; the hooks only ever pin this many
 _ROW_SOURCE_CAP = 32
 _PROBE_INDEX_CAP = 64
-
-
-class _TableMemo:
-    """LRU memo of ``build(table, *args)``, keyed by the table's identity
-    (and ``args``) WITH a strong reference to the keyed table (``id``
-    alone could be recycled). In the serving path the site cache returns
-    the same Table object for an unchanged site, so repeated batches hit
-    this memo instead of re-deriving from the table."""
-
-    def __init__(self, build: Callable, cap: int):
-        self.build = build
-        self.cap = cap
-        self._memo: "OrderedDict[tuple, tuple]" = OrderedDict()
-
-    def __call__(self, t: Table, *args):
-        k = (id(t),) + args
-        hit = self._memo.get(k)
-        if hit is not None and hit[0] is t:
-            self._memo.move_to_end(k)
-            return hit[1]
-        value = self.build(t, *args)
-        self._memo[k] = (t, value)
-        while len(self._memo) > self.cap:
-            self._memo.popitem(last=False)
-        return value
 
 
 def _columns(t: Table):
@@ -182,11 +157,11 @@ def make_hooks(cl) -> LoopHooks:
     same values, same ORM-cache mutations, same failure behavior — only
     the gather/fold machinery differs (epoch-cached indices + kernels)."""
     probe_cache = _ProbeIndexCache(cl)
-    row_source = _TableMemo(_columns, _ROW_SOURCE_CAP)
+    row_source = TableMemo(_columns, _ROW_SOURCE_CAP)
     # the build keys of a prefetch cache, by its table: each invocation
     # re-prefetches, but the same Table (the same sort as the cache's own),
     # so the direct-address table is built once and not per invocation
-    prefetch_keys = _TableMemo(_BuildKeys, _PROBE_INDEX_CAP)
+    prefetch_keys = TableMemo(_BuildKeys, _PROBE_INDEX_CAP)
 
     def probe(env, bk, keys):
         tracer = getattr(env, "tracer", NOOP_TRACER)

@@ -30,12 +30,15 @@ import dataclasses
 import itertools
 from typing import Dict, Mapping, Optional, Tuple
 
+import jax
 import numpy as np
 
+from ..obs.metrics import MetricsRegistry
 from ..obs.trace import NOOP_TRACER
-from ..obs.transfer import to_host
+from ..obs.transfer import to_device, to_host
 from .algebra import (Aggregate, Join, Limit, OrderBy, Project, Query, Scan,
                       Select)
+from .memo import TableMemo
 from .table import Table
 
 __all__ = [
@@ -429,6 +432,102 @@ class DatabaseServer:
 # Client environment (simulated clock + caches)
 # --------------------------------------------------------------------------
 
+# Process-wide counters of the client's prefetch-cache index
+# (``index_builds``, ``index_reuses``): one build per prefetched key column,
+# a reuse for every later ``cacheByColumn`` of it.
+# ``ServingRuntime.metrics_snapshot()`` surfaces them as ``client_*``.
+CLIENT = MetricsRegistry()
+
+# a serving process sees unbounded distinct prefetched results; the client
+# pins at most this many indexes, each with its host image
+_INDEX_CAP = 16
+# a prefetch's rows reach the host a page of its index order at a time, at
+# the first lookup that reads the page: a prefetch looked up at a few keys
+# pulls a few pages, and a page read again is pulled once
+_PAGE_ROWS = 1 << 14
+
+
+@jax.jit
+def _gather(columns, pos):
+    return tuple(c[pos] for c in columns)
+
+
+class _HostImage:
+    """The rows of a prefetched table in its index's order, on the host:
+    ``values[j][i]`` is column ``j`` of the row at sorted position ``i``.
+    Each page is gathered on the device and pulled at its first read."""
+
+    __slots__ = ("columns", "order", "page", "values", "pulled")
+
+    def __init__(self, columns: tuple, order: np.ndarray):
+        self.columns = columns
+        self.order = order
+        n = len(order)
+        self.page = max(1, min(_PAGE_ROWS, n))
+        self.values = [np.empty(n, c.dtype) for c in columns]
+        self.pulled = np.zeros(-(-n // self.page), bool)
+
+    def slice(self, lo: int, hi: int) -> list:
+        """Each column's values at sorted positions ``lo`` to ``hi - 1``."""
+        for p in range(lo // self.page, (hi - 1) // self.page + 1):
+            if not self.pulled[p]:
+                self._pull(p)
+        return [v[lo:hi] for v in self.values]
+
+    def _pull(self, p: int) -> None:
+        lo = p * self.page
+        hi = min(lo + self.page, len(self.order))
+        # every page has the one shape: the gather compiles once per table
+        pos = np.zeros(self.page, np.int32)
+        pos[:hi - lo] = self.order[lo:hi]
+        pages = _gather(self.columns,
+                        to_device(pos, np.int32, "database.lookup_cache"))
+        for v, page in zip(self.values, pages):
+            v[lo:hi] = to_host(page, "database.lookup_cache")[:hi - lo]
+        self.pulled[p] = True
+
+
+class _CacheIndex:
+    """A prefetched key column in sorted order: ``order`` is its stable
+    sort and ``keys`` the keys in that order; :meth:`image` holds the rows
+    of the table last looked up through it."""
+
+    __slots__ = ("order", "keys", "_image")
+
+    def __init__(self, key_col):
+        arr = to_host(key_col, "database.cache_by_column")
+        self.order = np.argsort(arr, kind="stable")
+        self.keys = arr[self.order]
+        self._image: Optional[_HostImage] = None
+
+    def image(self, columns: tuple) -> _HostImage:
+        """The host image of the table whose columns are ``columns``: kept
+        while the table keeps its arrays, made anew for one that shares
+        only the key column."""
+        img = self._image
+        if img is None or len(img.columns) != len(columns) or any(
+                a is not b for a, b in zip(img.columns, columns)):
+            img = self._image = _HostImage(columns, self.order)
+        return img
+
+
+# Keyed by the key column's array, which a re-wrapped ``Table(name, schema,
+# t.columns)`` shares with ``t``: the site cache hands back the same result
+# for an unchanged site, so its index is sorted and each page pulled once.
+_INDEXES = TableMemo(_CacheIndex, _INDEX_CAP)
+
+
+def _search_key(keys: np.ndarray, key_val):
+    """``key_val`` in the index's own dtype, or None where that dtype cannot
+    hold it exactly (such a key matches nothing). Searching in the index's
+    dtype keeps numpy from promoting and copying the whole index."""
+    try:
+        k = keys.dtype.type(key_val)
+    except (TypeError, ValueError, OverflowError):
+        return None
+    return k if k.item() == key_val else None
+
+
 class ClientEnv:
     """Application-side runtime: clock, ORM id-cache, prefetch cache.
 
@@ -507,33 +606,33 @@ class ClientEnv:
         """``Utils.cacheByColumn`` from the paper (footnote 3)."""
         # building the local hash index costs C_Z per row
         self.charge_statement(t.nrows)
+        key_col = t.column(col)
         with self.tracer.span("client.cache_by_column", table=t.name,
-                              rows=t.nrows):
-            arr = to_host(t.column(col), "database.cache_by_column")
-            order = np.argsort(arr, kind="stable")
-            sorted_keys = arr[order]
-        # store as (table, sorted keys, order) for O(log n) lookups
+                              rows=t.nrows) as sp:
+            idx = _INDEXES.get(key_col)
+            built = idx is None
+            if built:
+                idx = _INDEXES(key_col)
+            CLIENT.inc("index_builds" if built else "index_reuses")
+            if self.tracer.enabled:
+                sp.attrs["built"] = built
+        # store as (table, sorted keys, order) for O(log n) lookups, with
+        # the index, whose host image serves the rows
         self._prefetch_cache[(t.name, col)] = {
-            "table": t, "keys": sorted_keys, "order": order,
+            "table": t, "keys": idx.keys, "order": idx.order, "index": idx,
         }
 
     def lookup_cache(self, table_name: str, col: str, key_val) -> Optional[Dict[str, object]]:
-        entry = self._prefetch_cache.get((table_name, col))
-        if entry is None:
-            raise KeyError(f"no prefetch cache for ({table_name}, {col})")
-        self.charge_statement()
-        tracer = self.tracer
-        with tracer.span("client.lookup") as sp:
-            keys = entry["keys"]
-            lo = np.searchsorted(keys, key_val, side="left")
-            row = None
-            if lo < len(keys) and keys[lo] == key_val:
-                row = entry["table"].row(int(entry["order"][lo]))
-            if tracer.enabled:
-                sp.attrs["n_rows"] = int(row is not None)
-        return row
+        rows = self._lookup(table_name, col, key_val, first=True)
+        return rows[0] if rows else None
 
     def lookup_cache_all(self, table_name: str, col: str, key_val) -> list:
+        return self._lookup(table_name, col, key_val, first=False)
+
+    def _lookup(self, table_name: str, col: str, key_val, first: bool) -> list:
+        """The rows of a prefetch cache whose key is ``key_val`` (the first
+        alone where ``first``), in table order: the values ``Table.row``
+        gives, read from the index's host image of the table."""
         entry = self._prefetch_cache.get((table_name, col))
         if entry is None:
             raise KeyError(f"no prefetch cache for ({table_name}, {col})")
@@ -541,10 +640,20 @@ class ClientEnv:
         tracer = self.tracer
         with tracer.span("client.lookup") as sp:
             keys = entry["keys"]
-            lo = np.searchsorted(keys, key_val, side="left")
-            hi = np.searchsorted(keys, key_val, side="right")
-            t = entry["table"]
-            rows = [t.row(int(entry["order"][i])) for i in range(lo, hi)]
+            k = _search_key(keys, key_val)
+            rows = []
+            if k is not None:
+                lo = keys.searchsorted(k, side="left")
+                hi = keys.searchsorted(k, side="right")
+                if first:
+                    hi = min(hi, lo + 1)
+                if hi > lo:
+                    t = entry["table"]
+                    names = t.schema.names
+                    image = entry["index"].image(
+                        tuple(t.columns[n] for n in names))
+                    cols = [v.tolist() for v in image.slice(lo, hi)]
+                    rows = [dict(zip(names, vals)) for vals in zip(*cols)]
             if tracer.enabled:
                 sp.attrs["n_rows"] = len(rows)
         return rows

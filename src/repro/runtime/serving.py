@@ -56,6 +56,7 @@ from ..core.regions import Program
 from ..obs.metrics import MetricsRegistry, merge_snapshots, registry_counter
 from ..obs.trace import NOOP_TRACER
 from ..obs.transfer import TRANSFERS
+from ..relational.database import CLIENT
 from .feedback import FeedbackController
 from .sitecache import SiteCache
 
@@ -318,7 +319,8 @@ class ServingRuntime:
 
     def metrics_snapshot(self) -> Dict[str, object]:
         """One flat snapshot across every component registry (serving,
-        session, feedback, the process's host↔device transfer counters)
+        session, feedback, the process's host↔device transfer and
+        prefetch-cache index counters)
         plus the site-cache / compiler stats dicts ingested as gauges —
         diff two snapshots to see a serve cycle."""
         self.metrics.ingest(self.site_cache.stats(), prefix="site_cache_")
@@ -330,6 +332,7 @@ class ServingRuntime:
         if self.feedback is not None:
             parts["feedback"] = self.feedback.metrics.snapshot()
         parts["transfer"] = TRANSFERS.snapshot()
+        parts["client"] = CLIENT.snapshot()
         return merge_snapshots(**parts)
 
     # ------------------------------------------------------------- telemetry

@@ -279,7 +279,7 @@ class TestTracer:
         lookups = tracer.spans("client.lookup")
         assert len(lookups) == 20 and tracer.well_nested()
         (build,) = tracer.spans("client.cache_by_column")
-        assert build.attrs == {"table": "tasks", "rows": 2000}
+        assert build.attrs == {"table": "tasks", "rows": 2000, "built": True}
         assert all(batch.wall_start <= s.wall_start <= s.wall_end
                    <= batch.wall_end for s in lookups)
         assert sum(s.attrs["n_rows"] for s in lookups) > 0

@@ -106,6 +106,116 @@ def test_prefetch_cache_lookup(db):
     assert env.lookup_cache("customer", "c_id", 10**9) is None
 
 
+# --------------------------------------------------------------------------
+# The prefetch cache's host image: index built once, rows read on the host
+# --------------------------------------------------------------------------
+
+def _tasks(keys=(3, 1, 3, 2, 3, 1), name="tasks"):
+    n = len(keys)
+    return Table.from_columns(
+        name, Schema.of(Field("t_role", "int32"), Field("t_id", "int32"),
+                        Field("t_hours", "float32")),
+        t_role=np.asarray(keys), t_id=np.arange(n) * 10,
+        t_hours=np.linspace(0.1, 2.3, n))
+
+
+def _client_delta(before):
+    from repro.relational.database import CLIENT
+    return CLIENT.diff(before)
+
+
+@pytest.mark.parametrize("rewrap", [False, True])
+def test_prefetch_index_is_built_once_per_table(rewrap):
+    from repro.relational.database import CLIENT
+    t = _tasks()
+    env = ClientEnv(DatabaseServer({"tasks": t}), FAST_LOCAL)
+    before = CLIENT.snapshot()
+    env.cache_by_column(t, "t_role")
+    again = Table("tasks_by_role", t.schema, t.columns) if rewrap else t
+    env.cache_by_column(again, "t_role")
+    assert _client_delta(before) == {"index_builds": 1, "index_reuses": 1}
+    assert len(env.lookup_cache_all(again.name, "t_role", 3)) == 3
+
+
+@pytest.mark.parametrize("lookup", ["lookup_cache", "lookup_cache_all"])
+def test_lookups_after_the_first_read_nothing_from_the_device(lookup):
+    from repro.obs.transfer import TRANSFERS
+    t = _tasks()
+    db = DatabaseServer({"tasks": t})
+    env = ClientEnv(db, FAST_LOCAL)
+    env.cache_by_column(t, "t_role")
+    assert env.lookup_cache_all("tasks", "t_role", 2)    # pulls the columns
+    before = TRANSFERS.snapshot()
+    for key in (1, 2, 3, 7):
+        getattr(env, lookup)("tasks", "t_role", key)
+    # a later request re-prefetches the same result: no pull either
+    env = ClientEnv(db, FAST_LOCAL)
+    env.cache_by_column(t, "t_role")
+    getattr(env, lookup)("tasks", "t_role", 3)
+    assert TRANSFERS.diff(before) == {}
+
+
+@pytest.mark.parametrize("page", [1, 2, 4, 6])
+def test_host_image_pulls_each_page_once_at_its_first_read(monkeypatch, page):
+    from repro.obs.transfer import TRANSFERS
+    from repro.relational import database
+    monkeypatch.setattr(database, "_PAGE_ROWS", page)
+    t = _tasks(keys=(3, 1, 3, 2, 3, 1))     # in key order: 1 1 2 3 3 3
+    env = ClientEnv(DatabaseServer({"tasks": t}), FAST_LOCAL)
+    env.cache_by_column(t, "t_role")
+    sorted_at = {1: range(0, 2), 2: range(2, 3), 3: range(3, 6), 7: range(0)}
+    pulled = set()
+    for key in (1, 1, 3, 2, 7, 3):
+        before = TRANSFERS.snapshot()
+        rows = env.lookup_cache_all("tasks", "t_role", key)
+        assert rows == [r for r in t.to_rows() if r["t_role"] == key]
+        pages = {i // page for i in sorted_at[key]} - pulled
+        pulled |= pages
+        reads = TRANSFERS.diff(before).get(
+            "host_reads{site=database.lookup_cache}", 0)
+        assert reads == 3 * len(pages)      # one read a column a page
+
+
+@pytest.mark.parametrize("key,want", [
+    (3, 3), (np.int64(1), 1), (np.int64(2**32 + 3), None), (2**40, None),
+    (7, None), (3.0, 3), (3.5, None)])
+def test_cached_rows_are_table_rows(key, want):
+    t = _tasks()
+    env = ClientEnv(DatabaseServer({"tasks": t}), FAST_LOCAL)
+    env.cache_by_column(t, "t_role")
+    stored = np.asarray(t.column("t_role"))
+    rows = [] if want is None else [t.row(int(i))
+                                    for i in np.flatnonzero(stored == want)]
+
+    def typed(rs):
+        return [[(k, type(v), v) for k, v in r.items()] for r in rs]
+
+    got = env.lookup_cache_all("tasks", "t_role", key)
+    assert typed(got) == typed(rows)
+    one = env.lookup_cache("tasks", "t_role", key)
+    assert typed([] if one is None else [one]) == typed(rows[:1])
+
+
+@pytest.mark.parametrize("same_keys", [False, True])
+def test_fresh_prefetch_after_replace_table_reads_new_values(same_keys):
+    t = _tasks()
+    db = DatabaseServer({"tasks": t})
+    env = ClientEnv(db, FAST_LOCAL)
+    env.cache_by_column(db.table("tasks"), "t_role")
+    assert [r["t_id"] for r in env.lookup_cache_all("tasks", "t_role", 3)] \
+        == [0, 20, 40]
+    if same_keys:       # the key column's array is shared with the old table
+        new = t.with_column(t.schema.field("t_id"), np.arange(6) + 100)
+    else:
+        new = _tasks(keys=(3, 3, 1, 1, 2, 2))
+    db.replace_table(new)
+    env = ClientEnv(db, FAST_LOCAL)
+    env.cache_by_column(db.table("tasks"), "t_role")
+    want = [new.row(i) for i in range(new.nrows) if new.row(i)["t_role"] == 3]
+    assert env.lookup_cache_all("tasks", "t_role", 3) == want
+    assert env.lookup_cache("tasks", "t_role", 3) == want[0]
+
+
 def test_project_computed_column(db):
     from repro.relational import Arith
     q = Project(("o_id",), Scan("orders"), computed=(("dbl", Arith("*", Col("o_amt"), Lit(2.0))),))
