@@ -48,7 +48,8 @@ from ..relational.algebra import (AggSpec, Aggregate, Col, Join, Limit,
 from ..core.regions import (Assign, BasicBlock, BreakStmt, CacheByColumn,
                             CollectionAdd, CondRegion, ContinueStmt, IBin,
                             ICacheLookup, ICall, IConst, IEmptyList, IEmptyMap,
-                            IExpr, IField, IIndex, ILen, ILoadAll, INav,
+                            IExists, IExpr, IField, IIndex, ILen, ILoadAll,
+                            IMapGet, INav,
                             IQuery, IQueryValues, IScalarQuery, IVar,
                             LoopRegion, MapPut, NoOp, Prefetch, Program,
                             Region, ReturnStmt, SeqRegion, Stmt, UpdateRow,
@@ -221,6 +222,10 @@ class Expr:
     def len(self) -> "Expr":
         return Expr(ILen(self._ir), self._builder)
 
+    def get(self, key, default=None) -> "Expr":
+        """``m.get(key, default)`` on a traced map value."""
+        return Expr(IMapGet(self._ir, _ir(key), _ir(default)), self._builder)
+
     def __repr__(self):
         return f"Expr[{self._ir!r}]"
 
@@ -321,6 +326,20 @@ class ProgramBuilder:
     def query_values(self, source: Union[str, Query, Q], column: str) -> Expr:
         h = q(source)
         return Expr(IQueryValues(h.query, column), self)
+
+    def exists(self, source, pred, var: Optional[str] = None) -> Expr:
+        """``any(pred(row) for row in source)``: ``pred`` maps the row
+        cursor (a handle named ``var``) to a traced predicate::
+
+            late = b.exists(q("lineitem").where(...).bind(ok=o.o_orderkey),
+                            lambda l: l.l_commitdate < l.l_receiptdate)
+        """
+        src_expr = self.query(source) \
+            if isinstance(source, (str, Query, Q)) else source
+        name = var or self._fresh_var()
+        cursor = VarHandle(name, self, table=getattr(src_expr, "_table",
+                                                     None))
+        return Expr(IExists(name, _ir(src_expr), _ir(pred(cursor))), self)
 
     def cache_lookup(self, table: str, column: str, key,
                      all_matches: bool = False) -> Expr:

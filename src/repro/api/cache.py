@@ -67,7 +67,8 @@ def program_tables(program) -> Tuple[str, ...]:
         q = getattr(e, "query", None)
         if q is not None:
             out.update(query_tables(q))
-        for attr in ("base", "left", "right", "keyexpr"):
+        for attr in ("base", "left", "right", "keyexpr", "source", "pred",
+                     "default"):
             k = getattr(e, attr, None)
             if k is not None:
                 from_expr(k)
@@ -185,7 +186,8 @@ def _param_site_keys(program, key_of) -> Tuple[str, ...]:
         q = getattr(e, "query", None)
         if q is not None:
             from_query(q, getattr(e, "bindings", ()))
-        for attr in ("base", "left", "right", "keyexpr"):
+        for attr in ("base", "left", "right", "keyexpr", "source", "pred",
+                     "default"):
             k = getattr(e, attr, None)
             if k is not None:
                 from_expr(k)

@@ -36,8 +36,13 @@ Supported constructs (all lower to the same IR the builder emits by hand):
     ``{t.k: t.x for t in ...}``, ``{t.x for t in ...}``) — lowered to the
     same loop-accumulation IR an explicit loop emits (fresh accumulator +
     ``LoopRegion`` + guarded ``CollectionAdd``/``MapPut``; a set is the
-    keyed map with the member as its own key); generator expressions and
-    nested comprehensions stay ``LiftError``;
+    keyed map with the member as its own key); nested comprehensions stay
+    ``LiftError``;
+  * ``any(pred for v in <source> if cond)`` — an existential check
+    (:class:`~repro.core.regions.IExists`); other generator expressions
+    stay ``LiftError``;
+  * ``m.get(k, default)`` on a traced map —
+    :class:`~repro.core.regions.IMapGet`;
   * calls to :func:`~repro.core.regions.register_function`-registered pure
     functions by name, plus ``len``/``min``/``max`` builtins;
   * **small pure helper functions inlined automatically** — an unregistered
@@ -556,8 +561,9 @@ class _Lifter:
         if isinstance(node, ast.DictComp):
             return self._comp(node, "dict")
         if isinstance(node, ast.GeneratorExp):
-            raise self._err(node, "generator expressions — materialize with "
-                                  "a list/set/dict comprehension or an "
+            raise self._err(node, "generator expressions (other than "
+                                  "any(...)) — materialize with a "
+                                  "list/set/dict comprehension or an "
                                   "explicit loop")
         if isinstance(node, ast.IfExp):
             raise self._err(node, "conditional expressions — write an "
@@ -675,6 +681,52 @@ class _Lifter:
                 self.scope[var] = saved
         return acc
 
+    def _any(self, gen: ast.GeneratorExp):
+        """Lower ``any(pred for v in src if cond ...)`` to an existential
+        check over the source (``IExists``): the ``if`` clauses and the
+        element, conjoined, are its predicate over the row ``v``. The
+        flag loops that compute the same (``found = 0; for v in src: if
+        pred: found = 1``, with or without ``break``) reach the same
+        existential fold in F-IR."""
+        if len(gen.generators) != 1:
+            raise self._err(gen, "any() over a generator with multiple "
+                                 "`for` clauses — write explicit loops")
+        g = gen.generators[0]
+        if getattr(g, "is_async", 0) or not isinstance(g.target, ast.Name):
+            raise self._err(gen, "any() generator target must be a single "
+                                 "variable")
+        src = self._expr(g.iter)
+        if not isinstance(src, (Expr, Q, Query, str)):
+            raise self._err(g.iter, f"cannot iterate a trace-time "
+                                    f"{type(src).__name__} in any() — its "
+                                    f"source is a query handle (q(...)) or "
+                                    f"a traced collection")
+        var = g.target.id
+        _missing = object()
+        saved = self.scope.get(var, _missing)
+
+        def pred(cursor):
+            self.scope[var] = cursor
+            out = None
+            for part in list(g.ifs) + [gen.elt]:
+                p = self._expr(part)
+                if not isinstance(p, Expr):
+                    raise self._err(part, "any() condition is a trace-time "
+                                          "constant — it must test the row")
+                out = p if out is None else self._apply_op("and", out, p,
+                                                           part)
+            return out
+
+        self._comp_depth += 1      # no loop may be emitted inside it
+        try:
+            return self.b.exists(src, pred, var=var)
+        finally:
+            self._comp_depth -= 1
+            if saved is _missing:
+                self.scope.pop(var, None)
+            else:
+                self.scope[var] = saved
+
     # ------------------------------------------------------------------ calls
     def _maybe_static(self, node: ast.expr):
         """Resolve an expression to a trace-time value if possible, else
@@ -728,6 +780,14 @@ class _Lifter:
                     raise self._err(node, f"registered function {func.id!r} "
                                           f"takes positional arguments only")
                 return self.b.call(func.id, *args)
+        if isinstance(func, ast.Attribute) and func.attr == "get" \
+                and isinstance(func.value, ast.Name) \
+                and isinstance(self.scope.get(func.value.id), Expr):
+            args, kwargs = self._call_args(node)
+            if kwargs or not 1 <= len(args) <= 2:
+                raise self._err(node, "m.get() takes a key and an optional "
+                                      "default")
+            return self.scope[func.value.id].get(*args)
         f = self._expr(func)
         if isinstance(f, Expr):
             raise self._err(node, "calling a traced value")
@@ -738,6 +798,9 @@ class _Lifter:
                     raise self._err(node, f"registered function {rname!r} "
                                           f"takes positional arguments only")
                 return self.b.call(rname, *args)
+        if f is builtins.any and len(node.args) == 1 and not node.keywords \
+                and isinstance(node.args[0], ast.GeneratorExp):
+            return self._any(node.args[0])
         marker = self._marker_name(f)
         args, kwargs = self._call_args(node)
         if marker in _EXPR_MARKERS:

@@ -49,45 +49,17 @@ import dataclasses
 from typing import Optional, Tuple
 
 from ..relational.algebra import (Cmp, Col, Param, Query, Scalar, Scan,
-                                  Select, scan_tables)
+                                  Select, _embedded_scalars, query_has_params,
+                                  scan_tables)
 from ..relational.database import DatabaseServer, NetworkProfile
 from .context import (ExecutionContext, ONE_SHOT, loop_site_key,
                       param_group_key, param_prov_key, while_site_key)
-from .fir import (FCacheLookupAllE, FCacheLookupE, FCondE, FExpr, FFoldE,
-                  FPointLookup, FQueryE, FSelLookupE, FTupleE, fir_children)
+from .fir import (FCacheLookupAllE, FCacheLookupE, FCondE, FExistsE, FExpr,
+                  FFoldE, FPointLookup, FQueryE, FSelLookupE, FTupleE,
+                  fir_children)
 
 __all__ = ["CostCatalog", "CostModel", "query_has_params",
            "query_param_cols", "query_pred_cols"]
-
-
-def _embedded_scalars(node):
-    """Every Scalar hanging off one dataclass node — covers predicates,
-    computed-projection pairs, and whatever scalar slots future operators
-    add, without naming fields."""
-    for f in dataclasses.fields(node):
-        v = getattr(node, f.name)
-        if isinstance(v, Scalar):
-            yield v
-        elif isinstance(v, tuple):
-            for item in v:
-                if isinstance(item, Scalar):
-                    yield item
-                elif isinstance(item, tuple):
-                    yield from (x for x in item if isinstance(x, Scalar))
-
-
-def query_has_params(q: Query) -> bool:
-    """True iff a relational tree contains a ``Param`` anywhere (predicates
-    and computed projections included) — the sites whose bindings may differ
-    between batched invocations, so they never amortize."""
-    def scalar_has(s: Scalar) -> bool:
-        if isinstance(s, Param):
-            return True
-        return any(scalar_has(k) for k in _embedded_scalars(s))
-
-    if any(scalar_has(s) for s in _embedded_scalars(q)):
-        return True
-    return any(query_has_params(c) for c in q.children())
 
 
 def query_param_cols(q: Query) -> Tuple[str, ...]:
@@ -321,8 +293,10 @@ class CostModel:
             return per_row + self._ops_cost(e.keyexpr, n_rows)
         if isinstance(e, FCacheLookupE):
             return c.c_y + self._ops_cost(e.keyexpr, n_rows)
-        if isinstance(e, FFoldE):
-            # nested fold: per-OUTER-row cost of running the inner loop
+        if isinstance(e, (FFoldE, FExistsE)):
+            # nested fold or existential check: per-OUTER-row cost of
+            # running the inner loop (a check may stop at its first match;
+            # priced, like the fold, for all the rows it may visit)
             src = e.source
             if isinstance(src, FQueryE):
                 inner_q_cost = self.query_cost_correlated(src.query)
@@ -337,8 +311,12 @@ class CostModel:
             else:
                 inner_q_cost = c.c_y
                 inner_rows = self.cat.loop_iters_default
-            assert isinstance(e.func, FTupleE)
-            per_inner = sum(self.slot_row_cost(i, inner_rows) for i in e.func.items)
+            if isinstance(e, FExistsE):
+                per_inner = self._ops_cost(e.pred, inner_rows)
+            else:
+                assert isinstance(e.func, FTupleE)
+                per_inner = sum(self.slot_row_cost(i, inner_rows)
+                                for i in e.func.items)
             return inner_q_cost + inner_rows * (per_inner + c.c_z)
         if isinstance(e, FQueryE):
             return self.query_cost(e.query)
@@ -375,10 +353,21 @@ class CostModel:
                 c += self._iexpr_cost(e2)
         return c
 
+    def guard_cost(self, pred) -> float:
+        """Cost of evaluating a branch's condition once: nothing beyond the
+        statement unless it runs queries (an existential check does)."""
+        return self._iexpr_cost(pred)
+
     def _iexpr_cost(self, e) -> float:
-        from .regions import ICacheLookup, ILoadAll, INav, IQuery
+        from .regions import ICacheLookup, IExists, ILoadAll, INav, IQuery
         if isinstance(e, IQuery):
             return self.query_cost(e.query)
+        if isinstance(e, IExists):
+            # its source once, then header + predicate per row it visits
+            rows = self.query_rows(e.source.query) \
+                if isinstance(e.source, IQuery) else self.cat.loop_iters_default
+            return self._iexpr_cost(e.source) + rows * (
+                self._iexpr_cost(e.pred) + 2 * self.cat.c_z)
         if isinstance(e, ILoadAll):
             return self.query_cost(Scan(e.table))
         if isinstance(e, INav):

@@ -30,19 +30,19 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 from ..relational.algebra import Cmp, Col, Param, Query, Scan, Select
 from ..relational.table import Table
-from .regions import (Assign, BasicBlock, CollectionAdd, CondRegion, IBin,
-                      ICacheLookup, ICall, IConst, IEmptyList, IEmptyMap,
-                      IExpr, IField, INav, IQuery, IVar, LoopRegion, MapPut,
-                      NoOp, Prefetch, Region, SeqRegion, Stmt, _BIN_OPS,
-                      _FUNCTIONS)
+from .regions import (Assign, BasicBlock, BreakStmt, CollectionAdd,
+                      CondRegion, IBin, ICacheLookup, ICall, IConst,
+                      IEmptyList, IEmptyMap, IExists, IExpr, IField, IMapGet,
+                      INav, IQuery, IVar, LoopRegion, MapPut, NoOp, Prefetch,
+                      Region, SeqRegion, Stmt, _BIN_OPS, _FUNCTIONS)
 
 __all__ = [
     "FExpr", "FConst", "FVarRef", "FAcc", "FRow", "FField", "FBin", "FCall",
     "FInsert", "FMapPutE", "FTupleE", "FProjectE", "FCondE", "FPointLookup",
     "FSelLookupE", "FCacheLookupE", "FCacheLookupAllE", "FQueryE", "FFoldE",
-    "FSeqE", "FPrefetchE", "loop_to_fir", "FIRConversionError", "eval_fir",
-    "fir_to_region", "fir_children", "fir_rebuild", "fir_map", "fold_to_loop",
-    "NameGen", "fold_accumulators",
+    "FSeqE", "FPrefetchE", "FExistsE", "FMapGetE", "FIfE", "loop_to_fir",
+    "FIRConversionError", "eval_fir", "fir_to_region", "fir_children",
+    "fir_rebuild", "fir_map", "fold_to_loop", "NameGen", "fold_accumulators",
 ]
 
 
@@ -280,15 +280,74 @@ class FCacheLookupAllE(FExpr):
 
 @dataclasses.dataclass(frozen=True, eq=False)
 class FQueryE(FExpr):
-    """A relational query leaf (executed at the database)."""
+    """A relational query leaf (executed at the database), its ``Param``s
+    bound to ``bindings`` (values at region entry)."""
 
     query: Query
+    bindings: Tuple[Tuple[str, FExpr], ...] = ()
 
     def key(self):
-        return ("fquery", self.query.key())
+        if not self.bindings:
+            return ("fquery", self.query.key())
+        return ("fquery", self.query.key(),
+                tuple((n, e.key()) for n, e in self.bindings))
 
     def __repr__(self):
-        return f"Q[{self.query.sql()}]"
+        if not self.bindings:
+            return f"Q[{self.query.sql()}]"
+        binds = ", ".join(f"{n}={e!r}" for n, e in self.bindings)
+        return f"Q[{self.query.sql()} | {binds}]"
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class FExistsE(FExpr):
+    """The existential fold: ``fold(or, false, σ_pred(source))`` — True iff
+    some row of ``source`` (named ``row_name`` in ``pred``) satisfies
+    ``pred``. ``any(...)`` and the flag loops, with or without ``break``,
+    all convert to it."""
+
+    source: FExpr   # FQueryE | FSelLookupE | FCacheLookupAllE
+    pred: FExpr
+    row_name: str
+
+    def key(self):
+        return ("fexists", self.source.key(), self.pred.key(), self.row_name)
+
+    def __repr__(self):
+        return f"exists({self.row_name} : {self.source!r} | {self.pred!r})"
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class FMapGetE(FExpr):
+    """``map.get(mkey, default)``."""
+
+    map: FExpr
+    mkey: FExpr
+    default: FExpr
+
+    def key(self):
+        return ("fmapget", self.map.key(), self.mkey.key(), self.default.key())
+
+    def __repr__(self):
+        return f"{self.map!r}.get({self.mkey!r}, {self.default!r})"
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class FIfE(FExpr):
+    """A value chosen by a predicate: ``then`` where ``pred`` holds, else
+    ``other`` — what a temporary holds after a guarded assignment. It lives
+    only during conversion: a fold whose updates still hold one after
+    :func:`_truth` and comparison folding has no F-IR form."""
+
+    pred: FExpr
+    then: FExpr
+    other: FExpr
+
+    def key(self):
+        return ("fif", self.pred.key(), self.then.key(), self.other.key())
+
+    def __repr__(self):
+        return f"if({self.pred!r}, {self.then!r}, {self.other!r})"
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -341,8 +400,16 @@ class FSeqE(FExpr):
 # --------------------------------------------------------------------------
 
 def fir_children(e: FExpr) -> Tuple[FExpr, ...]:
-    if isinstance(e, (FConst, FVarRef, FAcc, FRow, FQueryE, FPrefetchE)):
+    if isinstance(e, FQueryE):
+        return tuple(b for _, b in e.bindings)
+    if isinstance(e, (FConst, FVarRef, FAcc, FRow, FPrefetchE)):
         return ()
+    if isinstance(e, FExistsE):
+        return (e.source, e.pred)
+    if isinstance(e, FMapGetE):
+        return (e.map, e.mkey, e.default)
+    if isinstance(e, FIfE):
+        return (e.pred, e.then, e.other)
     if isinstance(e, FField):
         return (e.base,)
     if isinstance(e, FBin):
@@ -370,8 +437,17 @@ def fir_children(e: FExpr) -> Tuple[FExpr, ...]:
 
 def fir_rebuild(e: FExpr, new_children: Sequence[FExpr]) -> FExpr:
     c = tuple(new_children)
-    if isinstance(e, (FConst, FVarRef, FAcc, FRow, FQueryE, FPrefetchE)):
+    if isinstance(e, FQueryE):
+        return FQueryE(e.query, tuple((n, b) for (n, _), b in
+                                      zip(e.bindings, c))) if c else e
+    if isinstance(e, (FConst, FVarRef, FAcc, FRow, FPrefetchE)):
         return e
+    if isinstance(e, FExistsE):
+        return FExistsE(c[0], c[1], e.row_name)
+    if isinstance(e, FMapGetE):
+        return FMapGetE(c[0], c[1], c[2])
+    if isinstance(e, FIfE):
+        return FIfE(c[0], c[1], c[2])
     if isinstance(e, FField):
         return FField(c[0], e.col)
     if isinstance(e, FBin):
@@ -448,10 +524,16 @@ def _iexpr_to_fir(e: IExpr, subst: Dict[str, FExpr], row_names: Dict[str, str]) 
     if isinstance(e, IField):
         return FField(_iexpr_to_fir(e.base, subst, row_names), e.field)
     if isinstance(e, IBin):
-        return FBin(e.op, _iexpr_to_fir(e.left, subst, row_names),
-                    _iexpr_to_fir(e.right, subst, row_names))
+        return _fold_compare(FBin(e.op, _iexpr_to_fir(e.left, subst, row_names),
+                                  _iexpr_to_fir(e.right, subst, row_names)))
     if isinstance(e, ICall):
         return FCall(e.func, tuple(_iexpr_to_fir(a, subst, row_names) for a in e.args))
+    if isinstance(e, IExists):
+        return _exists_fold(e.var, e.source, e.pred, subst, row_names)
+    if isinstance(e, IMapGet):
+        return FMapGetE(_iexpr_to_fir(e.base, subst, row_names),
+                        _iexpr_to_fir(e.keyexpr, subst, row_names),
+                        _iexpr_to_fir(e.default, subst, row_names))
     if isinstance(e, INav):
         base = _iexpr_to_fir(e.base, subst, row_names)
         if isinstance(base, (FPointLookup, FCacheLookupE)):
@@ -478,9 +560,8 @@ def _iexpr_to_fir(e: IExpr, subst: Dict[str, FExpr], row_names: Dict[str, str]) 
             if isinstance(lhs, Col) and isinstance(rhs, Param) and rhs.name == pname:
                 return FSelLookupE(q.child.table, lhs.name,
                                    _iexpr_to_fir(bexpr, subst, row_names))
-        if e.bindings:
-            raise FIRConversionError(f"correlated query too complex: {e!r}")
-        return FQueryE(e.query)
+        return FQueryE(e.query, tuple(
+            (n, _iexpr_to_fir(b, subst, row_names)) for n, b in e.bindings))
     if isinstance(e, IEmptyList):
         return FConst(())
     if isinstance(e, IEmptyMap):
@@ -488,6 +569,74 @@ def _iexpr_to_fir(e: IExpr, subst: Dict[str, FExpr], row_names: Dict[str, str]) 
     if hasattr(e, "table") and type(e).__name__ == "ILoadAll":
         return FQueryE(Scan(e.table))
     raise FIRConversionError(f"cannot represent {e!r} in F-IR")
+
+
+def _fold_compare(e: FBin) -> FExpr:
+    """``if(p, a, b) == c`` (or ``!=``) over constants, folded to ``p``, its
+    negation or a constant: a flag set by a guarded assignment and then
+    tested reads as the guard itself."""
+    if e.op not in ("==", "!="):
+        return e
+    for f, c in ((e.left, e.right), (e.right, e.left)):
+        if isinstance(f, FIfE) and isinstance(c, FConst) \
+                and isinstance(f.then, FConst) and isinstance(f.other, FConst):
+            hit_then = (f.then.value == c.value) == (e.op == "==")
+            hit_other = (f.other.value == c.value) == (e.op == "==")
+            if hit_then == hit_other:
+                return FConst(hit_then)
+            return f.pred if hit_then else FBin("==", f.pred, FConst(False))
+    return e
+
+
+def _truth(e: FExpr) -> FExpr:
+    """A flag used as a condition: ``if(p, a, b)`` with a truthy constant
+    ``a`` and a falsy constant ``b`` holds exactly where ``p`` does."""
+    if isinstance(e, FIfE) and isinstance(e.then, FConst) \
+            and isinstance(e.other, FConst) \
+            and bool(e.then.value) and not bool(e.other.value):
+        return e.pred
+    return e
+
+
+def _exists_flag(loop: LoopRegion) -> Optional[Tuple[str, IExpr, IExpr]]:
+    """``(flag, value, pred)`` of a flag loop ``for v in src: if pred: flag =
+    value`` (``break`` after the assignment or not), whose value and guard
+    read neither the flag nor, for the value, the row: after it, ``flag``
+    is ``value`` exactly where some row satisfies ``pred``. None for any
+    other loop."""
+    body = loop.body
+    if not isinstance(body, CondRegion) or body.else_r is not None:
+        return None
+    then = body.then_r
+    parts = then.parts if isinstance(then, SeqRegion) else (then,)
+    if not all(isinstance(p, BasicBlock) for p in parts):
+        return None
+    stmts = [p.stmt for p in parts]
+    if stmts and isinstance(stmts[-1], BreakStmt):
+        stmts.pop()
+    if len(stmts) != 1 or not isinstance(stmts[0], Assign):
+        return None
+    flag, value = stmts[0].target, stmts[0].expr
+    if flag == loop.var or {flag, loop.var} & set(value.free_vars()) \
+            or flag in body.pred.free_vars():
+        return None
+    return flag, value, body.pred
+
+
+def _exists_fold(var: str, source: IExpr, pred: IExpr,
+                 subst: Dict[str, FExpr], row_names: Dict[str, str]
+                 ) -> FExistsE:
+    """The existential fold of ``any(pred for var in source)``; its
+    predicate may read its own row and values fixed for the check, and
+    no lookup or query."""
+    row = _row_name_for(var)
+    pred_f = _truth(_iexpr_to_fir(pred, subst, {**row_names, var: row}))
+    if fir_contains(pred_f, lambda x: isinstance(
+            x, (FPointLookup, FSelLookupE, FCacheLookupE, FCacheLookupAllE,
+                FQueryE, FFoldE, FExistsE, FIfE))):
+        raise FIRConversionError(f"existential predicate too complex: "
+                                 f"{pred!r}")
+    return FExistsE(_source_to_fir(source, subst, row_names), pred_f, row)
 
 
 def loop_to_fir(loop: LoopRegion) -> Tuple[FFoldE, Dict[str, int]]:
@@ -528,6 +677,15 @@ def _convert_loop(loop: LoopRegion, subst: Dict[str, FExpr],
             acc_order.append(name)
         acc_update[name] = upd
 
+    local: set = set()      # temporaries assigned in this body
+
+    def cond(guard: IExpr) -> FExpr:
+        return _truth(_iexpr_to_fir(guard, ctx(), row_names))
+
+    def own(target: str) -> Dict[str, FExpr]:
+        # a collection or map read in its own update is its running value
+        return {**ctx(), target: acc_ref(target)}
+
     def handle_stmt(stmt: Stmt, guard: Optional[IExpr]) -> None:
         if isinstance(stmt, Assign):
             e = stmt.expr
@@ -540,27 +698,33 @@ def _convert_loop(loop: LoopRegion, subst: Dict[str, FExpr],
                 cur = acc_ref(stmt.target)
                 upd = FBin(e.op, cur, other_f) if l_is else FBin(e.op, other_f, cur)
                 if guard is not None:
-                    upd = FCondE(_iexpr_to_fir(guard, ctx(), row_names), upd)
+                    upd = FCondE(cond(guard), upd)
                 record(stmt.target, upd)
                 return
+            val = _iexpr_to_fir(e, ctx(), row_names)
             if guard is not None:
-                raise FIRConversionError("guarded temp assignment")
-            subst[stmt.target] = _iexpr_to_fir(e, ctx(), row_names)
+                # a temporary of this body set under a guard keeps its
+                # earlier value in this iteration where the guard fails
+                if stmt.target not in local:
+                    raise FIRConversionError("guarded temp assignment")
+                val = FIfE(cond(guard), val, subst[stmt.target])
+            subst[stmt.target] = val
+            local.add(stmt.target)
             return
         if isinstance(stmt, CollectionAdd):
-            val = _iexpr_to_fir(stmt.expr, ctx(), row_names)
+            val = _iexpr_to_fir(stmt.expr, own(stmt.target), row_names)
             upd: FExpr = FInsert(acc_ref(stmt.target), val)
             if guard is not None:
-                upd = FCondE(_iexpr_to_fir(guard, ctx(), row_names), upd)
+                upd = FCondE(cond(guard), upd)
             record(stmt.target, upd)
             return
         if isinstance(stmt, MapPut):
-            c = ctx()
+            c = own(stmt.target)
             upd = FMapPutE(acc_ref(stmt.target),
                            _iexpr_to_fir(stmt.keyexpr, c, row_names),
                            _iexpr_to_fir(stmt.valexpr, c, row_names))
             if guard is not None:
-                upd = FCondE(_iexpr_to_fir(guard, c, row_names), upd)
+                upd = FCondE(cond(guard), upd)
             record(stmt.target, upd)
             return
         if isinstance(stmt, NoOp):
@@ -571,6 +735,20 @@ def _convert_loop(loop: LoopRegion, subst: Dict[str, FExpr],
         if isinstance(part, LoopRegion):
             if guard is not None:
                 raise FIRConversionError("guarded nested loop")
+            flag = _exists_flag(part)
+            if flag is not None:
+                # a flag loop: the flag is `value` where the existential
+                # fold holds, else what it held before the loop
+                name, value, pred = flag
+                if name not in local:
+                    raise FIRConversionError(
+                        f"flag {name!r} carried across iterations")
+                found = _exists_fold(part.var, part.source, pred, ctx(),
+                                     row_names)
+                subst[name] = FIfE(found,
+                                   _iexpr_to_fir(value, ctx(), row_names),
+                                   subst[name])
+                continue
             inner = _convert_loop(part, ctx(), row_names)
             if len(inner.acc_names) != 1:
                 raise FIRConversionError("nested loop with multiple accumulators")
@@ -597,6 +775,9 @@ def _convert_loop(loop: LoopRegion, subst: Dict[str, FExpr],
         return e
 
     func = FTupleE(tuple(unwrap(acc_update[a]) for a in acc_order))
+    if fir_contains(func, lambda x: isinstance(x, FIfE)):
+        raise FIRConversionError("an update reads a conditionally set "
+                                 "temporary")
     init = FTupleE(tuple(FVarRef(a) for a in acc_order))
     return FFoldE(func, init, source, tuple(acc_order), row_name)
 
@@ -723,7 +904,20 @@ def eval_fir(e: FExpr, env, state: Mapping[str, object],
         k = eval_fir(e.keyexpr, env, state, accs, rows)
         return env.lookup_cache_all(e.table, e.key_col, k)
     if isinstance(e, FQueryE):
-        return env.execute_query(e.query)
+        params = {n: eval_fir(b, env, state, accs, rows)
+                  for n, b in e.bindings}
+        return env.execute_query(e.query, params or None)
+    if isinstance(e, FExistsE):
+        src = eval_fir(e.source, env, state, accs, rows)
+        for rr in (src.to_rows() if isinstance(src, Table) else src):
+            if bool(eval_fir(e.pred, env, state, accs,
+                             {**rows, e.row_name: rr})):
+                return True
+        return False
+    if isinstance(e, FMapGetE):
+        return eval_fir(e.map, env, state, accs, rows).get(
+            eval_fir(e.mkey, env, state, accs, rows),
+            eval_fir(e.default, env, state, accs, rows))
     if isinstance(e, FPrefetchE):
         t = env.execute_query(e.query)
         env.cache_by_column(t, e.col)
@@ -817,14 +1011,36 @@ def _val_to_iexpr(e: FExpr, row_vars: Dict[str, str], pre: List[Region],
             e.table, e.key_col, _val_to_iexpr(e.keyexpr, row_vars, pre, names)))))
         return IVar(tmp)
     if isinstance(e, FQueryE):
-        return IQuery(e.query)
+        return _query_to_iexpr(e, row_vars, pre, names)
+    if isinstance(e, FExistsE):
+        var = names.fresh("r")
+        source = _source_to_iexpr(e.source, row_vars, pre, names)
+        # the predicate reads only its own row (and constants): nothing it
+        # computes is hoisted out of the check
+        inner: List[Region] = []
+        pred = _val_to_iexpr(e.pred, {**row_vars, e.row_name: var}, inner,
+                             names)
+        if inner:
+            raise TypeError(f"cannot codegen an existential check whose "
+                            f"predicate needs statements: {e!r}")
+        return IExists(var, source, pred)
+    if isinstance(e, FMapGetE):
+        return IMapGet(_val_to_iexpr(e.map, row_vars, pre, names),
+                       _val_to_iexpr(e.mkey, row_vars, pre, names),
+                       _val_to_iexpr(e.default, row_vars, pre, names))
     raise TypeError(f"cannot codegen value {e!r}")
+
+
+def _query_to_iexpr(e: FQueryE, row_vars: Dict[str, str], pre: List[Region],
+                    names: NameGen) -> IQuery:
+    return IQuery(e.query, tuple(
+        (n, _val_to_iexpr(b, row_vars, pre, names)) for n, b in e.bindings))
 
 
 def _source_to_iexpr(src: FExpr, row_vars: Dict[str, str], pre: List[Region],
                      names: NameGen) -> IExpr:
     if isinstance(src, FQueryE):
-        return IQuery(src.query)
+        return _query_to_iexpr(src, row_vars, pre, names)
     if isinstance(src, FSelLookupE):
         key = _val_to_iexpr(src.keyexpr, row_vars, pre, names)
         return IQuery(Select(Cmp("==", Col(src.key_col), Param("k")), Scan(src.table)),

@@ -40,7 +40,8 @@ from .dag import AndNode, Memo
 
 __all__ = ["MemoPool"]
 
-_SLOT_OPS = ("slot-project", "slot-query", "slot-query-rows")
+_SLOT_OPS = ("slot-project", "slot-query", "slot-query-rows",
+             "slot-query-map")
 
 
 @dataclasses.dataclass(frozen=True)

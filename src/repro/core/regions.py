@@ -39,7 +39,8 @@ from typing import Callable, Dict, Mapping, Optional, Sequence, Tuple, Union
 import jax.numpy as jnp
 import numpy as np
 
-from ..relational.algebra import Query, Scan
+from ..obs.transfer import to_host
+from ..relational.algebra import SERVER, Query, Scan
 from ..relational.database import ClientEnv
 from ..relational.table import Table
 from .context import loop_site_key, while_site_key
@@ -48,7 +49,7 @@ __all__ = [
     # expressions
     "IExpr", "IConst", "IVar", "IField", "IBin", "ICall", "IQuery", "ILoadAll",
     "INav", "ICacheLookup", "IEmptyList", "IEmptyMap", "IIndex", "ILen",
-    "IScalarQuery", "IQueryValues",
+    "IScalarQuery", "IQueryValues", "IQueryMap", "IExists", "IMapGet",
     # statements
     "Stmt", "Assign", "CollectionAdd", "MapPut", "Prefetch", "CacheByColumn",
     "UpdateRow", "NoOp", "BreakStmt", "ContinueStmt", "ReturnStmt",
@@ -303,6 +304,73 @@ class IQueryValues(IExpr):
 
     def __repr__(self):
         return f"queryValues({self.query.sql()!r}, {self.col})"
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class IQueryMap(IExpr):
+    """Execute a query and return ``{row[key_col]: row[val_col]}`` (a map
+    value): what a keyed accumulation loop computes once its grouped
+    aggregate runs at the database."""
+
+    query: Query
+    key_col: str
+    val_col: str
+    bindings: Tuple[Tuple[str, "IExpr"], ...] = ()
+
+    def key(self):
+        return ("iquerymap", self.query.key(), self.key_col, self.val_col,
+                tuple((n, e.key()) for n, e in self.bindings))
+
+    def free_vars(self):
+        out: Tuple[str, ...] = ()
+        for _, e in self.bindings:
+            out += e.free_vars()
+        return out
+
+    def __repr__(self):
+        return (f"queryMap({self.query.sql()!r}, {self.key_col} -> "
+                f"{self.val_col})")
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class IExists(IExpr):
+    """``any(pred for var in source)``: True iff some row of ``source`` (a
+    query, a cache lookup or a collection) satisfies ``pred``, with ``var``
+    bound to the row. Rows are visited in order until the first match."""
+
+    var: str
+    source: IExpr
+    pred: IExpr
+
+    def key(self):
+        return ("iexists", self.var, self.source.key(), self.pred.key())
+
+    def free_vars(self):
+        return self.source.free_vars() + tuple(
+            v for v in self.pred.free_vars() if v != self.var)
+
+    def __repr__(self):
+        return f"exists({self.var} : {self.source!r} | {self.pred!r})"
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class IMapGet(IExpr):
+    """``m.get(key, default)`` on a map value."""
+
+    base: IExpr
+    keyexpr: IExpr
+    default: IExpr
+
+    def key(self):
+        return ("imapget", self.base.key(), self.keyexpr.key(),
+                self.default.key())
+
+    def free_vars(self):
+        return (self.base.free_vars() + self.keyexpr.free_vars()
+                + self.default.free_vars())
+
+    def __repr__(self):
+        return f"{self.base!r}.get({self.keyexpr!r}, {self.default!r})"
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -848,6 +916,17 @@ class Interpreter:
         if isinstance(e, IQueryValues):
             t = env.execute_query(e.query)
             return np.asarray(t.column(e.col)).tolist()
+        if isinstance(e, IQueryMap):
+            params = {n: self.eval(x, state) for n, x in e.bindings}
+            t = env.execute_query(e.query, params or None)
+            keys = to_host(t.column(e.key_col), "interpreter.query_map")
+            vals = to_host(t.column(e.val_col), "interpreter.query_map")
+            return dict(zip(keys.tolist(), vals.tolist()))
+        if isinstance(e, IExists):
+            return self._exists(e, state)
+        if isinstance(e, IMapGet):
+            return self.eval(e.base, state).get(self.eval(e.keyexpr, state),
+                                                self.eval(e.default, state))
         if isinstance(e, IEmptyList):
             return []
         if isinstance(e, IEmptyMap):
@@ -864,6 +943,28 @@ class Interpreter:
             v = self.eval(e.base, state)
             return v.nrows if isinstance(v, Table) else len(v)
         raise TypeError(f"cannot eval {e!r}")
+
+    def _exists(self, e: IExists, state: Dict[str, object]) -> bool:
+        """One existential check answered row at a time: the source's rows
+        in order, charged like the loop it stands for, until the first
+        match."""
+        SERVER.inc("exists_per_row")
+        src = self.eval(e.source, state)
+        rows = src.to_rows() if isinstance(src, Table) else src
+        missing = object()
+        saved = state.get(e.var, missing)
+        try:
+            for row in rows:
+                self.env.charge_statement(2)  # loop header + condition
+                state[e.var] = _Row(row) if isinstance(row, dict) else row
+                if bool(self.eval(e.pred, state)):
+                    return True
+            return False
+        finally:
+            if saved is missing:
+                state.pop(e.var, None)
+            else:
+                state[e.var] = saved
 
     # ----------------------------------------------------------- statements
     def exec_stmt(self, s: Stmt, state: Dict[str, object]) -> None:

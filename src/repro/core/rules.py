@@ -6,14 +6,15 @@ Memo layout produced by ``build_memo`` + the F-IR conversion rule:
                └── AND("assemble", [g_v1 .. g_vk]) (F-IR form, Fig. 10)
   g_vi        ──┬── AND("slot-project", payload=(var, i, fold-or-seq expr))
                ├── AND("slot-query",       ...)    from T5  (γ aggregate)
+               ├── AND("slot-query-map",   ...)    from T5g (grouped γ → map)
                └── AND("slot-query-rows",  ...)    from T1/T4 (collection query)
 
-Fold-rewriting rules (T2/N2 correlated+plain, N1, N1a) fire on
+Fold-rewriting rules (T2/N2 correlated+plain, N1, N1a, SJ) fire on
 ``slot-project`` nodes and add new ``slot-project`` alternatives whose
 payload embeds the rewritten fold (possibly wrapped in seq(prefetch, ...)).
-Slot-extraction rules (T1, T4, T5) fire on ``slot-project`` nodes and add
-``slot-query[-rows]`` alternatives. Duplicate detection in the memo makes
-the cyclic pairs (T2 ↔ N2) terminate.
+Slot-extraction rules (T1, T4, T5, T5g) fire on ``slot-project`` nodes and
+add ``slot-query[-rows|-map]`` alternatives. Duplicate detection in the
+memo makes the cyclic pairs (T2 ↔ N2) terminate.
 """
 
 from __future__ import annotations
@@ -21,15 +22,16 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from ..relational.algebra import (AggSpec, Aggregate, Arith, Cmp, Col, Func,
-                                  Join, Lit, Param, Project, Query, Scalar,
-                                  Scan, Select)
+from ..relational.algebra import (AggSpec, Aggregate, Arith, BoolOp, Cmp,
+                                  Col, Func, Join, Lit, Param, Project, Query,
+                                  Scalar, Scan, Select, SemiJoin)
 from .dag import AndNode, Memo, Rule
 from .fir import (FAcc, FBin, FCacheLookupAllE, FCacheLookupE, FCall, FCondE,
-                  FConst, FExpr, FField, FFoldE, FInsert, FPointLookup,
-                  FProjectE, FQueryE, FRow, FSelLookupE, FSeqE, FTupleE,
-                  FVarRef, FIRConversionError, FPrefetchE, fir_children,
-                  fir_contains, fir_map, loop_to_fir)
+                  FConst, FExistsE, FExpr, FField, FFoldE, FInsert, FMapGetE,
+                  FMapPutE, FPointLookup, FProjectE, FQueryE, FRow,
+                  FSelLookupE, FSeqE, FTupleE, FVarRef, FIRConversionError,
+                  FPrefetchE, fir_children, fir_contains, fir_map,
+                  loop_to_fir)
 from .regions import (Assign, BasicBlock, CondRegion, IConst, IEmptyList,
                       IEmptyMap, LoopRegion, Program, Region, SeqRegion,
                       WhileRegion)
@@ -138,6 +140,8 @@ def _fexpr_to_scalar(e: FExpr, colmap: Dict[Tuple[str, str], str]) -> Scalar:
             return Arith(e.op, l, r)
         if e.op in ("==", "!=", "<", "<=", ">", ">="):
             return Cmp(e.op, l, r)
+        if e.op in ("and", "or"):
+            return BoolOp(e.op, l, r)
         raise _NotScalar(e.op)
     if isinstance(e, FCall):
         return Func(e.func, tuple(_fexpr_to_scalar(a, colmap) for a in e.args))
@@ -160,11 +164,20 @@ def _row_fields(e: FExpr, row: str) -> List[str]:
 def _only_over_rows(e: FExpr, rows: frozenset) -> bool:
     """True iff e references only given row vars + constants (no accs/lookups)."""
     if isinstance(e, (FAcc, FVarRef, FPointLookup, FSelLookupE, FCacheLookupE,
-                      FCacheLookupAllE, FFoldE, FQueryE)):
+                      FCacheLookupAllE, FFoldE, FQueryE, FExistsE, FMapGetE)):
         return False
     if isinstance(e, FRow):
         return e.name in rows
     return all(_only_over_rows(k, rows) for k in fir_children(e))
+
+
+def _plain_query(src: FExpr) -> Optional[Query]:
+    """The query of a fold source with no parameters to bind, else None:
+    the rules that turn a source into a query of another shape, or into a
+    result the program does not bind, take only these."""
+    if isinstance(src, FQueryE) and not src.bindings:
+        return src.query
+    return None
 
 
 def _get_parts(payload: FExpr) -> Tuple[Tuple[FExpr, ...], FFoldE]:
@@ -226,7 +239,7 @@ def rule_T1(memo: Memo, and_id: int, ctx: RuleContext) -> int:
     if s is None:
         return 0
     node, var, i, pre, fold = s
-    if pre or not isinstance(fold.source, FQueryE):
+    if pre or _plain_query(fold.source) is None:
         return 0
     upd = fold.func.items[i]
     if not (isinstance(upd, FInsert) and isinstance(upd.coll, FAcc)
@@ -250,18 +263,19 @@ def rule_T5(memo: Memo, and_id: int, ctx: RuleContext) -> int:
     node, var, i, pre, fold = s
     if pre:
         return 0
-    binding: Optional[FExpr] = None
+    bindings: Tuple[Tuple[str, FExpr], ...] = ()
     if isinstance(fold.source, FQueryE):
         base_q = fold.source.query
+        bindings = fold.source.bindings
     elif isinstance(fold.source, FSelLookupE):
         src = fold.source
-        # correlated aggregate: σ_{A=:k}(R) — the key expr must be evaluable
-        # at the region entry (no reference to this fold's row)
-        if fir_contains(src.keyexpr, lambda x: isinstance(x, FRow)):
-            return 0
         base_q = Select(Cmp("==", Col(src.key_col), Param("k")), Scan(src.table))
-        binding = src.keyexpr
+        bindings = (("k", src.keyexpr),)
     else:
+        return 0
+    # a correlated aggregate: the bound values must be evaluable at the
+    # region entry (no reference to this fold's row)
+    if any(fir_contains(b, lambda x: isinstance(x, FRow)) for _, b in bindings):
         return 0
     upd = fold.func.items[i]
     if isinstance(upd, FCondE):
@@ -300,7 +314,7 @@ def rule_T5(memo: Memo, and_id: int, ctx: RuleContext) -> int:
             agg_q = Aggregate((), (AggSpec(_AGG_OF_OP[upd.op], "h_val", "agg_out"),),
                               proj)
     memo.insert(AndNode("slot-query", (),
-                        ("agg", var, agg_q, upd.op, "agg_out", binding)),
+                        ("agg", var, agg_q, upd.op, "agg_out", bindings)),
                 group=memo.owner(and_id))
     return 1
 
@@ -316,7 +330,7 @@ def rule_T4(memo: Memo, and_id: int, ctx: RuleContext) -> int:
     if s is None:
         return 0
     node, var, i, pre, fold = s
-    if pre or not isinstance(fold.source, FQueryE):
+    if pre or _plain_query(fold.source) is None:
         return 0
     upd = fold.func.items[i]
     if isinstance(upd, FProjectE):
@@ -423,7 +437,8 @@ def rule_point_to_join(memo: Memo, and_id: int, ctx: RuleContext) -> int:
         return e
 
     new_func = fir_map(fold.func, rewrite)
-    new_fold = FFoldE(new_func, fold.init, FQueryE(q), fold.acc_names,
+    new_fold = FFoldE(new_func, fold.init,
+                      FQueryE(q, fold.source.bindings), fold.acc_names,
                       fold.row_name)
     return _add_slot_variant(memo, and_id, var, i, new_fold, ctx, fold)
 
@@ -525,7 +540,8 @@ def rule_T2_plain(memo: Memo, and_id: int, ctx: RuleContext) -> int:
     if len(fold.acc_names) != 1:
         return 0  # σ push must preserve the other slots' row set
     new_fold = FFoldE(FTupleE((upd.then,)), fold.init,
-                      FQueryE(Select(pred, fold.source.query)),
+                      FQueryE(Select(pred, fold.source.query),
+                              fold.source.bindings),
                       fold.acc_names, fold.row_name)
     return _add_slot_variant(memo, and_id, var, i, _mk_payload(pre, new_fold), ctx, fold)
 
@@ -545,7 +561,8 @@ def rule_N2_plain(memo: Memo, and_id: int, ctx: RuleContext) -> int:
     if pred_f is None:
         return 0
     new_fold = FFoldE(FTupleE((FCondE(pred_f, fold.func.items[i]),)), fold.init,
-                      FQueryE(sel.child), fold.acc_names, fold.row_name)
+                      FQueryE(sel.child, fold.source.bindings),
+                      fold.acc_names, fold.row_name)
     return _add_slot_variant(memo, and_id, var, i, _mk_payload(pre, new_fold), ctx, fold)
 
 
@@ -609,8 +626,9 @@ def rule_N1(memo: Memo, and_id: int, ctx: RuleContext) -> int:
 
 
 def rule_N1_all(memo: Memo, and_id: int, ctx: RuleContext) -> int:
-    """N1 (set form): an inner fold over a correlated σ source → prefetch the
-    whole relation + iterate the local multi-row cache lookup."""
+    """N1 (set form): an inner fold or existential check over a correlated
+    σ source → prefetch the whole relation + iterate the local multi-row
+    cache lookup."""
     s = _slot(memo, and_id)
     if s is None:
         return 0
@@ -618,12 +636,14 @@ def rule_N1_all(memo: Memo, and_id: int, ctx: RuleContext) -> int:
     targets = set()
 
     def rewrite(e: FExpr) -> FExpr:
-        if isinstance(e, FFoldE) and isinstance(e.source, FSelLookupE):
+        if isinstance(e, (FFoldE, FExistsE)) \
+                and isinstance(e.source, FSelLookupE):
             src = e.source
             targets.add((src.table, src.key_col))
-            return FFoldE(e.func, e.init,
-                          FCacheLookupAllE(src.table, src.key_col, src.keyexpr),
-                          e.acc_names, e.row_name)
+            lookup = FCacheLookupAllE(src.table, src.key_col, src.keyexpr)
+            if isinstance(e, FExistsE):
+                return FExistsE(lookup, e.pred, e.row_name)
+            return FFoldE(e.func, e.init, lookup, e.acc_names, e.row_name)
         return e
 
     new_fold = fir_map(fold, rewrite)
@@ -677,9 +697,130 @@ def rule_T3(memo: Memo, and_id: int, ctx: RuleContext) -> int:
         return e
 
     new_items = tuple(fir_map(it, rewrite) for it in fold.func.items)
-    new_fold = FFoldE(FTupleE(new_items), fold.init, FQueryE(new_q),
+    new_fold = FFoldE(FTupleE(new_items), fold.init,
+                      FQueryE(new_q, fold.source.bindings),
                       fold.acc_names, fold.row_name)
     return _add_slot_variant(memo, and_id, var, i, _mk_payload(pre, new_fold), ctx, fold)
+
+
+# --------------------------------------------------------------------------
+# Existential checks and keyed accumulation: SJ, T5g
+# --------------------------------------------------------------------------
+
+def _conjuncts(e: FExpr) -> List[FExpr]:
+    if isinstance(e, FBin) and e.op == "and":
+        return _conjuncts(e.left) + _conjuncts(e.right)
+    return [e]
+
+
+def rule_semijoin(memo: Memo, and_id: int, ctx: RuleContext) -> int:
+    """fold(?(∃ t2 ∈ σ_{A = t.B}(R) : p(t2), g), id, Q) ≡
+    fold(g, id, Q ⋉_{B = A} σ_p(R)) — an existential check correlated on
+    one key becomes a semi-join evaluated at the database: one query for
+    the loop instead of one per row. Conjuncts of the guard over the outer
+    row alone become a σ on Q."""
+    s = _slot(memo, and_id)
+    if s is None:
+        return 0
+    node, var, i, pre, fold = s
+    if pre or not isinstance(fold.source, FQueryE) \
+            or len(fold.acc_names) != 1:
+        return 0   # the semi-join must keep every slot's row set
+    upd = fold.func.items[i]
+    if not isinstance(upd, FCondE):
+        return 0
+    parts = _conjuncts(upd.pred)
+    checks = [c for c in parts if isinstance(c, FExistsE)]
+    if len(checks) != 1:
+        return 0
+    ex = checks[0]
+    src, key = ex.source, getattr(ex.source, "keyexpr", None)
+    if not (isinstance(src, FSelLookupE) and isinstance(key, FField)
+            and isinstance(key.base, FRow)
+            and key.base.name == fold.row_name):
+        return 0
+    outer_rows = frozenset([fold.row_name])
+    rest = [c for c in parts if c is not ex]
+    if not _only_over_rows(ex.pred, frozenset([ex.row_name])) \
+            or not all(_only_over_rows(c, outer_rows) for c in rest):
+        return 0
+    try:
+        inner = _fexpr_to_scalar(ex.pred, _self_colmap(ex.pred, ex.row_name))
+        outer = [_fexpr_to_scalar(c, _self_colmap(c, fold.row_name))
+                 for c in rest]
+    except _NotScalar:
+        return 0
+    left = fold.source.query
+    for p in outer:
+        left = Select(p, left)
+    semi = SemiJoin(left, Select(inner, Scan(src.table)), key.col,
+                    src.key_col)
+    new_fold = FFoldE(FTupleE((upd.then,)), fold.init,
+                      FQueryE(semi, fold.source.bindings),
+                      fold.acc_names, fold.row_name)
+    return _add_slot_variant(memo, and_id, var, i, new_fold, ctx, fold)
+
+
+def rule_T5_grouped(memo: Memo, and_id: int, ctx: RuleContext) -> int:
+    """fold(mapput(m, t.K, m.get(t.K, 0) + h), {}, Q) ≡ γ_{K; count | sum(h)}(Q)
+    — keyed accumulation becomes a grouped aggregate at the database (T5's
+    grouped form), the map built from its rows. Counts, and sums of
+    integer columns: answers the database computes exactly."""
+    s = _slot(memo, and_id)
+    if s is None:
+        return 0
+    node, var, i, pre, fold = s
+    if pre or not isinstance(fold.source, FQueryE):
+        return 0
+    if (fold.key(), var) not in ctx.empty_vars:
+        return 0  # the map is built from the query alone: it must start empty
+    rows = frozenset([fold.row_name])
+    base_q = fold.source.query
+    upd = fold.func.items[i]
+    if isinstance(upd, FCondE):
+        if not _only_over_rows(upd.pred, rows):
+            return 0
+        try:
+            base_q = Select(_fexpr_to_scalar(
+                upd.pred, _self_colmap(upd.pred, fold.row_name)), base_q)
+        except _NotScalar:
+            return 0
+        upd = upd.then
+    if not (isinstance(upd, FMapPutE) and isinstance(upd.map, FAcc)
+            and upd.map.name == var and isinstance(upd.val, FBin)
+            and upd.val.op == "+"):
+        return 0
+    key = upd.mkey
+    if not (isinstance(key, FField) and isinstance(key.base, FRow)
+            and key.base.name == fold.row_name):
+        return 0
+    for got, h in ((upd.val.left, upd.val.right),
+                   (upd.val.right, upd.val.left)):
+        if isinstance(got, FMapGetE) and isinstance(got.map, FAcc) \
+                and got.map.name == var and got.mkey == key \
+                and got.default == FConst(0):
+            break
+    else:
+        return 0
+    if h == FConst(1):
+        spec = AggSpec("count", None, "agg_out")
+    elif isinstance(h, FField) and isinstance(h.base, FRow) \
+            and h.base.name == fold.row_name:
+        try:
+            dtype = base_q.output_schema(ctx.db).field(h.col).dtype
+        except Exception:
+            return 0
+        if not dtype.startswith("int"):
+            return 0
+        spec = AggSpec("sum", h.col, "agg_out")
+    else:
+        return 0
+    agg_q = Aggregate((key.col,), (spec,), base_q)
+    memo.insert(AndNode("slot-query-map", (),
+                        ("map", var, agg_q, key.col, "agg_out",
+                         fold.source.bindings)),
+                group=memo.owner(and_id))
+    return 1
 
 
 # --------------------------------------------------------------------------
@@ -701,6 +842,8 @@ def default_rules() -> List[Rule]:
         Rule("T4", "slot-project", rule_T4),
         Rule("T4j", "slot-project", rule_point_to_join),
         Rule("T5", "slot-project", rule_T5),
+        Rule("T5g", "slot-project", rule_T5_grouped),
+        Rule("SJ", "slot-project", rule_semijoin),
         Rule("N1", "slot-project", rule_N1),
         Rule("N1a", "slot-project", rule_N1_all),
     ]

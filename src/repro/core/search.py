@@ -29,8 +29,8 @@ from .cost import CostCatalog, CostModel, query_has_params
 from .dag import AndNode, Budget, Memo, expand, expand_exhaustive
 from .fir import FExpr, FPrefetchE, NameGen, fold_to_loop
 from .regions import (Assign, BasicBlock, CondRegion, IBin, IQuery,
-                      IQueryValues, IScalarQuery, IVar, LoopRegion, Program,
-                      Region, SeqRegion, WhileRegion)
+                      IQueryMap, IQueryValues, IScalarQuery, IVar, LoopRegion,
+                      Program, Region, SeqRegion, WhileRegion)
 from .rules import RuleContext, _get_parts, build_memo, default_rules
 
 __all__ = ["optimize", "run_search", "OptimizationResult", "Plan",
@@ -158,10 +158,12 @@ class Searcher:
             return base, _merge_resources(*[p.resources for p in children])
         if node.op == "cond":
             p = cat.cond_prob_default
+            # the guard itself: an existential check in it runs its query
+            base = cat.c_z + cm.guard_cost(node.payload)
             if len(children) == 1:
-                base = cat.c_z + p * children[0].base
+                base += p * children[0].base
             else:
-                base = cat.c_z + p * children[0].base + (1 - p) * children[1].base
+                base += p * children[0].base + (1 - p) * children[1].base
             return base, _merge_resources(*[c.resources for c in children])
         if node.op == "loop":
             var, source = node.payload
@@ -229,10 +231,10 @@ class Searcher:
                                 cm.amortize(p_cost) if p_am else
                                 p_cost * cm.param_site_amortization(p.query)))
             return n * slot, tuple(res)
-        if node.op == "slot-query":
-            _, var, q, op, col, binding = node.payload
+        if node.op in ("slot-query", "slot-query-map"):
+            q, bindings = node.payload[2], node.payload[5]
             qc = cm.query_cost(q)
-            if binding is None and not query_has_params(q) \
+            if not bindings and not query_has_params(q) \
                     and cm.tables_shareable(scan_tables(q)):
                 qc = cm.amortize(qc)
             else:
@@ -266,7 +268,7 @@ def _sql_push_score(p: Plan) -> int:
     score = 0
     if p.op == "slot-query-rows":
         score += 100
-    if p.op == "slot-query":
+    if p.op in ("slot-query", "slot-query-map"):
         score += 80
     if p.op == "slot-project":
         _, _, _, payload = p.payload
@@ -341,8 +343,11 @@ def _assemble_to_region(plan: Plan, emitted_prefetch: set,
             k = payload.key()
             fold_slots.setdefault(k, (payload, []))[1].append(i)
         elif c.op == "slot-query":
-            _, var, q, op, col, binding = c.payload
-            queries.append((var, ("agg", q, op, col, binding)))
+            _, var, q, op, col, bindings = c.payload
+            queries.append((var, ("agg", q, op, col, bindings)))
+        elif c.op == "slot-query-map":
+            _, var, q, key_col, col, bindings = c.payload
+            queries.append((var, ("map", q, key_col, col, bindings)))
         elif c.op == "slot-query-rows":
             _, var, q, col = c.payload
             queries.append((var, ("rows", q, col)))
@@ -368,13 +373,14 @@ def _assemble_to_region(plan: Plan, emitted_prefetch: set,
         if var in covered:
             continue  # dependency closure already computes it in a loop
         if spec[0] == "agg":
-            _, q, op, col, binding = spec
-            bindings = ()
-            if binding is not None:
-                from .fir import _val_to_iexpr
-                bindings = (("k", _val_to_iexpr(binding, {}, [], names)),)
+            _, q, op, col, bindings = spec
             parts.append(BasicBlock(Assign(
-                var, IBin(op, IVar(var), IScalarQuery(q, col, bindings)))))
+                var, IBin(op, IVar(var), IScalarQuery(
+                    q, col, _bound(bindings, names))))))
+        elif spec[0] == "map":
+            _, q, key_col, col, bindings = spec
+            parts.append(BasicBlock(Assign(
+                var, IQueryMap(q, key_col, col, _bound(bindings, names)))))
         else:
             _, q, col = spec
             if col is None:
@@ -383,6 +389,12 @@ def _assemble_to_region(plan: Plan, emitted_prefetch: set,
                 parts.append(BasicBlock(Assign(var, IQueryValues(q, col))))
     parts.extend(loops)
     return SeqRegion(tuple(parts)) if len(parts) != 1 else parts[0]
+
+
+def _bound(bindings, names: NameGen) -> Tuple:
+    """A query's F-IR parameter bindings as imperative expressions."""
+    from .fir import _val_to_iexpr
+    return tuple((n, _val_to_iexpr(b, {}, [], names)) for n, b in bindings)
 
 
 def _loop_assigned_vars(r: Region) -> set:

@@ -33,9 +33,9 @@ from ..obs.transfer import to_host
 from ..relational.table import Table
 from .regions import (Assign, BasicBlock, BreakStmt, CollectionAdd, CondRegion,
                       ContinueStmt, IBin, ICacheLookup, ICall, IConst, IField,
-                      ILen, INav, IVar, LoopRegion, MapPut, NoOp, Region,
-                      ReturnStmt, SeqRegion, Stmt, UpdateRow, _BIN_OPS,
-                      _FUNCTIONS)
+                      ILen, IMapGet, INav, IVar, LoopRegion, MapPut, NoOp,
+                      Region, ReturnStmt, SeqRegion, Stmt, UpdateRow,
+                      _BIN_OPS, _FUNCTIONS)
 
 __all__ = ["analyze_loop", "exec_loop_plan", "try_exec_loop_fast",
            "LoopHooks", "LoopPlan"]
@@ -88,6 +88,21 @@ def _is_pure_vec(e, rowvars: set, rowtmps: set, scalartmps: set) -> bool:
     return False
 
 
+def keyed_accumulation(stmt: MapPut):
+    """``(key, default, delta)`` of a keyed accumulation ``m[k] =
+    m.get(k, d) + delta`` (either operand order, ``d`` a constant), or
+    None for any other map write."""
+    v = stmt.valexpr
+    if not (isinstance(v, IBin) and v.op == "+"):
+        return None
+    for got, delta in ((v.left, v.right), (v.right, v.left)):
+        if isinstance(got, IMapGet) and got.base == IVar(stmt.target) \
+                and got.keyexpr == stmt.keyexpr \
+                and isinstance(got.default, IConst):
+            return stmt.keyexpr, got.default.value, delta
+    return None
+
+
 def analyze_loop(r: LoopRegion, invariants: Dict[str, object]) -> Optional[LoopPlan]:
     flat = _flatten(r.body)
     if flat is None:
@@ -101,19 +116,31 @@ def analyze_loop(r: LoopRegion, invariants: Dict[str, object]) -> Optional[LoopP
     # (then its per-row column — including an accumulator's running value —
     # is available). Referencing it BEFORE its definition means reading the
     # previous iteration's value, which has no columnar form outside the
-    # matched `acc = acc <op> x` shape; those loops run exact.
+    # matched `acc = acc <op> x` shape; those loops run exact. A variable
+    # last assigned under a guard holds, where the guard failed, a value
+    # carried from an earlier row: it may be read under that guard only.
     body_defs = {s.target for s, _ in flat
                  if isinstance(s, Assign)}
     defined: set = set()
+    guard_of: Dict[str, object] = {}
+    map_writes = [s.target for s, _ in flat if isinstance(s, MapPut)]
 
-    def refs_ok(e) -> bool:
-        return all(nm not in body_defs or nm in defined
+    def refs_ok(e, guard=None) -> bool:
+        return all((nm not in body_defs or nm in defined)
+                   and guard_of.get(nm) in (None, guard)
                    for nm in e.free_vars())
+
+    def pure(e, guard) -> bool:
+        return _is_pure_vec(e, rowvars, rowtmps, scalartmps) \
+            and refs_ok(e, guard)
+
+    def define(target: str, guard) -> None:
+        defined.add(target)
+        guard_of[target] = guard
 
     for stmt, guard in flat:
         if isinstance(stmt, tuple) and stmt[0] == "__guard__":
-            if not (_is_pure_vec(stmt[1], rowvars, rowtmps, scalartmps)
-                    and refs_ok(stmt[1])):
+            if not pure(stmt[1], guard):
                 return None
             continue
         if isinstance(stmt, (BreakStmt, ContinueStmt, ReturnStmt)):
@@ -130,14 +157,13 @@ def analyze_loop(r: LoopRegion, invariants: Dict[str, object]) -> Optional[LoopP
                 if guard is not None:
                     return None  # guarded nav: cache-state depends on mask order; exact only
                 rowtmps.add(stmt.target)
-                defined.add(stmt.target)
+                define(stmt.target, guard)
                 continue
             if isinstance(e, ICacheLookup) and not e.all_matches:
-                if not (_is_pure_vec(e.keyexpr, rowvars, rowtmps, scalartmps)
-                        and refs_ok(e.keyexpr)):
+                if not pure(e.keyexpr, guard):
                     return None
                 rowtmps.add(stmt.target)
-                defined.add(stmt.target)
+                define(stmt.target, guard)
                 continue
             # scalar accumulator: acc = acc <op> expr | expr <op> acc
             if isinstance(e, IBin) and e.op in _ACC_OPS \
@@ -146,36 +172,36 @@ def analyze_loop(r: LoopRegion, invariants: Dict[str, object]) -> Optional[LoopP
                 r_is_acc = isinstance(e.right, IVar) and e.right.name == stmt.target
                 if l_is_acc != r_is_acc:
                     other = e.right if l_is_acc else e.left
-                    if _is_pure_vec(other, rowvars, rowtmps, scalartmps) \
-                            and refs_ok(other):
+                    if pure(other, guard):
                         if stmt.target not in accs:
                             accs.append(stmt.target)
                         scalartmps.add(stmt.target)
-                        defined.add(stmt.target)
+                        # the running column holds on every row
+                        define(stmt.target, None)
                         continue
                     return None
-            if _is_pure_vec(e, rowvars, rowtmps, scalartmps) and refs_ok(e):
+            if pure(e, guard):
                 scalartmps.add(stmt.target)
-                defined.add(stmt.target)
+                define(stmt.target, guard)
                 continue
             return None
         if isinstance(stmt, CollectionAdd):
-            if not (_is_pure_vec(stmt.expr, rowvars, rowtmps, scalartmps)
-                    and refs_ok(stmt.expr)):
+            if not pure(stmt.expr, guard):
                 return None
             continue
         if isinstance(stmt, MapPut):
-            if not (_is_pure_vec(stmt.keyexpr, rowvars, rowtmps, scalartmps)
-                    and refs_ok(stmt.keyexpr)
-                    and _is_pure_vec(stmt.valexpr, rowvars, rowtmps, scalartmps)
-                    and refs_ok(stmt.valexpr)):
+            keyed = keyed_accumulation(stmt)
+            if keyed is not None and map_writes.count(stmt.target) == 1:
+                # m[k] = m.get(k, d) + delta: a sum by key (the map's only
+                # write in the body, so each key's adds keep row order)
+                if not (pure(keyed[0], guard) and pure(keyed[2], guard)):
+                    return None
+                continue
+            if not (pure(stmt.keyexpr, guard) and pure(stmt.valexpr, guard)):
                 return None
             continue
         if isinstance(stmt, UpdateRow):
-            if not (_is_pure_vec(stmt.val, rowvars, rowtmps, scalartmps)
-                    and refs_ok(stmt.val)
-                    and _is_pure_vec(stmt.keyexpr, rowvars, rowtmps, scalartmps)
-                    and refs_ok(stmt.keyexpr)):
+            if not (pure(stmt.val, guard) and pure(stmt.keyexpr, guard)):
                 return None
             continue
         if isinstance(stmt, NoOp):
@@ -290,12 +316,15 @@ def exec_loop_plan(env, r: LoopRegion, src: Table, state: Dict[str, object],
     env.charge_statement(n)  # loop header per iteration
     mask = np.ones(n, dtype=bool)
     active = n
+    # each body temporary's assignments: (rows it ran on, or None for all)
+    assigned: Dict[str, List[Optional[np.ndarray]]] = {}
 
     for stmt, guard in plan.stmts:
         if isinstance(stmt, tuple) and stmt[0] == "__guard__":
-            env.charge_statement(int(mask.sum()))  # cond evaluation per row
+            # a top-level `if`: every row evaluates its condition
+            env.charge_statement(n)
             pred = np.broadcast_to(np.asarray(_eval_vec(stmt[1], ce)), (n,))
-            mask = mask & pred.astype(bool)
+            mask = pred.astype(bool)
             active = int(mask.sum())
             continue
         nexec = active if guard is not None else n
@@ -304,25 +333,32 @@ def exec_loop_plan(env, r: LoopRegion, src: Table, state: Dict[str, object],
             if isinstance(e, INav):
                 nav(env, ce, stmt.target, e, n)
                 env.charge_statement(nexec)  # the assign itself
-                continue
-            if isinstance(e, ICacheLookup):
+            elif isinstance(e, ICacheLookup):
                 cache_lookup(env, ce, stmt.target, e, n)
                 env.charge_statement(nexec)   # assign
                 env.charge_statement(nexec)   # lookup_cache charge
-                continue
-            if stmt.target in plan.accumulators and isinstance(e, IBin) and e.op in _ACC_OPS:
+            elif stmt.target in plan.accumulators and isinstance(e, IBin) and e.op in _ACC_OPS:
                 accumulate(ce, stmt, e, mask if guard is not None else None, state)
                 env.charge_statement(nexec)
                 continue
-            val = _eval_vec(e, ce)
-            ce.cols[stmt.target] = _broadcast(val, n) if not isinstance(val, dict) else val
-            env.charge_statement(nexec)
+            else:
+                val = _eval_vec(e, ce)
+                ce.cols[stmt.target] = _broadcast(val, n) if not isinstance(val, dict) else val
+                env.charge_statement(nexec)
+            assigned.setdefault(stmt.target, []).append(
+                mask.copy() if guard is not None else None)
             continue
         if isinstance(stmt, CollectionAdd):
             vals = _broadcast(_eval_vec(stmt.expr, ce), n)
             sel = vals[mask] if guard is not None else vals
             with tracer.span("loop.export", n=len(sel)):
                 state.setdefault(stmt.target, []).extend(sel.tolist())
+            env.charge_statement(nexec)
+            continue
+        if isinstance(stmt, MapPut) and keyed_accumulation(stmt) is not None:
+            with tracer.span("loop.export", n=nexec):
+                _vec_keyed_accumulate(ce, stmt, mask if guard is not None
+                                      else None, state)
             env.charge_statement(nexec)
             continue
         if isinstance(stmt, MapPut):
@@ -350,6 +386,36 @@ def exec_loop_plan(env, r: LoopRegion, src: Table, state: Dict[str, object],
         col = ce.cols.get(acc)
         if isinstance(col, np.ndarray):
             state[acc] = col[-1].item()
+    # every other body variable ends with the value of its last executed
+    # assignment, as the row-at-a-time loop leaves it
+    for name, runs in assigned.items():
+        row = _last_run_row(runs, n)
+        if row is not None:
+            value = ce.rows.get(name, ce.cols.get(name))
+            state[name] = _value_at(value, row)
+
+
+def _last_run_row(runs: List[Optional[np.ndarray]], n: int) -> Optional[int]:
+    """The last row on which any of a variable's assignments ran, each
+    given by the rows it ran on (None: every row); None if it never ran."""
+    last = -1
+    for ran in runs:
+        hit = n - 1 if ran is None else (
+            int(np.flatnonzero(ran)[-1]) if ran.any() else -1)
+        last = max(last, hit)
+    return last if last >= 0 else None
+
+
+def _value_at(value, row: int):
+    """A per-row value (a column, or a row's columns) at one row, as the
+    Python values the exact interpreter holds; any other value is the
+    same on every row."""
+    if isinstance(value, dict):
+        cols = {c: np.asarray(v) for c, v in value.items()}
+        if all(v.ndim == 1 for v in cols.values()):
+            return {c: v[row].item() for c, v in cols.items()}
+        return value
+    return np.asarray(value)[row].item()
 
 
 def _vec_nav(env, ce: _ColEnv, target: str, e: INav, n: int) -> None:
@@ -430,6 +496,31 @@ def _vec_accumulate(ce: _ColEnv, stmt: Assign, e: IBin, mask, state) -> None:
     else:
         run = np.maximum(a0, np.maximum.accumulate(delta))
     ce.cols[acc] = run
+
+
+def _vec_keyed_accumulate(ce: _ColEnv, stmt: MapPut, mask, state) -> None:
+    """``m[k] = m.get(k, d) + delta`` over the rows under ``mask``: a sum by
+    key, each key's deltas added in row order onto its value in the map
+    (``d`` for a new key), new keys in order of first occurrence — the
+    values and the order the row-at-a-time loop leaves."""
+    key_e, default, delta_e = keyed_accumulation(stmt)
+    keys = _broadcast(_eval_vec(key_e, ce), ce.n)
+    deltas = _broadcast(_eval_vec(delta_e, ce), ce.n)
+    if mask is not None:
+        keys, deltas = keys[mask], deltas[mask]
+    m = state.setdefault(stmt.target, {})
+    if not len(keys):
+        return
+    uniq, first, inv = np.unique(keys, return_index=True, return_inverse=True)
+    uniq = uniq.tolist()
+    start = [m.get(k, default) for k in uniq]
+    floats = deltas.dtype.kind == "f" or any(isinstance(v, float)
+                                             for v in start)
+    dtype = np.float64 if floats else np.int64
+    sums = np.asarray(start, dtype)
+    np.add.at(sums, inv.reshape(-1), deltas.astype(dtype))
+    for j in np.argsort(first, kind="stable").tolist():
+        m[uniq[j]] = sums[j].item()
 
 
 def _vec_update(env, ce: _ColEnv, stmt: UpdateRow, mask, n: int) -> None:

@@ -64,7 +64,8 @@ def _walk_exprs(e, out: List) -> None:
     if not isinstance(e, IExpr):
         return
     out.append(e)
-    for attr in ("base", "left", "right", "keyexpr", "valexpr"):
+    for attr in ("base", "left", "right", "keyexpr", "valexpr", "source",
+                 "pred", "default"):
         sub = getattr(e, attr, None)
         if isinstance(sub, IExpr):
             _walk_exprs(sub, out)

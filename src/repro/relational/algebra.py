@@ -15,20 +15,33 @@ combinators, ``Func``) evaluate column-vectorized over a table.
 from __future__ import annotations
 
 import dataclasses
+import functools
+from collections import OrderedDict
 from typing import Callable, Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.metrics import MetricsRegistry
 from ..obs.transfer import to_device, to_host
+from .memo import TableMemo
 from .table import Field, Schema, Table
 
 __all__ = [
     "Scalar", "Col", "Lit", "Arith", "Cmp", "BoolOp", "Not", "Func", "Param",
-    "Query", "Scan", "Select", "Project", "Join", "Aggregate", "OrderBy", "Limit",
-    "AggSpec", "equi_join_indices", "register_scalar_func", "scan_tables",
+    "Query", "Scan", "Select", "Project", "Join", "SemiJoin", "Aggregate",
+    "OrderBy", "Limit", "AggSpec", "equi_join_indices", "register_scalar_func",
+    "scan_tables", "query_has_params", "SERVER",
 ]
+
+# Process-wide counters of existential checks and of the semi-join:
+# ``exists_set`` (checks a semi-join answers set-at-a-time: its probe rows),
+# ``exists_per_row`` (checks the interpreter answers with a query of their
+# own, ``core/regions.py``), ``semijoin_probe_rows`` and
+# ``semijoin_build_rows`` (the key rows each semi-join reads).
+# ``ServingRuntime.metrics_snapshot()`` surfaces them as ``server_*``.
+SERVER = MetricsRegistry()
 
 # --------------------------------------------------------------------------
 # Scalar expressions
@@ -345,9 +358,10 @@ class Select(Query):
     def execute(self, db, params=None):
         t = self.child.execute(db, params)
         if t.nrows == 0:
-            return t
+            return record_rows(db, self, t)
         mask = self.pred.eval(t, params)
-        return t.filter_mask(to_host(mask, "algebra.select"))
+        return record_rows(
+            db, self, t.filter_mask(to_host(mask, "algebra.select")))
 
     def key(self):
         return ("select", self.pred.key(), self.child.key())
@@ -419,7 +433,9 @@ class Join(Query):
         rsel = rsel.rename(ren)
         cols = dict(lsel.columns)
         cols.update(rsel.columns)
-        return Table(f"{lt.name}_join_{rt.name}", lsel.schema.concat(rsel.schema), cols)
+        return record_rows(db, self, Table(f"{lt.name}_join_{rt.name}",
+                                           lsel.schema.concat(rsel.schema),
+                                           cols))
 
     def key(self):
         return ("join", self.left_key, self.right_key, self.left.key(), self.right.key())
@@ -442,12 +458,344 @@ class Join(Query):
         return ls.concat(Schema(tuple(rf)))
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class SemiJoin(Query):
+    """Left semi-join: the rows of ``left`` whose ``left_key`` equals the
+    ``right_key`` of some row of ``right`` (SQL ``EXISTS``/``IN``), each
+    once, in ``left``'s order, with ``left``'s columns.
+
+    Runs on the device over the key columns and their row masks
+    (:func:`masked`); neither key column reaches the host. Integer keys
+    with a small enough range probe a direct-address table of the right
+    side, one jitted program a call (the table itself is built once per
+    data epoch where the right side has no parameters); other keys take
+    one jitted sort and search."""
+
+    left: Query
+    right: Query
+    left_key: str
+    right_key: str
+
+    def execute(self, db, params=None):
+        base, mask = self.execute_masked(db, params)
+        return record_rows(db, self,
+                           base.filter_mask(to_host(mask, "algebra.semijoin")))
+
+    def execute_masked(self, db, params=None) -> Tuple[Table, jnp.ndarray]:
+        """The left base table and the mask of its rows that are in the
+        semi-join (the span ``server.semijoin``)."""
+        lt, lmask = masked(self.left, db, params)
+        lkeys = lt.column(self.left_key)
+        if lmask is None:
+            lmask = jnp.ones(lkeys.shape, jnp.bool_)
+        SERVER.inc("semijoin_probe_rows", lt.nrows)
+        SERVER.inc("exists_set", lt.nrows)
+        with db.tracer.span("server.semijoin", probe_rows=lt.nrows):
+            present = self._built(db, params) \
+                if jnp.issubdtype(lkeys.dtype, jnp.integer) else None
+            if present is not None:
+                out, n = _semijoin_probe(lkeys, lmask, present,
+                                         ordered=_KEY_FACTS(lkeys)[1])
+            else:
+                rt, rmask = self._right(db, params)
+                out, n = _semijoin_sorted(lkeys, lmask,
+                                          rt.column(self.right_key), rmask)
+            out.block_until_ready()
+        record_rows(db, self, n)
+        return lt, out
+
+    def _right(self, db, params) -> Tuple[Table, jnp.ndarray]:
+        rt, rmask = masked(self.right, db, params)
+        if rmask is None:
+            rmask = jnp.ones((rt.nrows,), jnp.bool_)
+        SERVER.inc("semijoin_build_rows", rt.nrows)
+        return rt, rmask
+
+    def _built(self, db, params) -> Optional[jnp.ndarray]:
+        """The right side's direct-address table, or None where its keys
+        do not fit one. A right side without parameters is built once per
+        data epoch of its tables and kept."""
+        key = None
+        if not query_has_params(self.right):
+            key = (db.instance_token, self.right.key(), self.right_key,
+                   db.site_epoch(scan_tables(self.right)))
+            hit = _BUILDS.get(key)
+            if hit is not None:
+                _BUILDS.move_to_end(key)
+                present, rows = hit
+                # the right side's rows as its build found them, for the
+                # server's time model
+                for node, n in zip(_preorder(self.right), rows):
+                    if n is not None:
+                        record_rows(db, node, n)
+                return present
+        rt, rmask = self._right(db, params)
+        rkeys = rt.column(self.right_key)
+        space, ordered = _KEY_FACTS(rkeys) if rt.nrows else (1, False)
+        if space is None or space > _MAX_SEMIJOIN_SPACE:
+            return None
+        present = _semijoin_build(rkeys, rmask, space=space, ordered=ordered)
+        rec = getattr(db, "_run_rows", None)
+        if key is not None and rec is not None:
+            _BUILDS[key] = (present, tuple(resolve_rows(
+                [rec.get(id(node)) for node in _preorder(self.right)])))
+            while len(_BUILDS) > _BUILDS_CAP:
+                _BUILDS.popitem(last=False)
+        return present
+
+    def key(self):
+        return ("semijoin", self.left_key, self.right_key, self.left.key(),
+                self.right.key())
+
+    def children(self):
+        return (self.left, self.right)
+
+    def sql(self):
+        return (f"SELECT * FROM ({self.left.sql()}) l WHERE EXISTS "
+                f"(SELECT 1 FROM ({self.right.sql()}) r "
+                f"WHERE r.{self.right_key} = l.{self.left_key})")
+
+    def output_schema(self, db):
+        return self.left.output_schema(db)
+
+
+# --------------------------------------------------------------------------
+# Masked evaluation: a σ chain or a semi-join as a row mask over its base
+# --------------------------------------------------------------------------
+
+def record_rows(db, node: Query, rows):
+    """Note the rows ``node`` produced in this query's run (a count, a row
+    mask, or the result table itself, which is returned), for the server's
+    time model (``DatabaseServer._true_times``), which then does not run
+    the node again. A server without that record ignores it."""
+    rec = getattr(db, "_run_rows", None)
+    if rec is not None:
+        rec[id(node)] = rows.nrows if isinstance(rows, Table) else rows
+    return rows
+
+
+def resolve_rows(values) -> list:
+    """Row counts of noted values (see :func:`record_rows`; None stays
+    None): counts as they are, masks and device counts read in one pull."""
+    out = list(values)
+    pending = [i for i, v in enumerate(out)
+               if v is not None and not isinstance(v, int)]
+    if pending:
+        counts = to_host(jnp.stack([jnp.sum(out[i], dtype=jnp.int32)
+                                    for i in pending]), "algebra.rows")
+        for i, c in zip(pending, counts.tolist()):
+            out[i] = c
+    return out
+
+
+def _preorder(q: Query):
+    yield q
+    for c in q.children():
+        yield from _preorder(c)
+
+
+def masked(q: Query, db, params=None) -> Tuple[Table, Optional[jnp.ndarray]]:
+    """``q``'s rows as a base table and a row mask over it (None: every
+    row). A chain of σ over a scan, and a semi-join over such chains, are
+    evaluated on the device over their base table's full columns, so no
+    shape depends on the data: nothing compacts, and nothing compiles per
+    result size. Any other node is executed and its result taken whole."""
+    if isinstance(q, Scan):
+        return db.table(q.table), None
+    if isinstance(q, Select):
+        base, mask = masked(q.child, db, params)
+        if base.nrows == 0:
+            return base, mask
+        mask = _pred_mask(q.pred, base, params, mask)
+        record_rows(db, q, mask)
+        return base, mask
+    if isinstance(q, SemiJoin):
+        return q.execute_masked(db, params)
+    return q.execute(db, params), None
+
+
+class _Columns:
+    """The columns a predicate reads, as it reads a table's, inside a
+    jitted program."""
+
+    def __init__(self, columns: Dict[str, jnp.ndarray], nrows: int):
+        self.columns = columns
+        self.nrows = nrows
+
+    def column(self, name: str):
+        return self.columns[name]
+
+
+def _param_names(s: Scalar):
+    if isinstance(s, Param):
+        yield s.name
+    for k in _embedded_scalars(s):
+        yield from _param_names(k)
+
+
+# one jitted program per predicate (by its structure), or None where the
+# predicate does not trace (a registered function of host Python)
+_PRED_PROGRAMS: "OrderedDict[tuple, Optional[Callable]]" = OrderedDict()
+_PRED_PROGRAMS_CAP = 256
+
+
+def _pred_mask(pred: Scalar, base: Table, params, mask):
+    """``pred`` over every row of ``base``, and-ed into ``mask``: one
+    jitted program of the base's shape, its parameters passed as values
+    (a new binding compiles nothing)."""
+    key = pred.key()
+    fn = _PRED_PROGRAMS.get(key, False)
+    if fn is False:
+        def run(columns, values, mask, nrows):
+            m = pred.eval(_Columns(columns, nrows), values)
+            return m if mask is None else jnp.logical_and(mask, m)
+        fn = _PRED_PROGRAMS[key] = jax.jit(run, static_argnums=(3,))
+        while len(_PRED_PROGRAMS) > _PRED_PROGRAMS_CAP:
+            _PRED_PROGRAMS.popitem(last=False)
+    if fn is not None:
+        columns = {c: base.column(c) for c in set(pred.columns())}
+        values = {}
+        for n in set(_param_names(pred)):
+            if params is None or n not in params:
+                raise KeyError(f"unbound query parameter {n!r}")
+            values[n] = params[n]
+        try:
+            return fn(columns, values, mask, base.nrows)
+        except Exception:   # not traceable: evaluate op by op from now on
+            _PRED_PROGRAMS[key] = None
+    m = pred.eval(base, params)
+    return m if mask is None else jnp.logical_and(mask, m)
+
+
+def _key_facts(col) -> Tuple[Optional[int], bool]:
+    """Of an integer key column: the slots of a direct-address table over
+    it (its largest key + 1; None where a key is negative), and whether
+    its keys are in ascending order. One pull of three scalars a column,
+    memoized by its array; (None, False) for other keys."""
+    if not jnp.issubdtype(col.dtype, jnp.integer) or col.shape[0] == 0:
+        return None, False
+    lo, hi, ordered = to_host(jnp.stack([
+        jnp.min(col), jnp.max(col),
+        jnp.all(col[1:] >= col[:-1]).astype(col.dtype)]),
+        "algebra.semijoin").tolist()
+    return (None if lo < 0 else int(hi) + 1), bool(ordered)
+
+
+_KEY_FACTS = TableMemo(_key_facts, 64)
+
+# largest direct-address table the semi-join builds: 2**28 one-byte slots
+_MAX_SEMIJOIN_SPACE = 1 << 28
+# the build sides kept: a build side with no parameters is the same for
+# every execution over the same data, so its table is built once per
+# data epoch
+_BUILDS_CAP = 16
+_BUILDS: "OrderedDict[tuple, jnp.ndarray]" = OrderedDict()
+
+
+@functools.partial(jax.jit, static_argnames=("space", "ordered"))
+def _semijoin_build(rkeys, rmask, space, ordered):
+    """The build side as a direct-address table of ``space`` slots: slot
+    ``k`` is set where some row under ``rmask`` has key ``k``. Keys in
+    ascending order take the TPU's sorted scatter, which compiles in
+    seconds where the general one takes ~25 s for 6M keys."""
+    if ordered:
+        return jnp.zeros(space, jnp.bool_).at[rkeys].max(
+            rmask, mode="drop", indices_are_sorted=True)
+    slot = jnp.where(rmask, rkeys, space)
+    return jnp.zeros(space, jnp.bool_).at[slot].set(True, mode="drop")
+
+
+@functools.partial(jax.jit, static_argnames=("ordered",))
+def _semijoin_probe(lkeys, lmask, present, ordered):
+    """Each probe row under ``lmask`` whose key has its slot set."""
+    space = present.shape[0]
+    hit = jnp.take(present, jnp.clip(lkeys, 0, space - 1),
+                   indices_are_sorted=ordered) & (lkeys >= 0) \
+        & (lkeys < space)
+    out = lmask & hit
+    return out, jnp.sum(out, dtype=jnp.int32)
+
+
+@jax.jit
+def _semijoin_sorted(lkeys, lmask, rkeys, rmask):
+    """Semi-join as one program for any key type: the build keys sorted,
+    each key's rows in the build mask first, then each probe key searched
+    for its first row."""
+    keys, dropped = jax.lax.sort(
+        (rkeys, jnp.logical_not(rmask).astype(jnp.int8)), num_keys=2)
+    pos = jnp.clip(jnp.searchsorted(keys, lkeys), 0, keys.shape[0] - 1)
+    out = lmask & (keys[pos] == lkeys) & (dropped[pos] == 0)
+    return out, jnp.sum(out, dtype=jnp.int32)
+
+
+def _embedded_scalars(node):
+    """Every Scalar hanging off one dataclass node — covers predicates,
+    computed-projection pairs, and whatever scalar slots future operators
+    add, without naming fields."""
+    for f in dataclasses.fields(node):
+        v = getattr(node, f.name)
+        if isinstance(v, Scalar):
+            yield v
+        elif isinstance(v, tuple):
+            for item in v:
+                if isinstance(item, Scalar):
+                    yield item
+                elif isinstance(item, tuple):
+                    yield from (x for x in item if isinstance(x, Scalar))
+
+
+def query_has_params(q: "Query") -> bool:
+    """True iff a relational tree contains a ``Param`` anywhere (predicates
+    and computed projections included) — the sites whose bindings may differ
+    between batched invocations, so they never amortize."""
+    def scalar_has(s: Scalar) -> bool:
+        if isinstance(s, Param):
+            return True
+        return any(scalar_has(k) for k in _embedded_scalars(s))
+
+    if any(scalar_has(s) for s in _embedded_scalars(q)):
+        return True
+    return any(query_has_params(c) for c in q.children())
+
+
 _AGG_FUNCS = {
     "sum": lambda x: jnp.sum(x),
     "min": lambda x: jnp.min(x),
     "max": lambda x: jnp.max(x),
     "avg": lambda x: jnp.mean(x),
 }
+
+
+def _group_codes(col) -> Tuple[np.ndarray, jnp.ndarray]:
+    """A group-by column's distinct values (host) and each row's index
+    among them (device), once per column array."""
+    uniq, inv = np.unique(to_host(col, "algebra.aggregate"),
+                          return_inverse=True)
+    return uniq, to_device(inv.reshape(-1).astype(np.int32), None,
+                           "algebra.aggregate")
+
+
+_GROUP_CODES = TableMemo(_group_codes, 64)
+
+
+# up to this many groups, grouped sums compare each row with each group
+# instead of scattering (a scatter-add into few slots is slow on the TPU)
+_COMPARE_GROUPS = 64
+
+
+@functools.partial(jax.jit, static_argnames=("n_groups",))
+def _masked_group_aggs(mask, codes, values, n_groups):
+    if n_groups <= _COMPARE_GROUPS:
+        member = (codes[None, :] == jnp.arange(n_groups, dtype=codes.dtype)
+                  [:, None]) & mask[None, :]
+        counts = jnp.sum(member, axis=1, dtype=jnp.int32)
+        sums = tuple(jnp.sum(jnp.where(member, v[None, :], 0), axis=1,
+                             dtype=v.dtype) for v in values)
+        return counts, sums
+    counts = jax.ops.segment_sum(mask.astype(jnp.int32), codes, n_groups)
+    sums = tuple(jax.ops.segment_sum(jnp.where(mask, v, jnp.zeros_like(v)),
+                                     codes, n_groups) for v in values)
+    return counts, sums
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -459,10 +807,40 @@ class Aggregate(Query):
     child: Query
 
     def execute(self, db, params=None):
+        if isinstance(self.child, (Select, SemiJoin)) \
+                and len(self.group_by) == 1 \
+                and all(a.func in ("count", "sum") for a in self.aggs):
+            return record_rows(db, self, self._grouped_masked(
+                *masked(self.child, db, params)))
         t = self.child.execute(db, params)
         if not self.group_by:
-            return self._global(t)
-        return self._grouped(t)
+            return record_rows(db, self, self._global(t))
+        return record_rows(db, self, self._grouped(t))
+
+    def _grouped_masked(self, base: Table, mask) -> Table:
+        """Grouped count/sum over the rows of ``base`` under ``mask``, on
+        the device over the full columns: one program of the base's shape,
+        whatever the rows that pass. The groups are the base column's
+        distinct values that have rows, in order, as :meth:`_grouped`
+        gives them."""
+        (g,) = self.group_by
+        uniq, codes = _GROUP_CODES(base.column(g))
+        if mask is None:
+            mask = jnp.ones(codes.shape, jnp.bool_)
+        values = tuple(base.column(a.col) for a in self.aggs
+                       if a.func == "sum")
+        counts, sums = _masked_group_aggs(mask, codes, values,
+                                          n_groups=len(uniq))
+        counts = to_host(counts, "algebra.aggregate")
+        present = counts > 0
+        fields, cols = [base.schema.field(g)], {g: uniq[present]}
+        sums = iter(sums)
+        for a in self.aggs:
+            vals = counts if a.func == "count" else \
+                to_host(next(sums), "algebra.aggregate")
+            fields.append(Field(a.out, str(vals.dtype)))
+            cols[a.out] = vals[present]
+        return Table("agg", Schema(tuple(fields)), cols)
 
     def _global(self, t: Table) -> Table:
         fields, cols = [], {}

@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-from typing import Dict, Mapping, Optional, Tuple
+from typing import Callable, Dict, Mapping, Optional, Tuple
 
 import jax
 import numpy as np
@@ -37,7 +37,7 @@ from ..obs.metrics import MetricsRegistry
 from ..obs.trace import NOOP_TRACER
 from ..obs.transfer import to_device, to_host
 from .algebra import (Aggregate, Join, Limit, OrderBy, Project, Query, Scan,
-                      Select)
+                      Select, SemiJoin, resolve_rows)
 from .memo import TableMemo
 from .table import Table
 
@@ -149,6 +149,10 @@ class DatabaseServer:
         # per-column histogram builds since startup — the ANALYZE work
         # counter targeted re-analyzes are judged by (tests/bench)
         self.histogram_builds = 0
+        # the rows each node of the running query produced, noted by the
+        # operators (``algebra.record_rows``): a count, or a row mask or
+        # device count not yet read; None outside ``run``
+        self._run_rows: Optional[Dict[int, object]] = None
         self.analyze()
 
     def table(self, name: str) -> Table:
@@ -281,15 +285,32 @@ class DatabaseServer:
         with tracer.span("server.run") as sp:
             if tracer.enabled:
                 sp.attrs["sql"] = query.sql()
-            result = query.execute(self, params)
-            first, last = self._true_times(query, params)
+            self._run_rows = {}
+            try:
+                result = query.execute(self, params)
+                first, last = self._true_times(query, params)
+            finally:
+                self._run_rows = None
         return result, first, last
+
+    def _rows_of(self, params) -> Callable[[Query], int]:
+        """The true row count of a node of the running query: the one its
+        operator noted (device counts and masks read in one pull), else
+        from running the node again."""
+        rec = self._run_rows or {}
+        rec.update(zip(rec, resolve_rows(rec.values())))
+
+        def rows(node: Query) -> int:
+            n = rec.get(id(node))
+            return n if n is not None else node.execute(self, params).nrows
+        return rows
 
     def _true_times(self, q: Query, params) -> Tuple[float, float]:
         """Server time model evaluated on TRUE cardinalities (post-execution)."""
         m = self.model
         total = m.startup_s
         blocking = m.startup_s
+        rows = self._rows_of(params)
 
         def walk(node: Query) -> int:
             nonlocal total, blocking
@@ -298,9 +319,8 @@ class DatabaseServer:
                 total += n / m.scan_rows_per_s
                 return n
             if isinstance(node, Select):
-                n_in = walk(node.child)
-                out = node.execute(self, params).nrows
-                return out
+                walk(node.child)
+                return rows(node)
             if isinstance(node, Project):
                 return walk(node.child)
             if isinstance(node, Join):
@@ -310,12 +330,19 @@ class DatabaseServer:
                 probe = max(nl, nr)
                 total += build / m.hash_build_rows_per_s + probe / m.hash_probe_rows_per_s
                 blocking += build / m.hash_build_rows_per_s
-                return node.execute(self, params).nrows
+                return rows(node)
+            if isinstance(node, SemiJoin):
+                # the right side is the hash table built, the left probes it
+                probe = walk(node.left)
+                build = walk(node.right)
+                total += build / m.hash_build_rows_per_s + probe / m.hash_probe_rows_per_s
+                blocking += build / m.hash_build_rows_per_s
+                return rows(node)
             if isinstance(node, Aggregate):
                 n_in = walk(node.child)
                 total += n_in / m.agg_rows_per_s
                 blocking = total  # aggregation is blocking
-                return node.execute(self, params).nrows
+                return rows(node)
             if isinstance(node, OrderBy):
                 n_in = walk(node.child)
                 total += n_in / m.sort_rows_per_s
@@ -368,6 +395,17 @@ class DatabaseServer:
                 total += build / m.hash_build_rows_per_s + probe / m.hash_probe_rows_per_s
                 blocking += build / m.hash_build_rows_per_s
                 return max(1.0, out), rbl + rbr
+            if isinstance(node, SemiJoin):
+                # a left row survives when its key is among the right's
+                # distinct keys: their share of the left key's distinct
+                # values, under containment
+                nl, rbl = est_rows(node.left)
+                nr, _ = est_rows(node.right)
+                ndv_l = self._ndv_of(node.left, node.left_key)
+                ndv_r = min(self._ndv_of(node.right, node.right_key), nr)
+                total += nr / m.hash_build_rows_per_s + nl / m.hash_probe_rows_per_s
+                blocking += nr / m.hash_build_rows_per_s
+                return max(1.0, nl * min(1.0, ndv_r / max(ndv_l, 1.0))), rbl
             if isinstance(node, Aggregate):
                 n, rb = est_rows(node.child)
                 total += n / m.agg_rows_per_s
@@ -409,7 +447,7 @@ class DatabaseServer:
         if isinstance(node, Scan):
             st = self._stats.get(node.table)
             return st.hist(col) if st is not None else None
-        if isinstance(node, (Select, Project, OrderBy, Limit)):
+        if isinstance(node, (Select, Project, OrderBy, Limit, SemiJoin)):
             kids = node.children()
             return self._hist_of(kids[0], col) if kids else None
         return None
@@ -417,7 +455,8 @@ class DatabaseServer:
     def _ndv_of(self, node: Query, col: str) -> float:
         if isinstance(node, Scan):
             return float(self.stats(node.table).ndv(col))
-        if isinstance(node, (Select, Project, OrderBy, Limit, Aggregate)):
+        if isinstance(node, (Select, Project, OrderBy, Limit, Aggregate,
+                             SemiJoin)):
             kids = node.children()
             return self._ndv_of(kids[0], col) if kids else 100.0
         if isinstance(node, Join):

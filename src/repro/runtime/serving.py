@@ -56,6 +56,7 @@ from ..core.regions import Program
 from ..obs.metrics import MetricsRegistry, merge_snapshots, registry_counter
 from ..obs.trace import NOOP_TRACER
 from ..obs.transfer import TRANSFERS
+from ..relational.algebra import SERVER
 from ..relational.database import CLIENT
 from .feedback import FeedbackController
 from .sitecache import SiteCache
@@ -319,8 +320,8 @@ class ServingRuntime:
 
     def metrics_snapshot(self) -> Dict[str, object]:
         """One flat snapshot across every component registry (serving,
-        session, feedback, the process's host↔device transfer and
-        prefetch-cache index counters)
+        session, feedback, the process's host↔device transfer,
+        prefetch-cache index, existential-check and semi-join counters)
         plus the site-cache / compiler stats dicts ingested as gauges —
         diff two snapshots to see a serve cycle."""
         self.metrics.ingest(self.site_cache.stats(), prefix="site_cache_")
@@ -333,6 +334,7 @@ class ServingRuntime:
             parts["feedback"] = self.feedback.metrics.snapshot()
         parts["transfer"] = TRANSFERS.snapshot()
         parts["client"] = CLIENT.snapshot()
+        parts["server"] = SERVER.snapshot()
         return merge_snapshots(**parts)
 
     # ------------------------------------------------------------- telemetry

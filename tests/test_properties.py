@@ -20,8 +20,8 @@ from repro.core import CostCatalog, Interpreter, optimize
 from repro.core.fir import eval_fir, loop_to_fir
 from repro.core.regions import (Assign, CollectionAdd, CondRegion, IBin,
                                 IConst, IEmptyList, IEmptyMap, IField,
-                                ILoadAll, IVar, LoopRegion, MapPut, Program,
-                                seq)
+                                ILoadAll, IMapGet, IVar, LoopRegion, MapPut,
+                                Program, seq)
 from repro.relational import (DatabaseServer, Field, Schema, Table,
                               equi_join_indices)
 from repro.relational.database import ClientEnv, FAST_LOCAL, SLOW_REMOTE
@@ -82,6 +82,48 @@ def accumulating_loop(draw):
     return Program("rand", seq(*stmts, loop), tuple(outputs))
 
 
+@st.composite
+def flag_loop(draw):
+    """A cursor loop that sets a flag (and maybe a plain temporary) in its
+    body, both read after the loop: the flag under a guard, to a constant
+    or to a value of the row, with or without a running sum and a keyed
+    accumulation beside it."""
+    guard = IBin("<", IField(IVar("t"), "i_w"), IConst(draw(st.integers(0, 100))))
+    value = IField(IVar("t"), "i_k") if draw(st.booleans()) else IConst(1)
+    body = [CondRegion(guard, seq(Assign("flag", value)))]
+    stmts = [Assign("flag", IConst(-1))]
+    outputs = ["flag"]
+    if draw(st.booleans()):
+        body.insert(draw(st.integers(0, 1)),
+                    Assign("last", IField(IVar("t"), "i_w")))
+        stmts.append(Assign("last", IConst(-1)))
+        outputs.append("last")
+    if draw(st.booleans()):
+        body.append(Assign("s", IBin("+", IVar("s"), IField(IVar("t"), "i_v"))))
+        stmts.append(Assign("s", IConst(0.0)))
+        outputs.append("s")
+    if draw(st.booleans()):
+        # keyed accumulation m[k] = m.get(k, 0) + delta, maybe under an
+        # `if` of its own after the flag's
+        key = IField(IVar("t"), "i_k")
+        delta = draw(st.sampled_from([IConst(1), IField(IVar("t"), "i_w"),
+                                      IField(IVar("t"), "i_v")]))
+        put = MapPut("m", key, IBin("+", IMapGet(IVar("m"), key, IConst(0)),
+                                    delta))
+        if draw(st.booleans()):
+            put = CondRegion(IBin(">", IField(IVar("t"), "i_w"),
+                                  IConst(draw(st.integers(0, 100)))), seq(put))
+        body.append(put)
+        stmts.append(Assign("m", IEmptyMap()))
+        outputs.append("m")
+    loop = LoopRegion("t", ILoadAll("items"), seq(*body))
+    # read after the loop: the flag decides what is appended
+    after = CondRegion(IBin("==", IVar("flag"), IConst(-1)),
+                       seq(CollectionAdd("seen", IConst(0))))
+    return Program("flag", seq(*stmts, Assign("seen", IEmptyList()), loop,
+                               after), tuple(outputs) + ("seen",))
+
+
 def _state_close(a, b):
     for k in a:
         va, vb = a[k], b[k]
@@ -111,6 +153,30 @@ def test_fast_interpreter_equals_exact(db, prog):
     _state_close(o1, o2)
     assert abs(env1.clock - env2.clock) < 1e-9 + 1e-6 * env1.clock
     assert env1.n_queries == env2.n_queries
+
+
+@pytest.mark.parametrize("tier", ["fast", "compiled"])
+@settings(max_examples=40, deadline=None)
+@given(db=small_db(), prog=flag_loop())
+def test_variable_set_in_loop_body_equals_exact(tier, db, prog):
+    """A variable assigned in a columnar loop body ends with the value of
+    its last executed assignment, and a keyed accumulation with the exact
+    interpreter's map, on the fast and the compiled tier."""
+    from repro.compiled.exec import SplicingInterpreter
+    from repro.compiled.lower import lower_program
+    env1 = ClientEnv(db, SLOW_REMOTE)
+    o1 = Interpreter(env1, "exact").run(prog)
+    env2 = ClientEnv(db, SLOW_REMOTE)
+    if tier == "fast":
+        o2 = Interpreter(env2, "fast").run(prog)
+    else:
+        lowered = lower_program(prog)
+        assert lowered.n_columnar == 1
+        o2 = SplicingInterpreter(env2, lowered).run(lowered.program)
+    assert o1["flag"] == o2["flag"] and o1["seen"] == o2["seen"]
+    assert o1.get("m") == o2.get("m")          # sums in row order: exact
+    _state_close(o1, o2)
+    assert abs(env1.clock - env2.clock) < 1e-9 + 1e-6 * env1.clock
 
 
 @settings(max_examples=40, deadline=None)
