@@ -39,7 +39,10 @@ __all__ = [
 # ``exists_set`` (checks a semi-join answers set-at-a-time: its probe rows),
 # ``exists_per_row`` (checks the interpreter answers with a query of their
 # own, ``core/regions.py``), ``semijoin_probe_rows`` and
-# ``semijoin_build_rows`` (the key rows each semi-join reads).
+# ``semijoin_build_rows`` (the key rows each semi-join program is handed),
+# ``range_index_selects`` (masked σ evaluations over the rows a range index
+# selected, :func:`masked`) and ``range_full_selects`` (masked σ
+# evaluations over full columns).
 # ``ServingRuntime.metrics_snapshot()`` surfaces them as ``server_*``.
 SERVER = MetricsRegistry()
 
@@ -464,12 +467,13 @@ class SemiJoin(Query):
     ``right_key`` of some row of ``right`` (SQL ``EXISTS``/``IN``), each
     once, in ``left``'s order, with ``left``'s columns.
 
-    Runs on the device over the key columns and their row masks
-    (:func:`masked`); neither key column reaches the host. Integer keys
-    with a small enough range probe a direct-address table of the right
-    side, one jitted program a call (the table itself is built once per
-    data epoch where the right side has no parameters); other keys take
-    one jitted sort and search."""
+    Runs on the device over the key columns of each side's selection and
+    row mask (:func:`masked`: the base's rows, or those a range index
+    picked); neither key column reaches the host. Integer keys with a
+    small enough range probe a direct-address table of the right side,
+    one jitted program a call (the table itself is built once per data
+    epoch where the right side has no parameters); other keys take one
+    jitted sort and search."""
 
     left: Query
     right: Query
@@ -477,39 +481,49 @@ class SemiJoin(Query):
     right_key: str
 
     def execute(self, db, params=None):
-        base, mask = self.execute_masked(db, params)
+        base, sel, mask = self.execute_masked(db, params)
         return record_rows(db, self,
-                           base.filter_mask(to_host(mask, "algebra.semijoin")))
+                           base.take(selected_rows(base, sel, mask)))
 
-    def execute_masked(self, db, params=None) -> Tuple[Table, jnp.ndarray]:
-        """The left base table and the mask of its rows that are in the
-        semi-join (the span ``server.semijoin``)."""
-        lt, lmask = masked(self.left, db, params)
+    def execute_masked(self, db, params=None) -> Tuple[
+            Table, Optional["RangeSelection"], jnp.ndarray]:
+        """The left base table, the selection of its rows the probe is
+        handed (None: every row) and the mask of those in the semi-join
+        (the span ``server.semijoin``)."""
+        lt, sel, lmask = masked(self.left, db, params)
         lkeys = lt.column(self.left_key)
+        n_probe = lt.nrows if sel is None else sel.width
         if lmask is None:
-            lmask = jnp.ones(lkeys.shape, jnp.bool_)
-        SERVER.inc("semijoin_probe_rows", lt.nrows)
-        SERVER.inc("exists_set", lt.nrows)
-        with db.tracer.span("server.semijoin", probe_rows=lt.nrows):
+            lmask = jnp.ones((n_probe,), jnp.bool_)
+        SERVER.inc("semijoin_probe_rows", n_probe)
+        SERVER.inc("exists_set", n_probe)
+        with db.tracer.span("server.semijoin", probe_rows=n_probe):
             present = self._built(db, params) \
                 if jnp.issubdtype(lkeys.dtype, jnp.integer) else None
-            if present is not None:
-                out, n = _semijoin_probe(lkeys, lmask, present,
+            if present is None:
+                rt, rsel, rmask = self._right(db, params)
+                out, n = _semijoin_sorted(
+                    _selected(lkeys, sel), lmask,
+                    _selected(rt.column(self.right_key), rsel), rmask)
+            elif sel is None:
+                out, n = _semijoin_probe(lkeys, None, lmask, present,
                                          ordered=_KEY_FACTS(lkeys)[1])
             else:
-                rt, rmask = self._right(db, params)
-                out, n = _semijoin_sorted(lkeys, lmask,
-                                          rt.column(self.right_key), rmask)
+                out, n = _semijoin_probe(sel.index.clustered(lkeys),
+                                         sel.start, lmask, present,
+                                         ordered=False)
             out.block_until_ready()
         record_rows(db, self, n)
-        return lt, out
+        return lt, sel, out
 
-    def _right(self, db, params) -> Tuple[Table, jnp.ndarray]:
-        rt, rmask = masked(self.right, db, params)
+    def _right(self, db, params) -> Tuple[
+            Table, Optional["RangeSelection"], jnp.ndarray]:
+        rt, sel, rmask = masked(self.right, db, params)
+        n_build = rt.nrows if sel is None else sel.width
         if rmask is None:
-            rmask = jnp.ones((rt.nrows,), jnp.bool_)
-        SERVER.inc("semijoin_build_rows", rt.nrows)
-        return rt, rmask
+            rmask = jnp.ones((n_build,), jnp.bool_)
+        SERVER.inc("semijoin_build_rows", n_build)
+        return rt, sel, rmask
 
     def _built(self, db, params) -> Optional[jnp.ndarray]:
         """The right side's direct-address table, or None where its keys
@@ -529,11 +543,13 @@ class SemiJoin(Query):
                     if n is not None:
                         record_rows(db, node, n)
                 return present
-        rt, rmask = self._right(db, params)
+        rt, sel, rmask = self._right(db, params)
         rkeys = rt.column(self.right_key)
         space, ordered = _KEY_FACTS(rkeys) if rt.nrows else (1, False)
         if space is None or space > _MAX_SEMIJOIN_SPACE:
             return None
+        if sel is not None:
+            rkeys, ordered = sel.take(rkeys), False
         present = _semijoin_build(rkeys, rmask, space=space, ordered=ordered)
         rec = getattr(db, "_run_rows", None)
         if key is not None and rec is not None:
@@ -594,24 +610,59 @@ def _preorder(q: Query):
         yield from _preorder(c)
 
 
-def masked(q: Query, db, params=None) -> Tuple[Table, Optional[jnp.ndarray]]:
-    """``q``'s rows as a base table and a row mask over it (None: every
-    row). A chain of σ over a scan, and a semi-join over such chains, are
-    evaluated on the device over their base table's full columns, so no
-    shape depends on the data: nothing compacts, and nothing compiles per
-    result size. Any other node is executed and its result taken whole."""
+def masked(q: Query, db, params=None) -> Tuple[
+        Table, Optional["RangeSelection"], Optional[jnp.ndarray]]:
+    """``q``'s rows as a base table, a selection of its rows (a
+    :class:`RangeSelection`; None: every row) and a mask over that
+    selection (None: all of it). A chain of σ over a scan, and a
+    semi-join over such chains, are evaluated on the device; nothing
+    compacts. A σ whose conjunction bounds one integer column of the base
+    from both sides, by literals or parameters, and keeps at most an
+    eighth of its rows, selects them through the column's sorted index
+    (:func:`_range_selection`): the selection is padded to a power of
+    two, so its shapes come in a few buckets a base, and the whole
+    predicate is then evaluated on the selected rows. Any other σ runs
+    over the base's full columns, one shape a base. Any other node is
+    executed and its result taken whole."""
     if isinstance(q, Scan):
-        return db.table(q.table), None
+        return db.table(q.table), None, None
     if isinstance(q, Select):
-        base, mask = masked(q.child, db, params)
+        base, sel, mask = masked(q.child, db, params)
         if base.nrows == 0:
-            return base, mask
-        mask = _pred_mask(q.pred, base, params, mask)
+            return base, sel, mask
+        if sel is None:
+            sel = _range_selection(q.pred, base, params)
+            if sel is not None and mask is not None:
+                mask = mask.at[sel.rows()].get(mode="promise_in_bounds")
+        mask = _pred_mask(q.pred, base, params, sel, mask)
+        SERVER.inc("range_full_selects" if sel is None
+                   else "range_index_selects")
         record_rows(db, q, mask)
-        return base, mask
+        return base, sel, mask
     if isinstance(q, SemiJoin):
         return q.execute_masked(db, params)
-    return q.execute(db, params), None
+    return q.execute(db, params), None, None
+
+
+def selected_rows(base: Table, sel, mask) -> np.ndarray:
+    """The host row ids of ``base`` that a selection and its mask keep
+    (see :func:`masked`), in the base's order: one pull."""
+    if sel is None:
+        return np.flatnonzero(to_host(mask, "algebra.semijoin"))
+    ids = to_host(jnp.where(mask, sel.rows(), -1), "algebra.semijoin")
+    return np.sort(ids[ids >= 0])
+
+
+def _window(col, start, width: int):
+    """``col[start:start + width]`` (``start`` a traced value inside a
+    jitted program), or ``col`` where ``start`` is None."""
+    return col if start is None else \
+        jax.lax.dynamic_slice_in_dim(col, start, width)
+
+
+def _selected(col, sel):
+    """``col`` at the rows of a selection (None: ``col``)."""
+    return col if sel is None else sel.take(col)
 
 
 class _Columns:
@@ -639,32 +690,182 @@ _PRED_PROGRAMS: "OrderedDict[tuple, Optional[Callable]]" = OrderedDict()
 _PRED_PROGRAMS_CAP = 256
 
 
-def _pred_mask(pred: Scalar, base: Table, params, mask):
-    """``pred`` over every row of ``base``, and-ed into ``mask``: one
-    jitted program of the base's shape, its parameters passed as values
-    (a new binding compiles nothing)."""
+def _pred_run(pred: Scalar, columns, values, mask, window, nrows):
+    """``pred`` over ``nrows`` rows of ``columns``, and-ed into ``mask``:
+    every row (``window`` None), or the window ``(start, lo, hi)`` of
+    columns in a range index's order, its rows ``[lo, hi)`` in the
+    range."""
+    if window is not None:
+        start, lo, hi = window
+        columns = {c: _window(v, start, nrows) for c, v in columns.items()}
+        i = jnp.arange(nrows, dtype=jnp.int32)
+        valid = (i >= lo) & (i < hi)
+        mask = valid if mask is None else jnp.logical_and(mask, valid)
+    m = pred.eval(_Columns(columns, nrows), values)
+    return m if mask is None else jnp.logical_and(mask, m)
+
+
+def _pred_mask(pred: Scalar, base: Table, params, sel, mask):
+    """``pred`` over the rows of ``base`` in the selection ``sel`` (None:
+    every row), and-ed into ``mask``: one jitted program a shape, its
+    parameters and the selection's offsets passed as values (a new
+    binding compiles nothing)."""
+    columns = {c: base.column(c) for c in set(pred.columns())}
+    window, nrows = None, base.nrows
+    if sel is not None:
+        columns = {c: sel.index.clustered(v) for c, v in columns.items()}
+        window, nrows = (sel.start, sel.lo, sel.hi), sel.width
+    values = {}
+    for n in set(_param_names(pred)):
+        if params is None or n not in params:
+            raise KeyError(f"unbound query parameter {n!r}")
+        values[n] = params[n]
     key = pred.key()
     fn = _PRED_PROGRAMS.get(key, False)
     if fn is False:
-        def run(columns, values, mask, nrows):
-            m = pred.eval(_Columns(columns, nrows), values)
-            return m if mask is None else jnp.logical_and(mask, m)
-        fn = _PRED_PROGRAMS[key] = jax.jit(run, static_argnums=(3,))
+        def run(columns, values, mask, window, nrows):
+            return _pred_run(pred, columns, values, mask, window, nrows)
+        fn = _PRED_PROGRAMS[key] = jax.jit(run, static_argnums=(4,))
         while len(_PRED_PROGRAMS) > _PRED_PROGRAMS_CAP:
             _PRED_PROGRAMS.popitem(last=False)
     if fn is not None:
-        columns = {c: base.column(c) for c in set(pred.columns())}
-        values = {}
-        for n in set(_param_names(pred)):
-            if params is None or n not in params:
-                raise KeyError(f"unbound query parameter {n!r}")
-            values[n] = params[n]
         try:
-            return fn(columns, values, mask, base.nrows)
+            return fn(columns, values, mask, window, nrows)
         except Exception:   # not traceable: evaluate op by op from now on
             _PRED_PROGRAMS[key] = None
-    m = pred.eval(base, params)
-    return m if mask is None else jnp.logical_and(mask, m)
+    return _pred_run(pred, columns, values, mask, window, nrows)
+
+
+# --------------------------------------------------------------------------
+# Range selection through a sorted index of one column
+# --------------------------------------------------------------------------
+
+_FLIPPED = {"<": ">", "<=": ">=", ">": "<", ">=": "<="}
+
+
+def _conjuncts(s: Scalar):
+    if isinstance(s, BoolOp) and s.op == "and":
+        yield from _conjuncts(s.left)
+        yield from _conjuncts(s.right)
+    else:
+        yield s
+
+
+def _integer_bound(s: Scalar, params) -> Optional[int]:
+    """The value of a literal or bound parameter that is an integer."""
+    if isinstance(s, Lit):
+        v = s.value
+    elif isinstance(s, Param) and params is not None and s.name in params:
+        v = params[s.name]
+    else:
+        return None
+    if isinstance(v, (bool, np.bool_)) or not isinstance(v, (int, np.integer)):
+        return None
+    return int(v)
+
+
+def _range_bounds(pred: Scalar, params) -> Dict[str, Tuple[int, int]]:
+    """The inclusive bounds ``[low, high]`` a conjunction sets on each
+    column it compares with integer literals or parameters from both
+    sides (``c >= a``, ``c > a``, ``c < b``, ``c <= b``, either side of
+    the comparison); the tightest where it sets several."""
+    low: Dict[str, int] = {}
+    high: Dict[str, int] = {}
+    for c in _conjuncts(pred):
+        if not isinstance(c, Cmp) or c.op not in _FLIPPED:
+            continue
+        column, bound, op = c.left, c.right, c.op
+        if not isinstance(column, Col):
+            column, bound, op = c.right, c.left, _FLIPPED[op]
+        v = _integer_bound(bound, params) if isinstance(column, Col) \
+            else None
+        if v is None:
+            continue
+        name = column.name
+        if op in (">", ">="):
+            v += op == ">"
+            low[name] = max(low.get(name, v), v)
+        else:
+            v -= op == "<"
+            high[name] = min(high.get(name, v), v)
+    return {n: (low[n], high[n]) for n in low if n in high}
+
+
+class _RangeIndex:
+    """A column's row ids in the order of their values (a stable argsort,
+    on the device) and the values in that order (host); and, made at a
+    column's first use, copies of its table's columns in that order
+    (device), so that a window of the index reads each column as one
+    contiguous slice and not as a gather, which costs a TPU v5e ~8 ns an
+    index whatever their number. Sorted and copied on the host (~0.2 s
+    for 1.5M keys), where nothing compiles: the TPU compiler takes ~30 s
+    over a device sort of as many."""
+
+    def __init__(self, col):
+        values = to_host(col, "algebra.range_index")
+        perm = np.argsort(values, kind="stable").astype(np.int32)
+        self.values = values[perm]
+        self.perm = to_device(perm, None, "algebra.range_index")
+        self.clustered = TableMemo(lambda c: to_device(
+            to_host(c, "algebra.range_index")[perm], None,
+            "algebra.range_index"), 16)
+
+
+# the index of a column, once per column array: a new data epoch is a new
+# array, and builds a new index
+_RANGE_INDEX = TableMemo(_RangeIndex, 16)
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class RangeSelection:
+    """The rows of a base table that a range over one of its columns
+    keeps, as a window of that column's :class:`_RangeIndex`: index
+    positions ``start`` to ``start + width``, of which ``[lo, hi)`` are in
+    the range. ``width`` is the least power of two that holds them."""
+
+    index: _RangeIndex
+    start: int
+    lo: int
+    hi: int
+    width: int
+
+    def take(self, col):
+        """``col`` (a column of the base) at the selected rows."""
+        return _window(self.index.clustered(col), self.start, self.width)
+
+    def rows(self):
+        """The selected rows' ids in the base (device)."""
+        return _window(self.index.perm, self.start, self.width)
+
+
+def _range_selection(pred: Scalar, base: Table, params):
+    """The :class:`RangeSelection` of the rows of ``base`` in a range
+    ``pred`` sets on an integer column from both sides, where they are at
+    most an eighth of the base's (the bounds are searched in the column's
+    sorted index on the host, so this is known before any device work);
+    of several such columns the one with the fewest rows. None
+    otherwise."""
+    best = None
+    for name, (low, high) in _range_bounds(pred, params).items():
+        if name not in base.columns:
+            continue
+        col = base.column(name)
+        if not jnp.issubdtype(col.dtype, jnp.integer):
+            continue
+        info = jnp.iinfo(col.dtype)
+        if low < info.min or high > info.max:
+            continue
+        index = _RANGE_INDEX(col)
+        lo = int(np.searchsorted(index.values, low, "left"))
+        n = max(int(np.searchsorted(index.values, high, "right")) - lo, 0)
+        if n <= base.nrows / 8 and (best is None or n < best[0]):
+            best = (n, index, lo)
+    if best is None:
+        return None
+    n, index, lo = best
+    width = 1 << max(n - 1, 0).bit_length()
+    start = min(lo, base.nrows - width)
+    return RangeSelection(index, start, lo - start, lo - start + n, width)
 
 
 def _key_facts(col) -> Tuple[Optional[int], bool]:
@@ -706,8 +907,10 @@ def _semijoin_build(rkeys, rmask, space, ordered):
 
 
 @functools.partial(jax.jit, static_argnames=("ordered",))
-def _semijoin_probe(lkeys, lmask, present, ordered):
-    """Each probe row under ``lmask`` whose key has its slot set."""
+def _semijoin_probe(lkeys, start, lmask, present, ordered):
+    """Each probe row under ``lmask`` whose key has its slot set: the
+    window of ``lkeys`` from ``start`` (None: every row)."""
+    lkeys = _window(lkeys, start, lmask.shape[0])
     space = present.shape[0]
     hit = jnp.take(present, jnp.clip(lkeys, 0, space - 1),
                    indices_are_sorted=ordered) & (lkeys >= 0) \
@@ -784,7 +987,11 @@ _COMPARE_GROUPS = 64
 
 
 @functools.partial(jax.jit, static_argnames=("n_groups",))
-def _masked_group_aggs(mask, codes, values, n_groups):
+def _masked_group_aggs(mask, codes, values, start, n_groups):
+    """Count and sums by group of the rows under ``mask``: the window of
+    the columns from ``start`` (None: every row)."""
+    codes = _window(codes, start, mask.shape[0])
+    values = tuple(_window(v, start, mask.shape[0]) for v in values)
     if n_groups <= _COMPARE_GROUPS:
         member = (codes[None, :] == jnp.arange(n_groups, dtype=codes.dtype)
                   [:, None]) & mask[None, :]
@@ -817,19 +1024,24 @@ class Aggregate(Query):
             return record_rows(db, self, self._global(t))
         return record_rows(db, self, self._grouped(t))
 
-    def _grouped_masked(self, base: Table, mask) -> Table:
-        """Grouped count/sum over the rows of ``base`` under ``mask``, on
-        the device over the full columns: one program of the base's shape,
-        whatever the rows that pass. The groups are the base column's
-        distinct values that have rows, in order, as :meth:`_grouped`
-        gives them."""
+    def _grouped_masked(self, base: Table, sel, mask) -> Table:
+        """Grouped count/sum over the rows of ``base`` in the selection
+        ``sel`` (None: every row) under ``mask`` (see :func:`masked`), on
+        the device: one program of the selection's shape, whatever the
+        rows that pass. The groups are the base column's distinct values
+        that have rows, in order, as :meth:`_grouped` gives them."""
         (g,) = self.group_by
         uniq, codes = _GROUP_CODES(base.column(g))
-        if mask is None:
-            mask = jnp.ones(codes.shape, jnp.bool_)
         values = tuple(base.column(a.col) for a in self.aggs
                        if a.func == "sum")
-        counts, sums = _masked_group_aggs(mask, codes, values,
+        start = None
+        if sel is not None:
+            clustered = sel.index.clustered
+            codes, start = clustered(codes), sel.start
+            values = tuple(clustered(v) for v in values)
+        if mask is None:
+            mask = jnp.ones(codes.shape, jnp.bool_)
+        counts, sums = _masked_group_aggs(mask, codes, values, start,
                                           n_groups=len(uniq))
         counts = to_host(counts, "algebra.aggregate")
         present = counts > 0
