@@ -21,7 +21,7 @@ lint:
 # (the drivers import and exercise the CobraSession/compile/run surface)
 bench-smoke:
 	PYTHONPATH=$(PYTHONPATH) $(PYTHON) -m benchmarks.run --smoke \
-		exp_crossover exp_wilos exp_opt_time bench_runtime bench_planner
+		exp_crossover exp_wilos exp_opt_time bench_runtime
 
 # full benchmark harness (all modules, paper-scale configurations)
 bench:
@@ -90,4 +90,3 @@ bench-compile:
 examples:
 	$(PYTHON) examples/quickstart.py
 	$(PYTHON) examples/serve_programs.py
-	$(PYTHON) examples/plan_distributed.py

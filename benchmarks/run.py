@@ -5,9 +5,6 @@
   exp_opt_time   Sec. VIII      (optimization time < 1 s + plan-cache hit)
   bench_runtime  serving runtime: batch-size/throughput crossover +
                  plan-store warm start (beyond-paper)
-  bench_kernels  kernel tile/roofline analysis + CPU reference timings
-  bench_roofline §Roofline table from dry-run artifacts
-  bench_planner  planner-vs-XLA validation (beyond-paper)
 
 Usage::
 
@@ -41,12 +38,9 @@ def main() -> None:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
     from repro.compile_cache import enable_compile_cache
     enable_compile_cache()
-    from . import (bench_kernels, bench_planner, bench_roofline,
-                   bench_runtime, exp_crossover, exp_opt_time, exp_wilos)
+    from . import bench_runtime, exp_crossover, exp_opt_time, exp_wilos
     mods = {"exp_crossover": exp_crossover, "exp_wilos": exp_wilos,
-            "exp_opt_time": exp_opt_time, "bench_runtime": bench_runtime,
-            "bench_kernels": bench_kernels,
-            "bench_roofline": bench_roofline, "bench_planner": bench_planner}
+            "exp_opt_time": exp_opt_time, "bench_runtime": bench_runtime}
     unknown = [a for a in args if a not in mods]
     if unknown:
         print(f"unknown module(s) {unknown}; available: {sorted(mods)}",

@@ -1,5 +1,7 @@
 """COBRA on TPU: cost-based rewriting of database applications (Emani &
-Sudarshan, 2018) as a production JAX framework.
+Sudarshan, 2018) — a database-application optimizer and the serving
+runtime built around it, over an in-process database whose columns are
+JAX arrays.
 
 The public surface is the session API::
 
@@ -17,8 +19,7 @@ or the ``session.trace()`` decorator. Compiled plans live in a cache keyed
 by (program fingerprint, cost catalog, optimizer config, per-table stats
 versions of the tables the program touches); ``db.analyze(table, ...)``
 bumps those versions, invalidating exactly the plans whose cost estimates
-went stale. The same session fronts the distributed TPU planner
-(``session.plan_step``) with a shared ``PlanReport`` result vocabulary.
+went stale.
 
 For production-shaped workloads, ``repro.runtime`` adds batched execution
 (``Executable.run_batch`` — one server round trip per query site per
@@ -34,11 +35,13 @@ every time; hold a ``CobraSession`` for compile-once/execute-many.
   repro.api         — CobraSession, OptimizerConfig, ProgramBuilder, PlanCache
   repro.runtime     — serving: run_batch, PlanStore, feedback re-optimization
   repro.core        — the paper: regions, F-IR, Region DAG, rules, search
-  repro.core.planner — the technique applied to distributed execution
+  repro.compiled    — the compiled execution tier (columnar loop lowering)
   repro.relational  — columnar JAX tables + simulated DB environment
-  repro.models      — the 10 assigned architectures
-  repro.kernels     — Pallas TPU kernels (+ jnp oracles)
-  repro.launch      — meshes, sharding, dry-run, train/serve drivers
+  repro.kernels     — Pallas TPU kernels for the relational operators
+                      (+ jnp oracles)
+  repro.cluster     — sharded serving over partitioned tables
+  repro.stats       — histograms, selectivity, q-error tracking
+  repro.obs         — tracer spans, metrics, explain and triage
 """
 
 __version__ = "1.2.0"

@@ -11,25 +11,21 @@ configuration, and a stats-versioned plan cache::
     db.analyze()                           # stats changed -> version bump
     exe3 = session.compile(make_p0())      # recompiled against fresh stats
 
-The same session also fronts the distributed TPU planner
-(``core.planner.plan``) through :meth:`CobraSession.plan_step`, so program
-rewriting and step-program sharding share one configuration/result
-vocabulary: both return a :class:`PlanReport` (domain ``"program"`` vs
-``"step"``) with the chosen alternative, its estimated cost, the number of
-alternatives considered, and memo statistics.
+Every compile reports a :class:`PlanReport`: the chosen plan, its
+estimated cost, the number of alternatives considered, and memo
+statistics.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import inspect
-import time
-from typing import Dict, Mapping, Optional, Sequence, Tuple, Union
+from typing import Dict, Mapping, Optional, Sequence, Tuple
 
 from ..core.context import ExecutionContext, ONE_SHOT
 from ..core.cost import CostCatalog
 from ..core.regions import Interpreter, Program
-from ..core.search import OptimizationResult, run_search
+from ..core.search import OptimizationResult, Plan, run_search
 from ..obs.metrics import MetricsRegistry, registry_counter
 from ..obs.trace import NOOP_TRACER
 from ..relational.database import ClientEnv, DatabaseServer, NetworkProfile, SLOW_REMOTE
@@ -41,21 +37,20 @@ __all__ = ["CobraSession", "Executable", "ExecutionResult", "PlanReport"]
 
 
 # --------------------------------------------------------------------------
-# Shared result vocabulary (program rewriting AND step-program planning)
+# Result vocabulary
 # --------------------------------------------------------------------------
 
 @dataclasses.dataclass
 class PlanReport:
-    """What any Cobra planning pass reports, regardless of domain."""
+    """What one compile of a program reports."""
 
-    domain: str                 # "program" (SQL/prefetch rewriting) | "step" (TPU sharding)
-    name: str                   # program name or arch/workload cell
-    choice: object              # search.Plan | planner.PlanChoice
+    name: str                   # program name
+    choice: Plan                # the winning plan
     est_cost_s: float           # model-estimated cost of the winner
     alternatives: int           # alternatives enumerated by the search
     memo_stats: Dict[str, int]
     opt_time_s: float
-    artifact: object            # rewritten Program | planner terms dict
+    artifact: Program           # the rewritten program
     from_cache: bool = False
     # ExecutionContext fingerprint the plan was costed under (telemetry:
     # serving plans are distinguishable from one-shot plans at a glance)
@@ -78,8 +73,8 @@ class PlanReport:
         under (from the context fingerprint, restricted to the program's
         parameterized-site groups). Empty = never observed (the cost model
         assumed no binding sharing)."""
-        if len(self.context_fp) > 4:
-            return dict(self.context_fp[4])
+        if len(self.context_fp) > 3:
+            return dict(self.context_fp[3])
         return {}
 
     def describe(self) -> str:
@@ -92,7 +87,7 @@ class PlanReport:
             ctx += f", binding-diversity~{avg:.2f}@{len(div)} site(s)"
         if self.budget_exhausted:
             ctx += ", BUDGET EXHAUSTED (greedy fallback)"
-        return (f"[{self.domain}] {self.name}: est {self.est_cost_s:.4g}s "
+        return (f"[program] {self.name}: est {self.est_cost_s:.4g}s "
                 f"over {self.alternatives} alternatives "
                 f"({self.opt_time_s*1e3:.1f}ms, {src}{ctx})")
 
@@ -155,7 +150,7 @@ class Executable:
     def report(self) -> PlanReport:
         swap = self.swap_outcome or {}
         return PlanReport(
-            domain="program", name=self.source.name, choice=self.result.plan,
+            name=self.source.name, choice=self.result.plan,
             est_cost_s=self.result.est_cost,
             alternatives=self.result.alternatives,
             memo_stats=self.result.memo_stats,
@@ -307,7 +302,6 @@ class CobraSession:
             from ..runtime.store import PlanStore
             plan_store = PlanStore.coerce(plan_store)
         self.plan_store = plan_store
-        self._step_cache: Dict[Tuple, PlanReport] = {}
         # zero the registry-backed telemetry counters (class descriptors)
         self.compile_calls = 0
         self.memo_runs = 0
@@ -426,63 +420,6 @@ class CobraSession:
         return ExecutionResult(outputs=outputs, simulated_s=env.clock,
                                n_queries=env.n_queries,
                                n_round_trips=env.n_round_trips)
-
-    # --------------------------------------------- distributed-planner facade
-    def plan_step(self, arch: Union[str, object], seq_len: int,
-                  global_batch: int, kind: str,
-                  mesh: Tuple[int, ...] = (1, 16, 16),
-                  top_k: int = 1) -> Union[PlanReport, list]:
-        """Front the TPU step-program planner with the same result vocabulary.
-
-        Accepts an architecture name (resolved via ``models.arch.get_arch``)
-        or an ``ArchConfig``. ``top_k > 1`` returns the K best reports."""
-        from ..core.planner import enumerate_plans, plan as planner_plan
-        cfg = arch
-        if isinstance(arch, str):
-            from ..models.arch import get_arch
-            cfg = get_arch(arch)
-        name = f"{getattr(cfg, 'name', arch)}/{kind}/T{seq_len}/B{global_batch}"
-        # the hardware profile is a memo-key component like the catalog is
-        # for program plans: an HW-table override (e.g. a different chip's
-        # peak FLOPs) must not be served a plan costed for the old hardware
-        from ..analysis.roofline import HW
-        # a context-pinned HW profile overlays the global table for this
-        # plan; the cache keys on the EFFECTIVE values, so a global HW
-        # override (e.g. a different chip's peak FLOPs) still invalidates
-        # and a pinned profile is genuinely what the plan is costed for
-        override = dict(self.context.hw)
-        hw_key = tuple(sorted({**HW, **override}.items()))
-        key = (name, tuple(mesh), top_k, hw_key)
-        cached = self._step_cache.get(key)
-        if cached is not None:
-            return cached
-
-        t0 = time.perf_counter()
-        saved = {k: HW[k] for k in override if k in HW}
-        added = set(override) - set(HW)
-        HW.update(override)
-        try:
-            out = planner_plan(cfg, seq_len, global_batch, kind, mesh=mesh,
-                               top_k=top_k)
-        finally:
-            HW.update(saved)
-            for k in added:
-                HW.pop(k, None)
-        dt = time.perf_counter() - t0
-        if top_k == 1:
-            report = PlanReport(
-                domain="step", name=name, choice=out["choice"],
-                est_cost_s=out["cost_s"], alternatives=out["n_alternatives"],
-                memo_stats=out["memo"], opt_time_s=dt, artifact=out["terms"])
-        else:
-            n_alts = len(enumerate_plans(cfg, kind))
-            report = [PlanReport(domain="step", name=name, choice=c["choice"],
-                                 est_cost_s=c["cost_s"], alternatives=n_alts,
-                                 memo_stats={}, opt_time_s=dt,
-                                 artifact=c["terms"])
-                      for c in out]
-        self._step_cache[key] = report
-        return report
 
     # ------------------------------------------------------- tracing frontend
     def trace(self, fn=None, *, name: Optional[str] = None,

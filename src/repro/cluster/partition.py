@@ -1,8 +1,7 @@
 """Horizontal partitioning of columnar tables across shards.
 
-The :class:`Partitioner` is the cluster's data-placement policy, in the
-spirit of the mesh + ``PartitionSpec`` idiom in ``repro.launch.sharding``:
-each table is either
+The :class:`Partitioner` is the cluster's data-placement policy: each
+table is either
 
   * **partitioned** — rows hashed to shards by one declared key column
     (``shard = int(key) % n_shards``, a deterministic modulo hash so tests

@@ -9,8 +9,6 @@ true while-loop iteration counts where the catalog only has a default. The
 :class:`ExecutionContext` packages exactly those runtime parameters —
 
   * ``batch_size``   — how many invocations share one client environment;
-  * ``hw``           — an optional hardware-profile override (the step-program
-    planner's HW table; program plans ignore it but key on it);
   * ``stats``        — a :class:`StatsProfile` of observed per-site iteration
     counts and wall-clock feedback published by the
     :class:`~repro.runtime.feedback.FeedbackController`
@@ -170,14 +168,11 @@ class ExecutionContext:
     """The runtime parameters a plan is optimized *for*."""
 
     batch_size: int = 1
-    hw: Tuple[Tuple[str, float], ...] = ()   # optional HW-profile override
     stats: StatsProfile = _EMPTY_STATS
 
     def __post_init__(self):
         if self.batch_size < 1:
             raise ValueError("batch_size must be >= 1")
-        if isinstance(self.hw, dict):
-            object.__setattr__(self, "hw", tuple(sorted(self.hw.items())))
 
     @classmethod
     def serving(cls, batch_size: int,
@@ -201,7 +196,7 @@ class ExecutionContext:
             want = set(sites)
             rel = tuple(kv for kv in self.stats.iters if kv[0] in want)
             rel_b = tuple(kv for kv in self.stats.bindings if kv[0] in want)
-        return ("ctx", self.batch_size, self.hw, rel, rel_b)
+        return ("ctx", self.batch_size, rel, rel_b)
 
     def describe(self) -> str:
         n = len(self.stats.iters)

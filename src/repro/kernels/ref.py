@@ -6,60 +6,9 @@ Also hosts the numpy twins (``*_np``) that the compiled execution tier's
 
 from __future__ import annotations
 
-import math
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-def flash_attention_ref(q, k, v, causal: bool = True,
-                        window: Optional[int] = None,
-                        chunk: Optional[int] = None,
-                        scale: Optional[float] = None):
-    """q (B,H,Tq,hd), k/v (B,KV,Tk,hd) — GQA broadcast; fp32 softmax."""
-    B, H, Tq, hd = q.shape
-    KV, Tk = k.shape[1], k.shape[2]
-    rep = H // KV
-    scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    qf = q.reshape(B, KV, rep, Tq, hd).astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    scores = jnp.einsum("bkrqh,bksh->bkrqs", qf, kf) * scale
-    qpos = jnp.arange(Tq)[:, None] + (Tk - Tq)
-    kpos = jnp.arange(Tk)[None, :]
-    m = jnp.ones((Tq, Tk), bool)
-    if causal:
-        m &= kpos <= qpos
-    if window is not None:
-        m &= kpos > qpos - window
-    if chunk is not None:
-        m &= (kpos // chunk) == (qpos // chunk)
-    scores = jnp.where(m[None, None, None], scores, -jnp.inf)
-    p = jax.nn.softmax(scores, axis=-1)
-    p = jnp.where(jnp.isnan(p), 0.0, p)
-    out = jnp.einsum("bkrqs,bksh->bkrqh", p, v.astype(jnp.float32))
-    return out.reshape(B, H, Tq, hd).astype(q.dtype)
-
-
-def rwkv6_scan_ref(r, k, v, w_log, u, state=None):
-    """Exact sequential recurrence (B,H,T,K)/(B,H,T,V) — see layers.py."""
-    B, H, T, K = r.shape
-    V = v.shape[-1]
-    S = jnp.zeros((B, H, K, V), jnp.float32) if state is None else state
-
-    def step(S, inp):
-        rt, kt, vt, wt = inp
-        rt, kt, vt = (x.astype(jnp.float32) for x in (rt, kt, vt))
-        bonus = jnp.einsum("bhk,hk,bhk->bh", rt, u.astype(jnp.float32), kt)
-        y = jnp.einsum("bhk,bhkv->bhv", rt, S) + bonus[..., None] * vt
-        S = S * jnp.exp(wt.astype(jnp.float32))[..., None] \
-            + jnp.einsum("bhk,bhv->bhkv", kt, vt)
-        return S, y
-
-    inputs = tuple(jnp.moveaxis(x, 2, 0) for x in (r, k, v, w_log))
-    S, ys = jax.lax.scan(step, S, inputs)
-    return jnp.moveaxis(ys, 0, 2).astype(r.dtype), S
 
 
 def segment_reduce_ref(values, segment_ids, num_segments: int, op: str = "sum"):

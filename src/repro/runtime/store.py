@@ -49,7 +49,9 @@ from typing import Dict, Optional
 
 __all__ = ["PlanStore", "content_address"]
 
-_FORMAT_VERSION = 1
+# raised whenever the shape of a logical key or a payload changes, so an
+# entry written in another shape is refused, never hashed against a new key
+_FORMAT_VERSION = 2
 
 
 def content_address(ident) -> str:

@@ -234,7 +234,7 @@ class TestSessionEndToEnd:
         db = make_orders_customer_db(100, 100)
         session = CobraSession(db, CostCatalog(SLOW_REMOTE))
         rep = session.compile(make_p0()).report
-        assert isinstance(rep, PlanReport) and rep.domain == "program"
+        assert isinstance(rep, PlanReport)
         assert rep.alternatives >= 1 and rep.est_cost_s > 0
         assert "P0" in rep.describe()
 
@@ -310,56 +310,6 @@ class TestTraceDecorator:
         exe1 = session.trace(body, name="agg")
         exe2 = session.trace(body, name="agg")
         assert not exe1.from_cache and exe2.from_cache
-
-
-# --------------------------------------------------------------------------
-# Distributed-planner facade (shared vocabulary)
-# --------------------------------------------------------------------------
-
-class TestPlannerFacade:
-    def test_plan_step_matches_core_planner(self):
-        from repro.core.planner import plan as core_plan
-        from repro.models.arch import get_arch
-        session = CobraSession(make_orders_customer_db(10, 10))
-        rep = session.plan_step("stablelm-12b", 2048, 64, "train")
-        raw = core_plan(get_arch("stablelm-12b"), 2048, 64, "train")
-        assert isinstance(rep, PlanReport) and rep.domain == "step"
-        assert rep.choice == raw["choice"]
-        assert rep.est_cost_s == pytest.approx(raw["cost_s"])
-        assert rep.alternatives == raw["n_alternatives"]
-
-    def test_plan_step_keyed_on_hardware_profile(self):
-        """An HW-table override is part of the step-plan memo key, like the
-        cost catalog is for program plans: the same cell re-planned on
-        different hardware must not be served the stale report."""
-        from repro.analysis.roofline import HW
-        session = CobraSession(make_orders_customer_db(10, 10))
-        r1 = session.plan_step("rwkv6-3b", 1024, 4, "decode")
-        old = HW["hbm_bw"]
-        try:
-            HW["hbm_bw"] = old / 4
-            r2 = session.plan_step("rwkv6-3b", 1024, 4, "decode")
-            assert r2 is not r1          # fresh planning pass, not the memo
-            r3 = session.plan_step("rwkv6-3b", 1024, 4, "decode")
-            assert r3 is r2              # memoized under the NEW profile
-        finally:
-            HW["hbm_bw"] = old
-        assert session.plan_step("rwkv6-3b", 1024, 4, "decode") is r1
-
-    def test_plan_step_memoized_and_topk(self):
-        session = CobraSession(make_orders_customer_db(10, 10))
-        r1 = session.plan_step("rwkv6-3b", 1024, 4, "decode")
-        r2 = session.plan_step("rwkv6-3b", 1024, 4, "decode")
-        assert r1 is r2  # facade memoizes identical cells
-        top3 = session.plan_step("rwkv6-3b", 1024, 4, "decode", top_k=3)
-        assert len(top3) == 3
-        assert top3[0].est_cost_s <= top3[1].est_cost_s <= top3[2].est_cost_s
-        # alternatives reports the enumerated space, not the truncated top-k
-        from repro.core.planner import enumerate_plans
-        from repro.models.arch import get_arch
-        n_space = len(enumerate_plans(get_arch("rwkv6-3b"), "decode"))
-        assert all(rep.alternatives == n_space for rep in top3)
-        assert n_space > 3
 
 
 # --------------------------------------------------------------------------

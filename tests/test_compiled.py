@@ -27,29 +27,13 @@ from repro.compiled import (CompileManager, available_backends, lower_program,
                             resolve_backend)
 from repro.core import CostCatalog
 from repro.kernels import ops
-from repro.programs import (make_m0, make_orders_customer_db, make_p0,
-                            make_p1, make_p2, make_sales_db, make_scan,
-                            make_wilos_a, make_wilos_b, make_wilos_c,
-                            make_wilos_d, make_wilos_e, make_wilos_f,
-                            make_wilos_db)
+from repro.programs import (make_orders_customer_db, make_p0, make_p2,
+                            make_scan, make_wilos_a, make_wilos_b,
+                            make_wilos_c, make_wilos_db)
 from repro.relational.database import FAST_LOCAL, SLOW_REMOTE
 from repro.runtime import ServingRuntime
 
-# (factory, db factory, param sets) per example program
-PROGRAMS = {
-    "P0": (make_p0, lambda: make_orders_customer_db(300, 30), [{}] * 3),
-    "P1": (make_p1, lambda: make_orders_customer_db(300, 30), [{}] * 3),
-    "P2": (make_p2, lambda: make_orders_customer_db(300, 30), [{}] * 3),
-    "M0": (make_m0, lambda: make_sales_db(200), [{}] * 3),
-    "SCAN": (make_scan, lambda: make_wilos_db(200), [{}] * 3),
-    "W_A": (make_wilos_a, lambda: make_wilos_db(120), [{}] * 2),
-    "W_B": (make_wilos_b, lambda: make_wilos_db(200), [{}] * 3),
-    "W_C": (make_wilos_c, lambda: make_wilos_db(120), [{}] * 2),
-    "W_D": (make_wilos_d, lambda: make_wilos_db(200), [{}] * 3),
-    "W_E": (make_wilos_e, lambda: make_wilos_db(200),
-            [{"worklist": [0, 1, 2]}, {"worklist": [1]}, {"worklist": []}]),
-    "W_F": (make_wilos_f, lambda: make_wilos_db(200), [{}] * 3),
-}
+from paper_programs import PROGRAMS
 
 
 def session(db, network=SLOW_REMOTE):

@@ -653,28 +653,14 @@ class TestServingContext:
 
 
 # --------------------------------------------------------------------------
-# Context-pinned HW profile through the planner facade
+# Context fingerprint defaults
 # --------------------------------------------------------------------------
 
 class TestContextHWProfile:
-    def test_pinned_hw_changes_step_plan_cost_and_restores_global(self):
-        from repro.analysis.roofline import HW
-        base = CobraSession(make_wilos_db(50))
-        ref = base.plan_step("rwkv6-3b", 2048, 16, "train")
-
-        slow = CobraSession(make_wilos_db(50), context=ExecutionContext(
-            hw={"peak_flops": HW["peak_flops"] / 10}))
-        before = dict(HW)
-        out = slow.plan_step("rwkv6-3b", 2048, 16, "train")
-        assert HW == before                      # overlay fully restored
-        assert out.est_cost_s > ref.est_cost_s   # the pin really costed it
-        # distinct HW profiles occupy distinct step-cache entries
-        assert slow.plan_step("rwkv6-3b", 2048, 16, "train") is out
-
     def test_one_shot_fingerprint_default_single_source(self):
         from repro.api import PlanCacheKey, PlanReport
         from repro.core import ONE_SHOT
         assert PlanCacheKey("fp", (), (), 1).context_key == \
             ONE_SHOT.fingerprint()
-        assert PlanReport("program", "p", None, 0.0, 0, {}, 0.0,
+        assert PlanReport("p", None, 0.0, 0, {}, 0.0,
                           None).context_fp == ONE_SHOT.fingerprint()

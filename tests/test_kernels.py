@@ -5,8 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import (build_direct_table, flash_attention, join_probe,
-                           rwkv6_scan, segment_reduce)
+from repro.kernels import build_direct_table, join_probe, segment_reduce
 from repro.kernels import ref
 
 KEY = jax.random.PRNGKey(7)
@@ -14,98 +13,6 @@ KEY = jax.random.PRNGKey(7)
 
 def keys(n):
     return jax.random.split(KEY, n)
-
-
-# --------------------------------------------------------------------------
-# flash attention
-# --------------------------------------------------------------------------
-
-ATTN_SWEEP = [
-    # (B, H, KV, Tq, Tk, hd, dtype, causal, window, chunk)
-    (1, 2, 2, 64, 64, 32, jnp.float32, True, None, None),
-    (2, 4, 2, 64, 64, 16, jnp.float32, True, None, None),     # GQA
-    (1, 2, 1, 128, 128, 32, jnp.bfloat16, True, None, None),  # bf16 + GQA
-    (1, 2, 2, 64, 64, 32, jnp.float32, True, 16, None),       # SWA
-    (1, 2, 2, 64, 64, 32, jnp.float32, True, None, 32),       # chunked local
-    (1, 1, 1, 32, 128, 32, jnp.float32, True, None, None),    # decode-ish tail
-    (1, 2, 2, 64, 64, 64, jnp.float32, False, None, None),    # bidirectional
-]
-
-
-@pytest.mark.slow  # full attention sweep; excluded from test-fast
-@pytest.mark.parametrize("B,H,KV,Tq,Tk,hd,dt,causal,window,chunk", ATTN_SWEEP)
-def test_flash_attention_matches_ref(B, H, KV, Tq, Tk, hd, dt, causal,
-                                     window, chunk):
-    k1, k2, k3 = keys(3)
-    q = jax.random.normal(k1, (B, H, Tq, hd), dt)
-    k = jax.random.normal(k2, (B, KV, Tk, hd), dt)
-    v = jax.random.normal(k3, (B, KV, Tk, hd), dt)
-    got = flash_attention(q, k, v, causal=causal, window=window, chunk=chunk,
-                          block_q=32, block_k=32, interpret=True)
-    want = ref.flash_attention_ref(q, k, v, causal=causal, window=window,
-                                   chunk=chunk)
-    tol = 2e-2 if dt == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=tol, atol=tol)
-
-
-@pytest.mark.slow
-def test_flash_attention_block_shape_independence():
-    k1, k2, k3 = keys(3)
-    q = jax.random.normal(k1, (1, 2, 128, 32))
-    k = jax.random.normal(k2, (1, 2, 128, 32))
-    v = jax.random.normal(k3, (1, 2, 128, 32))
-    outs = [flash_attention(q, k, v, block_q=bq, block_k=bk, interpret=True)
-            for bq, bk in [(32, 32), (64, 32), (32, 64), (128, 128)]]
-    for o in outs[1:]:
-        np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
-                                   rtol=1e-5, atol=1e-5)
-
-
-# --------------------------------------------------------------------------
-# rwkv6 scan
-# --------------------------------------------------------------------------
-
-RWKV_SWEEP = [
-    # (B, H, T, K, V, chunk, dtype)
-    (1, 2, 64, 16, 16, 16, jnp.float32),
-    (2, 3, 128, 32, 32, 32, jnp.float32),
-    (1, 2, 64, 16, 32, 64, jnp.float32),    # chunk == T
-    (1, 2, 96, 16, 16, 32, jnp.bfloat16),
-]
-
-
-@pytest.mark.slow  # full scan sweep; excluded from test-fast
-@pytest.mark.parametrize("B,H,T,K,V,chunk,dt", RWKV_SWEEP)
-def test_rwkv6_scan_matches_ref(B, H, T, K, V, chunk, dt):
-    k1, k2, k3, k4, k5 = keys(5)
-    r = jax.random.normal(k1, (B, H, T, K), dt)
-    k = jax.random.normal(k2, (B, H, T, K), dt)
-    v = jax.random.normal(k3, (B, H, T, V), dt)
-    w = -jnp.exp(jax.random.normal(k4, (B, H, T, K)) * 1.5).astype(jnp.float32)
-    u = jax.random.normal(k5, (H, K), jnp.float32)
-    y, s = rwkv6_scan(r, k, v, w, u, chunk=chunk, interpret=True)
-    y0, s0 = ref.rwkv6_scan_ref(r, k, v, w, u)
-    tol = 3e-2 if dt == jnp.bfloat16 else 1e-3
-    np.testing.assert_allclose(np.asarray(y, np.float32),
-                               np.asarray(y0, np.float32), rtol=tol, atol=tol)
-    np.testing.assert_allclose(np.asarray(s), np.asarray(s0),
-                               rtol=1e-3, atol=1e-3)
-
-
-def test_rwkv6_extreme_decay_stable():
-    """Strongly negative decays must underflow benignly, never overflow."""
-    k1, k2, k3 = keys(3)
-    B, H, T, K = 1, 1, 64, 16
-    r = jax.random.normal(k1, (B, H, T, K))
-    k = jax.random.normal(k2, (B, H, T, K))
-    v = jax.random.normal(k3, (B, H, T, K))
-    w = jnp.full((B, H, T, K), -40.0)
-    u = jnp.zeros((H, K))
-    y, s = rwkv6_scan(r, k, v, w, u, chunk=16, interpret=True)
-    assert bool(jnp.all(jnp.isfinite(y)))
-    assert bool(jnp.all(jnp.isfinite(s)))
 
 
 # --------------------------------------------------------------------------

@@ -1,14 +1,6 @@
-"""End-to-end system behaviour: the full Cobra pipeline + planner + serving.
-
-(The original placeholder file; now the top-level integration tests.)
-"""
-
-import numpy as np
-import pytest
+"""End-to-end system behaviour: the full Cobra pipeline."""
 
 from repro.core import CostCatalog, Interpreter, optimize
-from repro.core.planner import MeshShape, PlanChoice, TPUCostModel, plan
-from repro.models.arch import get_arch
 from repro.programs import make_orders_customer_db, make_p0
 from repro.relational.database import ClientEnv, SLOW_REMOTE
 
@@ -25,69 +17,3 @@ def test_full_cobra_pipeline_p0():
     o1 = Interpreter(env1, "fast").run(res.program)
     assert o0["result"] == o1["result"]
     assert env1.clock < env0.clock
-
-
-class TestPlanner:
-    def test_every_arch_shape_has_feasible_plan(self):
-        from repro.configs import ALL_ARCHS, SHAPES
-        for arch in ALL_ARCHS:
-            cfg = get_arch(arch)
-            for shape, spec in SHAPES.items():
-                if shape == "long_500k" and not cfg.subquadratic:
-                    continue
-                out = plan(cfg, spec["seq_len"], spec["global_batch"],
-                           spec["kind"], mesh=(1, 16, 16))
-                assert out["terms"]["feasible"], (arch, shape, out["choice"])
-
-    def test_moe_prefers_all_to_all_for_many_experts(self):
-        """T4 analogue: 384 experts × top-8 must batch into all_to_all —
-        replicating 1T of expert weight cannot fit."""
-        cfg = get_arch("kimi-k2-1t-a32b")
-        out = plan(cfg, 4096, 256, "train", mesh=(1, 16, 16))
-        assert out["choice"].moe_mode == "ep_all_to_all"
-
-    def test_dp_infeasible_for_1t_params(self):
-        cfg = get_arch("kimi-k2-1t-a32b")
-        cm = TPUCostModel(cfg, 4096, 256, "train", MeshShape(1, 16, 16))
-        dp = cm.terms(PlanChoice("dp", "full", 8, False, "ep_all_to_all"))
-        assert not dp["feasible"]  # replicated 1T params >> 16 GB
-
-    def test_remat_tradeoff_visible(self):
-        """T2/N2 analogue: remat trades FLOPs for memory, monotonically."""
-        cfg = get_arch("stablelm-12b")
-        cm = TPUCostModel(cfg, 4096, 256, "train", MeshShape(1, 16, 16))
-        none = cm.terms(PlanChoice("fsdp_tp", "none", 8, False, "none"))
-        full = cm.terms(PlanChoice("fsdp_tp", "full", 8, False, "none"))
-        assert full["compute_s"] > none["compute_s"]
-        assert full["resident_bytes"] < none["resident_bytes"]
-
-    def test_multi_pod_scales_compute_term(self):
-        cfg = get_arch("internlm2-20b")
-        one = plan(cfg, 4096, 256, "train", mesh=(1, 16, 16))
-        two = plan(cfg, 4096, 256, "train", mesh=(2, 16, 16))
-        assert two["terms"]["compute_s"] < one["terms"]["compute_s"]
-
-
-@pytest.mark.slow  # spins up the batching server; excluded from test-fast
-class TestServing:
-    def test_batched_generation_deterministic(self):
-        from repro.launch.serve import ServeConfig, Server
-        server = Server(ServeConfig(max_new_tokens=6, max_seq=64))
-        rng = np.random.default_rng(0)
-        prompts = [rng.integers(0, server.arch.vocab_size, 8).astype(np.int32)
-                   for _ in range(3)]
-        a = server.generate(prompts)
-        b = server.generate(prompts)
-        assert a == b
-        assert all(len(o) == 6 for o in a)
-
-    def test_batching_invariance(self):
-        """A request decoded alone == decoded inside a batch (greedy)."""
-        from repro.launch.serve import ServeConfig, Server
-        server = Server(ServeConfig(max_new_tokens=5, max_seq=64))
-        rng = np.random.default_rng(1)
-        prompts = [rng.integers(0, server.arch.vocab_size, 8).astype(np.int32)
-                   for _ in range(3)]
-        solo = server.generate([prompts[0]])[0]
-        batched = server.generate(prompts)[0]
-        assert solo == batched
