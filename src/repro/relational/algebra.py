@@ -631,7 +631,8 @@ def masked(q: Query, db, params=None) -> Tuple[
         if base.nrows == 0:
             return base, sel, mask
         if sel is None:
-            sel = _range_selection(q.pred, base, params)
+            with db.tracer.span("server.range_select"):
+                sel = _range_selection(q.pred, base, params)
             if sel is not None and mask is not None:
                 mask = mask.at[sel.rows()].get(mode="promise_in_bounds")
         mask = _pred_mask(q.pred, base, params, sel, mask)
@@ -791,6 +792,27 @@ def _range_bounds(pred: Scalar, params) -> Dict[str, Tuple[int, int]]:
     return {n: (low[n], high[n]) for n in low if n in high}
 
 
+def search_key(keys: np.ndarray, key_val):
+    """``key_val`` in the sorted ``keys``' own dtype, or None where that
+    dtype cannot hold it exactly (such a key matches nothing). Searching
+    in the keys' dtype keeps numpy from promoting and copying them all."""
+    try:
+        k = keys.dtype.type(key_val)
+    except (TypeError, ValueError, OverflowError):
+        return None
+    return k if k.item() == key_val else None
+
+
+def _bound_position(values: np.ndarray, bound: int, side: str) -> int:
+    """``np.searchsorted(values, bound, side)`` over a sorted integer
+    column, searched in its own dtype (see :func:`search_key`); a bound
+    that dtype cannot hold lies past the edge it is beyond."""
+    k = search_key(values, bound)
+    if k is None:
+        return 0 if bound < np.iinfo(values.dtype).min else values.size
+    return int(np.searchsorted(values, k, side))
+
+
 class _RangeIndex:
     """A column's row ids in the order of their values (a stable argsort,
     on the device) and the values in that order (host); and, made at a
@@ -856,8 +878,8 @@ def _range_selection(pred: Scalar, base: Table, params):
         if low < info.min or high > info.max:
             continue
         index = _RANGE_INDEX(col)
-        lo = int(np.searchsorted(index.values, low, "left"))
-        n = max(int(np.searchsorted(index.values, high, "right")) - lo, 0)
+        lo = _bound_position(index.values, low, "left")
+        n = max(_bound_position(index.values, high, "right") - lo, 0)
         if n <= base.nrows / 8 and (best is None or n < best[0]):
             best = (n, index, lo)
     if best is None:

@@ -37,7 +37,7 @@ from ..obs.metrics import MetricsRegistry
 from ..obs.trace import NOOP_TRACER
 from ..obs.transfer import to_device, to_host
 from .algebra import (Aggregate, Join, Limit, OrderBy, Project, Query, Scan,
-                      Select, SemiJoin, resolve_rows)
+                      Select, SemiJoin, resolve_rows, search_key)
 from .memo import TableMemo
 from .table import Table
 
@@ -556,17 +556,6 @@ class _CacheIndex:
 _INDEXES = TableMemo(_CacheIndex, _INDEX_CAP)
 
 
-def _search_key(keys: np.ndarray, key_val):
-    """``key_val`` in the index's own dtype, or None where that dtype cannot
-    hold it exactly (such a key matches nothing). Searching in the index's
-    dtype keeps numpy from promoting and copying the whole index."""
-    try:
-        k = keys.dtype.type(key_val)
-    except (TypeError, ValueError, OverflowError):
-        return None
-    return k if k.item() == key_val else None
-
-
 class ClientEnv:
     """Application-side runtime: clock, ORM id-cache, prefetch cache.
 
@@ -679,7 +668,7 @@ class ClientEnv:
         tracer = self.tracer
         with tracer.span("client.lookup") as sp:
             keys = entry["keys"]
-            k = _search_key(keys, key_val)
+            k = search_key(keys, key_val)
             rows = []
             if k is not None:
                 lo = keys.searchsorted(k, side="left")

@@ -339,3 +339,104 @@ def test_a_range_on_the_build_side(monkeypatch, keys):
     # the sort and search evaluates the right side again after the
     # direct-address build refused its keys
     assert _counts()[0] - before[0] == (1 if keys == "direct" else 2)
+
+
+INT32 = np.iinfo(np.int32)
+
+
+def _int32_data(rng):
+    """``d`` over the whole int32 range, with rows at both of its edges
+    and next to them, and duplicates."""
+    data = _data(rng)
+    edges = [INT32.min] * 3 + [INT32.min + 1, INT32.max - 1] + [INT32.max] * 3
+    d = rng.integers(INT32.min, INT32.max, N, endpoint=True)
+    d[:len(edges)] = edges
+    d[len(edges):len(edges) + 40] = 12345
+    data["t"]["d"] = rng.permutation(d).astype(np.int32)
+    return data
+
+
+def _parent_selection(values, low, high, nrows):
+    """The selection's offsets as the bounds searched over the sorted
+    values widened to int64, where every int32 bound is exact."""
+    wide = values.astype(np.int64)
+    lo = int(np.searchsorted(wide, low, "left"))
+    n = max(int(np.searchsorted(wide, high, "right")) - lo, 0)
+    if n > nrows / 8:
+        return None
+    width = 1 << max(n - 1, 0).bit_length()
+    start = min(lo, nrows - width)
+    return start, lo - start, lo - start + n, width
+
+
+# (lo, hi) of ``d >= lo and d <= hi``: at the dtype's edges, one past the
+# far edge on each side (an empty range), inverted, and the whole dtype
+# (more than an eighth: full columns)
+INT32_WINDOWS = {"at_min": (INT32.min, INT32.min),
+                 "near_min": (INT32.min, INT32.min + 1),
+                 "at_max": (INT32.max, INT32.max),
+                 "near_max": (INT32.max - 1, INT32.max),
+                 "low_past_max": (INT32.max + 1, INT32.max),
+                 "high_past_min": (INT32.min, INT32.min - 1),
+                 "duplicates": (12345, 12345),
+                 "inverted": (100, -100),
+                 "whole_dtype": (INT32.min, INT32.max)}
+
+
+@pytest.mark.parametrize("window", sorted(INT32_WINDOWS))
+def test_int32_bounds_select_alike_in_every_integer_type(window):
+    """Python-int, np.int64 and (where it holds the bound) np.int32 bounds
+    give one selection, the one the bounds searched in int64 give, and it
+    keeps exactly the rows numpy's mask keeps; bounds past the dtype's far
+    edge give an empty range and raise nothing."""
+    rng = np.random.default_rng(SEED + 9)
+    data = _int32_data(rng)
+    db = _db(data)
+    base = db.table("t")
+    lo, hi = INT32_WINDOWS[window]
+    kinds = [int, np.int64]
+    if INT32.min <= lo <= INT32.max and INT32.min <= hi <= INT32.max:
+        kinds.append(np.int32)
+    want = _numpy_range(data["t"]["d"].astype(np.int64), lo, hi, "ge", "le")
+    pred = _range("ge", "le")
+    got = []
+    for kind in kinds:
+        params = {"lo": kind(lo), "hi": kind(hi)}
+        sel = algebra._range_selection(pred, base, params)
+        got.append(None if sel is None
+                   else (sel.start, sel.lo, sel.hi, sel.width))
+        ids, _ = _kept(db, Select(pred, Scan("t")), params)
+        assert ids.tolist() == np.flatnonzero(want).tolist()
+    values = algebra._RANGE_INDEX(base.column("d")).values
+    assert got == [_parent_selection(values, lo, hi, N)] * len(kinds)
+    if sel is not None:
+        assert sel.hi - sel.lo == int(want.sum())
+    assert (sel is None) == (window == "whole_dtype")
+
+
+@pytest.mark.parametrize("kind", [int, np.int64, np.int32])
+def test_the_bounds_are_searched_in_the_columns_dtype(monkeypatch, kind):
+    """Each key the index's search receives is of the sorted values' own
+    dtype, so numpy neither promotes nor copies the column a request."""
+    rng = np.random.default_rng(SEED + 10)
+    data = _int32_data(rng)
+    db = _db(data)
+    base = db.table("t")
+    values = algebra._RANGE_INDEX(base.column("d")).values
+    keys = []
+    search = np.searchsorted
+
+    def recording(a, v, *args, **kwargs):
+        if a is values:
+            keys.append(v)
+        return search(a, v, *args, **kwargs)
+
+    monkeypatch.setattr(algebra.np, "searchsorted", recording)
+    for lo, hi in [(12345, 12345), (INT32.min, INT32.min + 1),
+                   (INT32.max - 1, INT32.max)]:
+        sel = algebra._range_selection(_range("ge", "le"), base,
+                                       {"lo": kind(lo), "hi": kind(hi)})
+        assert sel is not None
+    assert len(keys) == 6
+    for k in keys:
+        assert isinstance(k, np.generic) and k.dtype == values.dtype, k
